@@ -70,6 +70,8 @@ def main(argv=None):
                     "sweep (read with repro.apps.obs_report / Perfetto)")
     args = ap.parse_args(argv)
 
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (bench_balancing, bench_blocking,
                             bench_calibration, bench_coarsening,
                             bench_decider, bench_dist, bench_dynamic,

@@ -15,7 +15,8 @@ python -m pytest -x -q --ignore=tests/test_dist.py "$@"
 # run would deselect everything here (pytest exit 5 → spurious CI fail)
 # or re-run arbitrary tests under the forced device count.
 XLA_FLAGS="--xla_force_host_platform_device_count=8${XLA_FLAGS:+ $XLA_FLAGS}" \
-    python -m pytest -x -q tests/test_dist.py
+    python -m pytest -x -q tests/test_dist.py \
+    tests/test_chip_smoke.py::test_phase_dist_tiny
 python -m compileall -q src
 python scripts/check_imports.py   # every bench_*/example module imports
 python scripts/check_docs.py      # README/docs symbol references resolve

@@ -29,8 +29,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.baselines import make_cusparse_analog, make_gespmm_analog
+from repro.compile_cache import enable_compile_cache
 from repro.core.pcsr import SpMMConfig
 from repro.data.tasks import NodeTask
+from repro.kernels import resolve_backend
 from repro.models.gnn import (accuracy, gat_forward, gcn_forward,
                               gin_forward, init_gat, init_gcn, init_gin,
                               node_ce_loss)
@@ -43,7 +45,9 @@ from repro.pipeline import ParamSpMM
 class GNNTrainResult:
     losses: list = field(default_factory=list)
     val_acc: float = 0.0
-    seconds_per_step: float = 0.0
+    setup_seconds: float = 0.0       # host: reorder, config pick, packs
+    compile_seconds: float = 0.0     # step 0: trace + compile + run
+    seconds_per_step: float = 0.0    # steady steps, after block_until_ready
     config: SpMMConfig | list | None = None   # list = per-partition configs
 
 
@@ -99,7 +103,8 @@ def train_gnn(task: NodeTask, *, model: str = "gcn", hidden: int = 64,
             # engine backward is native autodiff; the Pallas backward runs
             # its dK/dVf SpMMs on the operator's cached transpose PCSR
             kw.setdefault("build_transpose",
-                          kw.get("backend", "engine") == "pallas")
+                          resolve_backend(kw.get("backend"))[0] == "pallas")
+    t_setup = time.perf_counter()
     with span("gnn.pack", model=model, mode=spmm_mode,
               partitions=partitions):
         spmm, perm, cfg = build_spmm(task, hidden, spmm_mode,
@@ -142,8 +147,8 @@ def train_gnn(task: NodeTask, *, model: str = "gcn", hidden: int = 64,
             # the message fn aggregates instead of the plain-SpMM closure,
             # over the very same PCSR (+ transpose PCSR) the pipeline built
             spmm = make_gat_message_fn(spmm.op.pcsr, spmm.op.pcsr_t,
-                                       backend=kw.get("backend", "engine"),
-                                       interpret=kw.get("interpret", True))
+                                       backend=spmm.op.backend,
+                                       interpret=spmm.op.interpret)
     else:
         raise ValueError(model)
 
@@ -156,8 +161,10 @@ def train_gnn(task: NodeTask, *, model: str = "gcn", hidden: int = 64,
 
     grad_fn = jax.value_and_grad(loss_fn)
 
-    res = GNNTrainResult(config=cfg)
+    res = GNNTrainResult(config=cfg,
+                         setup_seconds=time.perf_counter() - t_setup)
     t0 = None
+    t_step0 = time.perf_counter()
     for step in range(steps):
         # step 0 pays tracing + compilation — its span is named apart so
         # the trace separates warmup from steady-state steps
@@ -169,6 +176,7 @@ def train_gnn(task: NodeTask, *, model: str = "gcn", hidden: int = 64,
         res.losses.append(float(loss))
         if step == 0:      # exclude jit warmup from timing
             t0 = time.perf_counter()
+            res.compile_seconds = t0 - t_step0
     jax.block_until_ready(params)
     if steps > 1:
         res.seconds_per_step = (time.perf_counter() - t0) / (steps - 1)
@@ -245,6 +253,7 @@ def main(argv=None):
                     help="write a Chrome-trace JSON of the run (read it "
                     "with repro.apps.obs_report or Perfetto)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     import contextlib
     ctx = tracing(args.trace) if args.trace else contextlib.nullcontext()

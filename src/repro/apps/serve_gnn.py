@@ -27,8 +27,10 @@ def main(argv=None):
                     help="corpus('serve') graph name")
     ap.add_argument("--model", default="gcn",
                     choices=["gcn", "gin", "gat"])
-    ap.add_argument("--backend", default="engine",
-                    choices=["engine", "pallas"])
+    ap.add_argument("--backend", default=None,
+                    choices=["engine", "pallas"],
+                    help="default: compiled Pallas kernels on a TPU, the "
+                    "pure-JAX engine elsewhere")
     ap.add_argument("--requests", type=int, default=32)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--feat", type=int, default=16)
@@ -46,6 +48,7 @@ def main(argv=None):
     args = ap.parse_args(argv)
 
     import jax
+    from repro.compile_cache import enable_compile_cache
     from repro.data.graphs import corpus
     from repro.models.gnn import init_gat, init_gcn, init_gin
     from repro.obs import metrics_snapshot, tracing
@@ -67,6 +70,7 @@ def main(argv=None):
     params = init(key, dims)
 
     stream = synthetic_stream(args.requests, g.n_rows, seed=args.seed)
+    enable_compile_cache()
     ctx = tracing(args.trace) if args.trace else contextlib.nullcontext()
     with ctx:
         svc = GNNService(g, feats, params, model=args.model,
@@ -90,7 +94,7 @@ def main(argv=None):
             sr = r.sampled
             ref = np.asarray(reference_forward(
                 sr.sub, feats[sr.nodes], params, model=args.model,
-                config=r.config, backend=args.backend))[sr.seed_local]
+                config=r.config, backend=svc.backend))[sr.seed_local]
             np.testing.assert_allclose(r.outputs, ref, rtol=1e-5,
                                        atol=1e-5,
                                        err_msg=f"request {r.rid}")
@@ -100,7 +104,7 @@ def main(argv=None):
               f"full-pipeline reference")
 
     stats = {
-        "graph": args.graph, "model": args.model, "backend": args.backend,
+        "graph": args.graph, "model": args.model, "backend": svc.backend,
         "requests": len(results), "batches": len(svc.batch_log),
         "buckets": per_bucket,
         "cache_hits": cache.hits, "cache_misses": cache.misses,

@@ -24,6 +24,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels import resolve_backend
+
 from .pcsr import PCSR, SpMMConfig, build_pcsr
 from .sparse import CSRMatrix
 
@@ -114,8 +116,8 @@ def attend_scores(scores, mask, rows, n_segments: int, *,
 
 
 def make_gat_message_fn(pcsr: PCSR, pcsr_t: Optional[PCSR] = None, *,
-                        backend: str = "engine",
-                        interpret: bool = True, slope: float = 0.2):
+                        backend: str | None = None,
+                        interpret: bool | None = None, slope: float = 0.2):
     """Differentiable fused GAT message ``f(Q, K, Vf) -> (n_rows, d)``:
     SDDMM → LeakyReLU → softmax-over-edges → SpMM, all over one PCSR.
 
@@ -125,7 +127,8 @@ def make_gat_message_fn(pcsr: PCSR, pcsr_t: Optional[PCSR] = None, *,
     through one head-tiled kernel call (a single compilation), the engine
     backend vmaps its jitted path.
 
-    Backends:
+    Backends (``None`` resolves from the platform, see
+    ``repro.kernels.resolve_backend``):
 
     * ``"engine"`` — the pure-JAX path, returned as-is: natively
       differentiable, no ``custom_vjp`` required.
@@ -157,6 +160,7 @@ def make_gat_message_fn(pcsr: PCSR, pcsr_t: Optional[PCSR] = None, *,
       — and slot tensors move between the two layouts through a
       precomputed ``slot_transfer_map`` gather/scatter.
     """
+    backend, interpret = resolve_backend(backend, interpret)
     arrs = pcsr.to_jax()
     cfg = pcsr.config
     V, R, K, n_blocks = cfg.V, cfg.R, pcsr.K, pcsr.n_blocks
@@ -261,13 +265,15 @@ def make_gat_message_fn(pcsr: PCSR, pcsr_t: Optional[PCSR] = None, *,
 
 
 def make_spmm_fn(pcsr: PCSR, pcsr_t: Optional[PCSR] = None, *,
-                 backend: str = "engine", interpret: bool = True):
+                 backend: str | None = None, interpret: bool | None = None):
     """Build a differentiable ``f(B) = A·B`` closed over PCSR arrays.
 
-    backend: "engine" (pure JAX, fast on CPU) or "pallas" (TPU kernel,
-    interpret-mode on CPU).  The VJP uses the transpose PCSR when given,
-    otherwise gradients flow through the engine's gather/scatter directly.
+    backend: "engine" (pure JAX) or "pallas" (TPU kernel); ``None``
+    resolves from the platform (``repro.kernels.resolve_backend``).  The
+    VJP uses the transpose PCSR when given, otherwise gradients flow
+    through the engine's gather/scatter directly.
     """
+    backend, interpret = resolve_backend(backend, interpret)
     if backend == "pallas":
         from repro.kernels.paramspmm.ops import paramspmm as _fwd_call
         fwd = lambda B: _fwd_call(pcsr, B, interpret=interpret)
@@ -347,7 +353,8 @@ def engine_spmm_fused(pcsr: PCSR, B, *, scale=None, bias=None,
 
 
 def make_fused_spmm_fn(pcsr: PCSR, pcsr_t: Optional[PCSR] = None, *,
-                       backend: str = "engine", interpret: bool = True):
+                       backend: str | None = None,
+                       interpret: bool | None = None):
     """Build the epilogue-fused aggregation closure
     ``fused(B, scale=None, bias=None, activation="none", residual=None)
     -> (n, d)`` computing ``act(scale ⊙ (A·B) + bias + residual)`` — one
@@ -372,6 +379,7 @@ def make_fused_spmm_fn(pcsr: PCSR, pcsr_t: Optional[PCSR] = None, *,
     Without ``pcsr_t`` the engine path falls back to native autodiff; the
     Pallas path requires it for gradients.
     """
+    backend, interpret = resolve_backend(backend, interpret)
     if backend == "pallas":
         from repro.kernels.paramspmm.ops import paramspmm
 
@@ -449,11 +457,13 @@ class ParamSpMMOperator:
     residual addend, GIN — layer on the Pallas backend)."""
 
     def __init__(self, csr: CSRMatrix, config: SpMMConfig, *,
-                 backend: str = "engine", interpret: bool = True,
+                 backend: str | None = None, interpret: bool | None = None,
                  build_transpose: bool = True):
         self.csr = csr
         self.config = config
+        backend, interpret = resolve_backend(backend, interpret)
         self.backend = backend
+        self.interpret = interpret
         self.pcsr = build_pcsr(csr.indptr, csr.indices, csr.data,
                                csr.n_rows, csr.n_cols, config)
         self.pcsr_t = None

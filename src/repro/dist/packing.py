@@ -34,11 +34,6 @@ import numpy as np
 
 from jax.sharding import PartitionSpec
 
-try:                                       # jax ≥ 0.6 top-level export
-    from jax import shard_map as _shard_map_raw
-except ImportError:                        # 0.4.x experimental home
-    from jax.experimental.shard_map import shard_map as _shard_map_raw
-
 AXIS = "parts"
 
 
@@ -57,11 +52,10 @@ def shard_map_2d(f, mesh, n_in: int, replicated: tuple = (),
                      for i in range(n_in))
     if out_specs is None:
         out_specs = spec if n_out == 1 else (spec,) * n_out
-    kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    try:
-        return _shard_map_raw(f, check_rep=False, **kwargs)
-    except TypeError:                      # newer jax dropped check_rep
-        return _shard_map_raw(f, **kwargs)
+    # compiled Pallas branches declare no per-axis variance (vma) on
+    # their outputs, which shard_map's checker would reject
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 @dataclass

@@ -64,6 +64,7 @@ from repro.core import (CostModel, CSRMatrix, SpMMConfig, build_pcsr,
                         config_space, extract_features)
 from repro.core.cost_model import halo_exchange_cost, overlap_exposed_cost
 from repro.core.engine import _engine, apply_epilogue, epilogue_grad
+from repro.kernels import resolve_backend
 from repro.obs import metrics as _obs_metrics, trace as _obs_trace
 
 from .gat import build_dist_gat, build_gat_pack
@@ -149,8 +150,9 @@ class DistGraph:
         (gather → SDDMM → softmax → SpMM) leaves nothing independent of
         the gather to overlap with, so ``gat_message`` always runs the
         joint-exchange path.
-    backend : ``"engine"`` (pure JAX) or ``"pallas"`` (TPU kernels,
-        interpret-mode on CPU).
+    backend : ``"engine"`` (pure JAX) or ``"pallas"`` (TPU kernels);
+        ``None`` lets the platform choose (``repro.kernels.resolve_backend``:
+        compiled kernels on a TPU, the engine elsewhere).
     op : operator the per-shard configs are priced for
         (``"spmm"`` | ``"sddmm"`` | ``"gat"``).
 
@@ -165,16 +167,15 @@ class DistGraph:
                  decider=None,
                  calibration=None,
                  mesh=None,
-                 backend: str = "engine",
-                 interpret: bool = True,
+                 backend: str | None = None,
+                 interpret: bool | None = None,
                  op: str = "spmm",
                  heads: int = 1,
                  overlap: bool = False,
                  max_f: int = 4):
         self.csr = csr
         self.dim = dim
-        self.backend = backend
-        self.interpret = interpret
+        self.backend, self.interpret = resolve_backend(backend, interpret)
         self.heads = heads
         self.overlap = overlap
         self.part: RowPartition = partition_csr(csr, n_parts, strategy)

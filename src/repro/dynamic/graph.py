@@ -22,6 +22,7 @@ from typing import Optional
 from repro.core import CostModel, CSRMatrix, SpMMConfig, config_space
 from repro.core.engine import make_gat_message_fn, make_spmm_fn
 from repro.obs import trace as _obs_trace
+from repro.kernels import resolve_backend
 
 from .governor import GovernorDecision, RepackGovernor
 from .pcsr import DynamicPCSR, MutationReport
@@ -30,7 +31,8 @@ from .pcsr import DynamicPCSR, MutationReport
 class DynamicGraph:
     """A mutable graph with always-exact, self-healing SpMM/GAT.
 
-    ``backend`` is ``"engine"`` (pure JAX) or ``"pallas"``;
+    ``backend`` is ``"engine"`` (pure JAX) or ``"pallas"`` (``None``:
+    chosen by the platform, ``repro.kernels.resolve_backend``);
     ``auto_heal=False`` keeps the governor advisory-only (its decisions
     still append to ``self.decisions``) so a caller can batch re-packs
     at its own cadence via ``repack()``.
@@ -38,13 +40,12 @@ class DynamicGraph:
 
     def __init__(self, csr: CSRMatrix, dim: int, *,
                  config: Optional[SpMMConfig] = None,
-                 backend: str = "engine", interpret: bool = True,
+                 backend: str | None = None, interpret: bool | None = None,
                  heads: int = 1, space=None, calibration=None,
                  slack: float = 1.25, amortize_steps: int = 100,
                  drift_threshold=None, auto_heal: bool = True):
         self.dim = dim
-        self.backend = backend
-        self.interpret = interpret
+        self.backend, self.interpret = resolve_backend(backend, interpret)
         self.heads = heads
         self.space = space or config_space(dim)
         self.calibration = calibration

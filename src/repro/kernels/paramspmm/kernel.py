@@ -1,41 +1,51 @@
 """ParamSpMM Pallas TPU kernel (paper Alg. 2, TPU adaptation per DESIGN.md §2)
 with fused prologue / epilogue.
 
-Grid ``(J, C, K)`` = (dim-tiles, chunks, slots).  Scalar-prefetched
-``colidx`` drives the gather of one ``(1, Dblk)`` row of ``B`` per step via
-``B``'s BlockSpec index map — the TPU-idiomatic replacement for the CUDA
-warp's irregular global load.  The ``(R, Dblk)`` output block is revisited
-across consecutive steps with the same ``trow`` and accumulated in VMEM:
-with ``S=True`` several chunks target one block (the paper's ``TRow`` +
-``atomicAdd``, made race-free by the sequential grid).
+Grid ``(J, C)`` = (dim-tiles, chunks).  Each grid step owns one chunk of
+``K`` slots:
+
+1. the chunk's steering — ``colidx``/``lrow`` slices and its ``(V, K)``
+   values — arrives in SMEM as ordinary pipelined blocks, so only the
+   per-chunk ``trow``/``init``/``fini`` vectors are scalar-prefetched
+   whole (whole-array prefetch of the per-slot arrays overflows the
+   1 MiB SMEM of a v5e at ~130k slots);
+2. the chunk's ``K`` rows of ``B`` (one ``Dblk``-lane tile each) are
+   gathered HBM → VMEM with manual async copies — the TPU replacement
+   for the CUDA warp's irregular global load;
+3. the slots are consumed in order, eight gathered rows per aligned
+   ``(8, Dblk)`` load, each scaled by its slot value and added into the
+   ``V`` output rows of its panel.
+
+The ``(R, Dblk)`` output block is revisited across consecutive chunks
+with the same ``trow`` and accumulated in VMEM: with ``S=True`` several
+chunks target one block (the paper's ``TRow`` + ``atomicAdd``, made
+race-free by the sequential grid).
 
 Parameter mapping (paper → here):
-  V → rows fed per gathered B row (vals block ``(1, V, K)``);
-  F → ``Dblk = F·128`` lanes per step (thread coarsening);
+  V → rows fed per gathered B row (slot values ``(V, K)`` per chunk);
+  F → ``Dblk = F·128`` lanes per gathered tile (thread coarsening);
   W → ``R = V·W`` output-block rows;
   S → chunking policy baked into the PCSR arrays (kernel is agnostic).
 
-Fusion (this file's reason to exist beyond the plain gather-scatter):
+Fusion:
 
 * **Softmax prologue** (``prologue=True``): ``vals`` carries raw attention
   *logits* (masked slots = −inf) and two extra tile-aligned
   ``(n_blocks·SUBLANES, LANES)`` inputs — one (8, 128) tile per block,
   row stats in sublane 0 — carry the per-row online-softmax stats the
-  fused SDDMM produced (its native output layout, aligned so the fused
-  path compiles on real TPU, not just in interpret mode).  The
-  attention weight α = exp(logit − rowmax)/rowsum is computed in-register
-  while the gathered B row is being consumed — the interstitial
-  elementwise normalize pass between SDDMM and SpMM disappears, making
-  the GAT forward exactly TWO kernels.
+  fused SDDMM produced (its native output layout; the block's tile is
+  read from SMEM).  The attention weight α = exp(logit − rowmax)/rowsum
+  is computed in-register while the gathered B row is consumed — the
+  normalize pass between SDDMM and SpMM disappears, making the GAT
+  forward exactly TWO kernels.
 * **Epilogue** (``scale``/``bias``/``residual``/``activation``): on the
-  last ``(j, k)`` visit of each output block — ``fini[c] == 1 and
-  k == K−1``, the moment the completed ``(R, Dblk)`` tile is still
-  VMEM-resident — a per-row degree-norm scale, per-feature bias add,
-  dense residual add (the matching ``(R, Dblk)`` tile of a full
-  ``(n, d)`` operand — GIN's ``(1+ε)h`` term), and activation are
-  applied before write-back, so a GCN aggregation step (and a GIN
-  ``(1+ε)h + A·h`` aggregation) is ONE kernel instead of kernel + 2–3
-  XLA elementwise passes over the (n, d) output.
+  last visit of each output block — ``fini[c] == 1``, the moment the
+  completed ``(R, Dblk)`` tile is still VMEM-resident — a per-row
+  degree-norm scale, per-feature bias add, dense residual add (the
+  matching ``(R, Dblk)`` tile of a full ``(n, d)`` operand — GIN's
+  ``(1+ε)h`` term), and activation are applied before write-back, so a
+  GCN aggregation step (and a GIN ``(1+ε)h + A·h`` aggregation) is ONE
+  kernel instead of kernel + 2–3 XLA elementwise passes.
 
 Padding-slot safety under the prologue: a masked/padding slot carries
 logit = −inf, so exp(−inf − m) = 0 regardless of the row stats — even the
@@ -53,62 +63,90 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.pcsr import LANES, SUBLANES
+from repro.kernels import resolve_backend
 
 ACTIVATIONS = ("none", "relu", "leaky_relu")
 
 
-def _kernel(colidx_ref, lrow_ref, trow_ref, init_ref, fini_ref,  # prefetch
-            *refs, V: int, K: int, prologue: bool, has_scale: bool,
+def gather_rows(src_hbm, row_of, buf, sem, *, n: int, lane0, width: int):
+    """Copy ``src[row_of(k), lane0:lane0+width]`` into ``buf[k]`` for
+    ``k < n`` (HBM → VMEM, one async copy per row on one semaphore) and
+    wait for all of them."""
+    def start(k, carry):
+        pltpu.make_async_copy(
+            src_hbm.at[pl.ds(row_of(k), 1), pl.ds(lane0, width)],
+            buf.at[pl.ds(k, 1)], sem).start()
+        return carry
+
+    def wait(k, carry):
+        pltpu.make_async_copy(src_hbm.at[pl.ds(0, 1), pl.ds(0, width)],
+                              buf.at[pl.ds(0, 1)], sem).wait()
+        return carry
+
+    jax.lax.fori_loop(0, n, start, 0)
+    jax.lax.fori_loop(0, n, wait, 0)
+
+
+def _kernel(trow_ref, init_ref, fini_ref,                   # scalar prefetch
+            colidx_ref, lrow_ref, vals_ref, b_hbm, *refs,
+            V: int, K: int, dblk: int, prologue: bool, has_scale: bool,
             has_bias: bool, has_resid: bool, activation: str, slope: float):
+    j = pl.program_id(0)
     c = pl.program_id(1)
-    k = pl.program_id(2)
 
     it = iter(refs)
-    vals_ref, b_ref = next(it), next(it)
     rowmax_ref = next(it) if prologue else None
     rowsum_ref = next(it) if prologue else None
     scale_ref = next(it) if has_scale else None
     bias_ref = next(it) if has_bias else None
     resid_ref = next(it) if has_resid else None
-    out_ref = next(it)
+    out_ref, gbuf, sem = next(it), next(it), next(it)
 
     # First visit of this output block in this dim-tile pass → zero it.
-    @pl.when((k == 0) & (init_ref[c] == 1))
+    @pl.when(init_ref[c] == 1)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    lr = lrow_ref[c * K + k]                 # panel within block
-    row = lr * V
-    vv = vals_ref[0, :, k]                   # (V,) values — or raw logits
-    if prologue:
-        # α = exp(logit − rowmax)/rowsum in-register (flash-style): the
-        # stats block for trow[c] is VMEM-resident across the chunk.
-        # Guards: empty rows have rowmax = −inf / rowsum = 0 (or garbage
-        # when the row's block was never visited by the SDDMM); masked and
-        # padding slots have logit = −inf, so α must come out exactly 0.
-        m = rowmax_ref[0, pl.ds(row, V)]
-        s = rowsum_ref[0, pl.ds(row, V)]
-        m = jnp.where(jnp.isfinite(m), m, 0.0)
-        den = jnp.where(s > 0, s, 1.0)
-        vv = jnp.exp(vv - m) / den
-    brow = b_ref[0, :]                       # (Dblk,) gathered B row
-    acc = out_ref[pl.ds(row, V), :]
-    out_ref[pl.ds(row, V), :] = acc + vv[:, None].astype(brow.dtype) * brow[None, :]
+    gather_rows(b_hbm, lambda k: colidx_ref[0, 0, k], gbuf, sem, n=K,
+                lane0=j * dblk, width=dblk)
+
+    def group(g, carry):
+        base = pl.multiple_of(g * SUBLANES, SUBLANES)
+        tile = gbuf[pl.ds(base, SUBLANES), :]    # 8 gathered B rows
+        for i in range(SUBLANES):
+            k = base + i
+            row = lrow_ref[0, 0, k] * V           # panel's first row
+            brow = tile[i:i + 1, :]               # (1, Dblk)
+            for v in range(V):
+                vv = jnp.full((1, 1), vals_ref[0, v, k], brow.dtype)
+                if prologue:
+                    # α = exp(logit − rowmax)/rowsum in-register.  Guards:
+                    # empty rows have rowmax = −inf / rowsum = 0 (or
+                    # garbage when the row's block was never visited by
+                    # the SDDMM); masked and padding slots have logit
+                    # = −inf, so α must come out exactly 0.
+                    m = jnp.full((1, 1), rowmax_ref[0, row + v], brow.dtype)
+                    s = jnp.full((1, 1), rowsum_ref[0, row + v], brow.dtype)
+                    m = jnp.where(jnp.isfinite(m), m, 0.0)
+                    den = jnp.where(s > 0, s, 1.0)
+                    vv = jnp.exp(vv - m) / den
+                r = pl.ds(row + v, 1)
+                out_ref[r, :] = out_ref[r, :] + vv * brow
+        return carry
+
+    jax.lax.fori_loop(0, K // SUBLANES, group, 0)
 
     if has_scale or has_bias or has_resid or activation != "none":
-        # Last (j, k) visit of this output block → the accumulated
-        # (R, Dblk) tile is complete for this dim tile; apply the fused
-        # epilogue while it is still VMEM-resident.
-        @pl.when((k == K - 1) & (fini_ref[c] == 1))
+        # Last visit of this output block → the accumulated (R, Dblk)
+        # tile is complete for this dim tile; apply the fused epilogue
+        # while it is still VMEM-resident.
+        @pl.when(fini_ref[c] == 1)
         def _epilogue():
             y = out_ref[...]
             if has_scale:
-                # per-row scales live in sublane 0, lanes 0..R−1 of the
-                # block's aligned stats tile
-                sc = scale_ref[0, pl.ds(0, y.shape[0])]
-                y = y * sc[:, None].astype(y.dtype)
+                y = y * scale_ref[...].astype(y.dtype)   # (R, 1) per row
             if has_bias:
-                y = y + bias_ref[0, :][None, :].astype(y.dtype)
+                y = y + bias_ref[0:1, :].astype(y.dtype)
             if has_resid:
                 # the residual operand's matching (R, Dblk) tile
                 y = y + resid_ref[...].astype(y.dtype)
@@ -123,19 +161,18 @@ def paramspmm_kernel(colidx, lrow, trow, init, fini, vals, B_padded, *,
                      n_blocks: int, R: int, V: int, K: int, dblk: int,
                      rowmax=None, rowsum=None, scale=None, bias=None,
                      residual=None, activation: str = "none",
-                     slope: float = 0.2, interpret: bool = True):
+                     slope: float = 0.2, interpret: bool | None = None):
     """Invoke the Pallas kernel on pre-padded operands.
 
     B_padded: (n_b, J·dblk).  Returns C_padded (n_blocks·R, J·dblk).
 
-    Optional fusion operands — the per-row ones all use the tile-aligned
-    stats layout ``(n_blocks·SUBLANES, LANES)`` (one (8, 128) f32 tile per
-    block, row r of block b at ``[b·SUBLANES, r]``), so every BlockSpec is
-    a whole hardware tile and the fused path compiles on real TPU:
-      rowmax/rowsum — softmax prologue stats (vals = logits), the fused
-                      SDDMM's native output layout;
-      scale         — per-row epilogue scale (degree norm), packed by
-                      ``ops._pack_scale``;
+    Optional fusion operands:
+      rowmax/rowsum (n_blocks·SUBLANES, LANES) — softmax prologue stats
+                      (vals = logits) in the fused SDDMM's native layout:
+                      one (8, 128) tile per block, row r of block b at
+                      ``[b·SUBLANES, r]``;
+      scale (n_blocks·R, 1) — per-row epilogue scale (degree norm),
+                      packed by ``ops._pack_scale``;
       bias (SUBLANES, J·dblk) — per-feature epilogue bias (row 0 real);
       residual (n_blocks·R, J·dblk) — dense epilogue addend in the
                       output's own padded block layout; each output
@@ -146,68 +183,73 @@ def paramspmm_kernel(colidx, lrow, trow, init, fini, vals, B_padded, *,
     if activation not in ACTIVATIONS:
         raise ValueError(f"activation {activation!r} not in {ACTIVATIONS}")
     assert R <= LANES, f"R={R} must fit one stats-tile lane row"
+    assert K % SUBLANES == 0, f"K={K} must be sublane-aligned"
     stats_shape = (n_blocks * SUBLANES, LANES)
-    for name, arr in (("rowmax", rowmax), ("rowsum", rowsum),
-                      ("scale", scale)):
+    for name, arr in (("rowmax", rowmax), ("rowsum", rowsum)):
         assert arr is None or arr.shape == stats_shape, (
             f"{name} must be tile-aligned {stats_shape}, got {arr.shape}")
+    assert scale is None or scale.shape == (n_blocks * R, 1), (
+        f"scale must be ({n_blocks * R}, 1), got {scale.shape}")
     C = trow.shape[0]
     dim_pad = B_padded.shape[1]
     assert dim_pad % dblk == 0
     assert bias is None or bias.shape == (SUBLANES, dim_pad), (
         f"bias must be ({SUBLANES}, {dim_pad}), got {bias.shape}")
     J = dim_pad // dblk
-    grid = (J, C, K)
     prologue = rowmax is not None
+    smem = pltpu.SMEM
 
-    in_specs = [
-        # whole chunk's vals; index map constant in k → fetched once/chunk
-        pl.BlockSpec((1, V, K), lambda j, c, k, ci, lr, tr, it, fi: (c, 0, 0)),
-        # the gather: B row chosen by the scalar-prefetched colidx
-        pl.BlockSpec((1, dblk),
-                     lambda j, c, k, ci, lr, tr, it, fi: (ci[c * K + k], j)),
-    ]
-    operands = [vals, B_padded]
+    def chunk_spec(rows):
+        return pl.BlockSpec((1, rows, K), lambda j, c, tr, it, fi: (c, 0, 0),
+                            memory_space=smem)
+
+    in_specs = [chunk_spec(1), chunk_spec(1), chunk_spec(V),
+                pl.BlockSpec(memory_space=pl.ANY)]          # B stays in HBM
+    operands = [colidx.reshape(C, 1, K), lrow.reshape(C, 1, K), vals,
+                B_padded]
     if prologue:
-        stats_spec = pl.BlockSpec(
-            (SUBLANES, LANES), lambda j, c, k, ci, lr, tr, it, fi: (tr[c], 0))
+        stats_spec = pl.BlockSpec((SUBLANES, LANES),
+                                  lambda j, c, tr, it, fi: (tr[c], 0),
+                                  memory_space=smem)
         in_specs += [stats_spec, stats_spec]
         operands += [rowmax, rowsum]
     if scale is not None:
         in_specs.append(pl.BlockSpec(
-            (SUBLANES, LANES), lambda j, c, k, ci, lr, tr, it, fi: (tr[c], 0)))
+            (R, 1), lambda j, c, tr, it, fi: (tr[c], 0)))
         operands.append(scale)
     if bias is not None:
         in_specs.append(pl.BlockSpec(
-            (SUBLANES, dblk), lambda j, c, k, ci, lr, tr, it, fi: (0, j)))
+            (SUBLANES, dblk), lambda j, c, tr, it, fi: (0, j)))
         operands.append(bias)
     if residual is not None:
         assert residual.shape == (n_blocks * R, dim_pad), (
             f"residual must match the padded output "
             f"({n_blocks * R}, {dim_pad}), got {residual.shape}")
         in_specs.append(pl.BlockSpec(
-            (R, dblk), lambda j, c, k, ci, lr, tr, it, fi: (tr[c], j)))
+            (R, dblk), lambda j, c, tr, it, fi: (tr[c], j)))
         operands.append(residual)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5,
-        grid=grid,
+        num_scalar_prefetch=3,
+        grid=(J, C),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((R, dblk),
-                               lambda j, c, k, ci, lr, tr, it, fi: (tr[c], j)),
+                               lambda j, c, tr, it, fi: (tr[c], j)),
+        scratch_shapes=[pltpu.VMEM((K, dblk), B_padded.dtype),
+                        pltpu.SemaphoreType.DMA(())],
     )
     fn = pl.pallas_call(
-        functools.partial(_kernel, V=V, K=K, prologue=prologue,
+        functools.partial(_kernel, V=V, K=K, dblk=dblk, prologue=prologue,
                           has_scale=scale is not None,
                           has_bias=bias is not None,
                           has_resid=residual is not None,
                           activation=activation, slope=slope),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_blocks * R, dim_pad), B_padded.dtype),
-        interpret=interpret,
+        interpret=resolve_backend("pallas", interpret)[1],
         name=f"paramspmm_v{V}_k{K}_r{R}_d{dblk}"
              f"{'_pro' if prologue else ''}"
              f"{'_res' if residual is not None else ''}"
              f"{'' if activation == 'none' else '_' + activation}",
     )
-    return fn(colidx, lrow, trow, init, fini, *operands)
+    return fn(trow, init, fini, *operands)

@@ -41,13 +41,10 @@ def _pad_cols(B, dblk: int):
 
 def _pack_scale(x, n_blocks: int, R: int):
     """Pack a flat per-row vector (≤ n_blocks·R entries) into the kernel's
-    tile-aligned per-row layout ``(n_blocks·SUBLANES, LANES)`` — one
-    (8, 128) tile per block, row r of block b at ``[b·SUBLANES, r]``."""
-    dense = jnp.pad(x.reshape(-1), (0, n_blocks * R - x.size)
-                    ).reshape(n_blocks, R)
-    out = jnp.zeros((n_blocks, SUBLANES, LANES), x.dtype)
-    out = out.at[:, 0, :R].set(dense)
-    return out.reshape(n_blocks * SUBLANES, LANES)
+    per-row column ``(n_blocks·R, 1)`` — block b's scales are the (R, 1)
+    tile at row ``b·R``, broadcast across lanes in the epilogue."""
+    return jnp.pad(x.reshape(-1), (0, n_blocks * R - x.size)
+                   ).reshape(n_blocks * R, 1)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -91,10 +88,10 @@ def _call(colidx, lrow, trow, init, fini, vals, B, rowmax=None, rowsum=None,
 
 
 def paramspmm(pcsr: PCSR, B, *, scale=None, bias=None, residual=None,
-              activation: str = "none", interpret: bool = True):
+              activation: str = "none", interpret: bool | None = None):
     """C = act(scale ⊙ (A·B) + bias + residual) where A is held as PCSR —
     the epilogue operands default to the identity (plain A·B).  Pallas
-    path (interpret on CPU)."""
+    path (``interpret=None``: compiled on a TPU, interpreted elsewhere)."""
     return paramspmm_with_vals(pcsr, None, B, scale=scale, bias=bias,
                                residual=residual, activation=activation,
                                interpret=interpret)
@@ -127,7 +124,7 @@ def _pad_chunk_vals(vals, n_extra: int, fill: float):
 
 def paramspmm_with_vals(pcsr: PCSR, vals, B, *, stats=None, scale=None,
                         bias=None, residual=None, activation: str = "none",
-                        interpret: bool = True):
+                        interpret: bool | None = None):
     """SpMM over A's *pattern* with per-slot values supplied at call time —
     the aggregation step of attention GNNs, where the PCSR topology is fixed
     but the edge weights change every step.  ``vals=None`` uses the values

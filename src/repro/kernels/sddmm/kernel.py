@@ -1,31 +1,35 @@
 """SDDMM Pallas TPU kernel — PCSR chunk traversal, dot-product reduction.
 
-Mirror image of ``kernels/paramspmm/kernel.py``: the same scalar-prefetched
-``colidx``/``lrow``/``trow`` arrays steer the grid, but the data flow is
-reversed — instead of scattering ``val · B[col]`` into an output block, each
-step *reduces* ``Q[row] · K[col]`` over a ``Dblk``-lane tile into the slot's
-score.  Grid ``(C, K, J)`` keeps the ``(1, V, K)`` output block resident in
-VMEM across all ``K·J`` steps of a chunk (consecutive revisits, the same
-trick the SpMM kernel plays with ``trow``), so partial dot products
-accumulate race-free in the sequential grid.
+Mirror image of ``kernels/paramspmm/kernel.py``: the same steering arrays
+drive the grid, but the data flow is reversed — instead of scattering
+``val · B[col]`` into an output block, each slot *reduces*
+``Q[row] · K[col]`` over a ``Dblk``-lane tile into the slot's score.
 
-Block selection per step ``(c, k, j)``:
-  Q block ``(V, Dblk)`` at panel ``trow[c]·W + lrow[c·K+k]`` — the paper's
-  coalesced dense-row access; K block ``(1, Dblk)`` at ``colidx[c·K+k]`` —
-  the one irregular gather, driven by scalar prefetch exactly as in SpMM.
+Grid ``(C, J)`` = (chunks, dim-tiles).  Per step the chunk's
+``colidx``/``lrow`` slices arrive in SMEM as pipelined blocks (only
+``trow``/``init`` are scalar-prefetched whole), its ``K`` key rows are
+gathered HBM → VMEM with manual async copies
+(``paramspmm.kernel.gather_rows``), and the query rows come from the
+target block's ``(R, Dblk)`` tile of ``Q`` — the paper's coalesced
+dense-row access.  Eight slots at a time, the query rows are staged
+into an aligned ``(8, Dblk)`` tile, multiplied with the eight gathered
+key rows and lane-reduced into an ``(8, 1)`` column of partial scores.
+Scores are kept slot-major — ``(C, K, V)``, slots on sublanes — inside
+the kernel and transposed to the PCSR slot layout ``(C, V, K)`` by the
+wrappers; the score block stays VMEM-resident across the ``J`` steps of
+a chunk, so partial dot products accumulate race-free.
 
 ``sddmm_softmax_kernel`` extends the same traversal with a fused edge
-softmax epilogue: when a slot's dot product completes (its last dim tile),
-the score is masked, scaled, LeakyReLU'd, and folded into per-row online
-softmax statistics kept in two tile-aligned ``(n_blocks·SUBLANES, LANES)``
-outputs addressed by ``trow[c]`` — one full ``(8, 128)`` f32 tile per
-block, row stats in sublane 0 / lanes 0..R−1 (R ≤ 32 < 128), so the
-block shape is exactly one hardware tile and the layout compiles on real
-TPU (a ``(1, R)`` block is neither sublane- nor lane-aligned and only
-works in interpret mode).  The same consecutive-revisit trick applies:
-with ``S=True`` a row split across chunks accumulates its max/normalizer
-exactly while the stats tile is VMEM resident.  ``ops.unpack_stats``
-recovers the dense ``(n_blocks, R)`` view for plain-JAX consumers.
+softmax epilogue: once a chunk's dot products are complete (its last dim
+tile), each score is masked, scaled, LeakyReLU'd, and folded slot by slot
+into per-row online softmax statistics kept in two tile-aligned
+``(n_blocks·SUBLANES, LANES)`` outputs addressed by ``trow[c]`` — one
+full ``(8, 128)`` f32 tile per block, row stats in sublane 0 / lanes
+0..R−1 (R ≤ 32 < 128), so the block shape is exactly one hardware tile.
+The same consecutive-revisit trick applies: with ``S=True`` a row split
+across chunks accumulates its max/normalizer exactly while the stats
+tile is VMEM resident.  ``ops.unpack_stats`` recovers the dense
+``(n_blocks, R)`` view for plain-JAX consumers.
 """
 from __future__ import annotations
 
@@ -37,84 +41,126 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.pcsr import LANES, SUBLANES
+from repro.kernels import resolve_backend
+from repro.kernels.paramspmm.kernel import gather_rows
 
 
-def _kernel(colidx_ref, lrow_ref, trow_ref,             # scalar prefetch
-            q_ref, k_ref,                               # VMEM inputs
-            out_ref,                                    # VMEM output
-            *, K: int):
-    k = pl.program_id(1)
-    j = pl.program_id(2)
+def _accumulate_scores(colidx_ref, lrow_ref, q_ref, k_hbm, score_ref, kbuf,
+                       qbuf, sem, *, V: int, K: int, dblk: int):
+    """score[k, v] += Q[row_k + v, tile j] · K[colidx_k, tile j] for every
+    slot of the chunk (``score_ref`` is the chunk's ``(1, K, V)`` block)."""
+    j = pl.program_id(1)
+    gather_rows(k_hbm, lambda k: colidx_ref[0, 0, k], kbuf, sem, n=K,
+                lane0=j * dblk, width=dblk)
 
-    # first step of this chunk's pass → zero the (1, V, K) score block
-    @pl.when((k == 0) & (j == 0))
+    def group(g, carry):
+        base = pl.multiple_of(g * SUBLANES, SUBLANES)
+        ktile = kbuf[pl.ds(base, SUBLANES), :]       # 8 gathered key rows
+        for v in range(V):
+            for i in range(SUBLANES):                # stage 8 query rows
+                row = lrow_ref[0, 0, base + i] * V + v
+                qbuf[i:i + 1, :] = q_ref[pl.ds(row, 1), :]
+            part = jnp.sum(qbuf[...] * ktile, axis=1, keepdims=True)
+            sl = (0, pl.ds(base, SUBLANES), pl.ds(v, 1))
+            score_ref[sl] = score_ref[sl] + part
+        return carry
+
+    jax.lax.fori_loop(0, K // SUBLANES, group, 0)
+
+
+def _scratch(K: int, dblk: int, dtype):
+    return [pltpu.VMEM((K, dblk), dtype), pltpu.VMEM((SUBLANES, dblk), dtype),
+            pltpu.SemaphoreType.DMA(())]
+
+
+def _chunk_spec(K: int, index_map):
+    return pl.BlockSpec((1, 1, K), index_map, memory_space=pltpu.SMEM)
+
+
+def _kernel(trow_ref, colidx_ref, lrow_ref, q_ref, k_hbm,
+            out_ref, kbuf, qbuf, sem, *, V: int, K: int, dblk: int):
+    # first dim tile of this chunk → zero its score block
+    @pl.when(pl.program_id(1) == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    qv = q_ref[...]                          # (V, Dblk) query panel
-    kv = k_ref[0, :]                         # (Dblk,) gathered key row
-    partial = jnp.sum(qv * kv[None, :], axis=1)          # (V,)
-    out_ref[0, :, k] = out_ref[0, :, k] + partial
+    _accumulate_scores(colidx_ref, lrow_ref, q_ref, k_hbm, out_ref, kbuf,
+                       qbuf, sem, V=V, K=K, dblk=dblk)
 
 
-def _fused_kernel(colidx_ref, lrow_ref, trow_ref, init_ref,   # scalar prefetch
-                  vals_ref, q_ref, k_ref,                     # VMEM inputs
-                  score_ref, rowmax_ref, rowsum_ref,          # VMEM outputs
-                  *, V: int, K: int, J: int, scale: float, slope: float):
+def _fused_kernel(trow_ref, init_ref,                       # scalar prefetch
+                  colidx_ref, lrow_ref, vals_ref, q_ref, k_hbm,
+                  score_ref, rowmax_ref, rowsum_ref,        # outputs
+                  kbuf, qbuf, sem,
+                  *, V: int, K: int, J: int, dblk: int, scale: float,
+                  slope: float):
     c = pl.program_id(0)
-    k = pl.program_id(1)
-    j = pl.program_id(2)
+    j = pl.program_id(1)
 
-    @pl.when((k == 0) & (j == 0))
+    @pl.when(j == 0)
     def _init_scores():
         score_ref[...] = jnp.zeros_like(score_ref)
 
     # first chunk of this output block → reset its softmax running stats
-    @pl.when((k == 0) & (j == 0) & (init_ref[c] == 1))
+    @pl.when((j == 0) & (init_ref[c] == 1))
     def _init_stats():
         rowmax_ref[...] = jnp.full(rowmax_ref.shape, -jnp.inf,
                                    rowmax_ref.dtype)
         rowsum_ref[...] = jnp.zeros_like(rowsum_ref)
 
-    qv = q_ref[...]                          # (V, Dblk) query panel
-    kv = k_ref[0, :]                         # (Dblk,) gathered key row
-    acc = score_ref[0, :, k] + jnp.sum(qv * kv[None, :], axis=1)
-    score_ref[0, :, k] = acc
+    _accumulate_scores(colidx_ref, lrow_ref, q_ref, k_hbm, score_ref, kbuf,
+                       qbuf, sem, V=V, K=K, dblk=dblk)
 
-    # Softmax epilogue: once the slot's dot product is complete (last dim
-    # tile), scale + LeakyReLU it and fold it into the block's running
-    # row-max / row-sum-of-exp (flash-attention-style online rescale).  The
-    # stats tile lives at trow[c] (one aligned (8, 128) tile per block, row
-    # stats in sublane 0), so split chunks of one block accumulate into the
-    # same VMEM-resident tiles across consecutive revisits.
+    # Softmax epilogue: once the chunk's dot products are complete (last
+    # dim tile), scale + LeakyReLU every slot and fold it, in slot order,
+    # into the block's running row-max / row-sum-of-exp
+    # (flash-attention-style online rescale).  The stats tile lives at
+    # trow[c] (row stats in sublane 0, one lane per row), so split chunks
+    # of one block accumulate into the same VMEM-resident tiles across
+    # consecutive revisits.
     @pl.when(j == J - 1)
     def _epilogue():
-        m = vals_ref[0, :, k] != 0           # (V,) real-edge mask
-        x = acc * scale
-        x = jnp.where(x >= 0, x, slope * x)  # LeakyReLU
-        # masked/padding slots publish −inf: downstream α = exp(logit − m)/Σ
-        # (the SpMM prologue, or the backward's recompute) then comes out
-        # exactly 0 with no separate mask operand.
-        score_ref[0, :, k] = jnp.where(m, x, -jnp.inf)
-        xm = jnp.where(m, x, -jnp.inf)       # padding never drives max/sum
-        row = lrow_ref[c * K + k] * V
-        m_old = rowmax_ref[0, pl.ds(row, V)]
-        s_old = rowsum_ref[0, pl.ds(row, V)]
-        m_new = jnp.maximum(m_old, xm)
-        finite = jnp.isfinite(m_new)         # rows with ≥1 real edge so far
-        s_scale = jnp.exp(jnp.where(finite, m_old - m_new, 0.0))
-        contrib = jnp.exp(jnp.where(finite, xm - m_new, -jnp.inf))
-        rowmax_ref[0, pl.ds(row, V)] = m_new
-        rowsum_ref[0, pl.ds(row, V)] = s_old * s_scale + contrib
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
+
+        def group(g, stats):
+            m_row, s_row = stats
+            base = pl.multiple_of(g * SUBLANES, SUBLANES)
+            for v in range(V):
+                sl = (0, pl.ds(base, SUBLANES), pl.ds(v, 1))
+                m = vals_ref[sl] != 0                 # (8, 1) real-edge mask
+                x = score_ref[sl] * scale
+                x = jnp.where(x >= 0, x, slope * x)   # LeakyReLU
+                # masked/padding slots publish −inf: downstream
+                # α = exp(logit − m)/Σ (the SpMM prologue, or the
+                # backward's recompute) then comes out exactly 0 with no
+                # separate mask operand; padding never drives max/sum.
+                xm = jnp.where(m, x, -jnp.inf)
+                score_ref[sl] = xm
+                for i in range(SUBLANES):
+                    row = lrow_ref[0, 0, base + i] * V + v
+                    hit = lane == row
+                    xi = jnp.broadcast_to(xm[i:i + 1, :], (1, LANES))
+                    m_new = jnp.maximum(m_row, xi)
+                    finite = jnp.isfinite(m_new)      # ≥1 real edge so far
+                    s_scale = jnp.exp(jnp.where(finite, m_row - m_new, 0.0))
+                    contrib = jnp.exp(jnp.where(finite, xi - m_new, -jnp.inf))
+                    s_row = jnp.where(hit, s_row * s_scale + contrib, s_row)
+                    m_row = jnp.where(hit, m_new, m_row)
+            return m_row, s_row
+
+        m_row, s_row = jax.lax.fori_loop(
+            0, K // SUBLANES, group, (rowmax_ref[0:1, :], rowsum_ref[0:1, :]))
+        rowmax_ref[0:1, :] = m_row
+        rowsum_ref[0:1, :] = s_row
 
 
 def sddmm_softmax_kernel(colidx, lrow, trow, init, vals, Q_padded, K_padded, *,
                          n_blocks: int, W: int, V: int, K: int, dblk: int,
-                         scale: float, slope: float, interpret: bool = True):
+                         scale: float, slope: float, interpret: bool | None = None):
     """Fused SDDMM → edge-softmax statistics, one grid pass.
 
-    Same (C, K, J) traversal as ``sddmm_kernel``, plus an epilogue on each
-    slot's final dim tile that applies ``scale`` and LeakyReLU(``slope``),
+    Same (C, J) traversal as ``sddmm_kernel``, plus an epilogue on each
+    chunk's final dim tile that applies ``scale`` and LeakyReLU(``slope``),
     masks padding slots to −inf, and maintains per-row online-softmax
     statistics in two extra tile-aligned ``(n_blocks·SUBLANES, LANES)``
     outputs (one (8, 128) tile per block; row r of block b lives at
@@ -129,53 +175,51 @@ def sddmm_softmax_kernel(colidx, lrow, trow, init, vals, Q_padded, K_padded, *,
     C = trow.shape[0]
     R = V * W
     assert R <= LANES, f"R={R} must fit one stats-tile lane row"
+    assert K % SUBLANES == 0, f"K={K} must be sublane-aligned"
     dim_pad = Q_padded.shape[1]
     assert dim_pad % dblk == 0
-    assert Q_padded.shape[0] % V == 0
+    assert Q_padded.shape[0] % R == 0
     J = dim_pad // dblk
-    grid = (C, K, J)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=grid,
+        num_scalar_prefetch=2,
+        grid=(C, J),
         in_specs=[
-            # whole chunk's vals (the edge mask); constant in k, j
-            pl.BlockSpec((1, V, K),
-                         lambda c, k, j, ci, lr, tr, it: (c, 0, 0)),
-            # query panel: V rows addressed by block·W + local panel index
-            pl.BlockSpec((V, dblk),
-                         lambda c, k, j, ci, lr, tr, it:
-                         (tr[c] * W + lr[c * K + k], j)),
-            # the gather: K row chosen by the scalar-prefetched colidx
-            pl.BlockSpec((1, dblk),
-                         lambda c, k, j, ci, lr, tr, it: (ci[c * K + k], j)),
+            _chunk_spec(K, lambda c, j, tr, it: (c, 0, 0)),
+            _chunk_spec(K, lambda c, j, tr, it: (c, 0, 0)),
+            # the chunk's slot values, slot-major (the edge mask)
+            pl.BlockSpec((1, K, V), lambda c, j, tr, it: (c, 0, 0)),
+            # the target block's query rows
+            pl.BlockSpec((R, dblk), lambda c, j, tr, it: (tr[c], j)),
+            pl.BlockSpec(memory_space=pl.ANY),        # keys stay in HBM
         ],
         out_specs=[
-            pl.BlockSpec((1, V, K),
-                         lambda c, k, j, ci, lr, tr, it: (c, 0, 0)),
-            pl.BlockSpec((SUBLANES, LANES),
-                         lambda c, k, j, ci, lr, tr, it: (tr[c], 0)),
-            pl.BlockSpec((SUBLANES, LANES),
-                         lambda c, k, j, ci, lr, tr, it: (tr[c], 0)),
+            pl.BlockSpec((1, K, V), lambda c, j, tr, it: (c, 0, 0)),
+            pl.BlockSpec((SUBLANES, LANES), lambda c, j, tr, it: (tr[c], 0)),
+            pl.BlockSpec((SUBLANES, LANES), lambda c, j, tr, it: (tr[c], 0)),
         ],
+        scratch_shapes=_scratch(K, dblk, Q_padded.dtype),
     )
     fn = pl.pallas_call(
-        functools.partial(_fused_kernel, V=V, K=K, J=J,
+        functools.partial(_fused_kernel, V=V, K=K, J=J, dblk=dblk,
                           scale=scale, slope=slope),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((C, V, K), Q_padded.dtype),
+            jax.ShapeDtypeStruct((C, K, V), Q_padded.dtype),
             jax.ShapeDtypeStruct((n_blocks * SUBLANES, LANES), Q_padded.dtype),
             jax.ShapeDtypeStruct((n_blocks * SUBLANES, LANES), Q_padded.dtype),
         ],
-        interpret=interpret,
+        interpret=resolve_backend("pallas", interpret)[1],
         name=f"sddmm_softmax_v{V}_k{K}_w{W}_d{dblk}",
     )
-    return fn(colidx, lrow, trow, init, vals, Q_padded, K_padded)
+    logits, rowmax, rowsum = fn(trow, init, colidx.reshape(C, 1, K),
+                                lrow.reshape(C, 1, K),
+                                jnp.swapaxes(vals, 1, 2), Q_padded, K_padded)
+    return jnp.swapaxes(logits, 1, 2), rowmax, rowsum
 
 
 def sddmm_kernel(colidx, lrow, trow, Q_padded, K_padded, *,
                  W: int, V: int, K: int, dblk: int,
-                 interpret: bool = True):
+                 interpret: bool | None = None):
     """Raw per-slot scores on pre-padded operands.
 
     Q_padded: (n_blocks·R, J·dblk); K_padded: (n_k, J·dblk).
@@ -183,30 +227,32 @@ def sddmm_kernel(colidx, lrow, trow, Q_padded, K_padded, *,
     the ops.py wrapper applies the ``vals != 0`` sampling mask).
     """
     C = trow.shape[0]
+    R = V * W
+    assert K % SUBLANES == 0, f"K={K} must be sublane-aligned"
     dim_pad = Q_padded.shape[1]
     assert dim_pad % dblk == 0
-    assert Q_padded.shape[0] % V == 0
+    assert Q_padded.shape[0] % R == 0
     J = dim_pad // dblk
-    grid = (C, K, J)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=grid,
+        num_scalar_prefetch=1,
+        grid=(C, J),
         in_specs=[
-            # query panel: V rows addressed by block·W + local panel index
-            pl.BlockSpec((V, dblk),
-                         lambda c, k, j, ci, lr, tr: (tr[c] * W + lr[c * K + k], j)),
-            # the gather: K row chosen by the scalar-prefetched colidx
-            pl.BlockSpec((1, dblk),
-                         lambda c, k, j, ci, lr, tr: (ci[c * K + k], j)),
+            _chunk_spec(K, lambda c, j, tr: (c, 0, 0)),
+            _chunk_spec(K, lambda c, j, tr: (c, 0, 0)),
+            # the target block's query rows
+            pl.BlockSpec((R, dblk), lambda c, j, tr: (tr[c], j)),
+            pl.BlockSpec(memory_space=pl.ANY),        # keys stay in HBM
         ],
-        out_specs=pl.BlockSpec((1, V, K),
-                               lambda c, k, j, ci, lr, tr: (c, 0, 0)),
+        out_specs=pl.BlockSpec((1, K, V), lambda c, j, tr: (c, 0, 0)),
+        scratch_shapes=_scratch(K, dblk, Q_padded.dtype),
     )
     fn = pl.pallas_call(
-        functools.partial(_kernel, K=K),
+        functools.partial(_kernel, V=V, K=K, dblk=dblk),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((C, V, K), Q_padded.dtype),
-        interpret=interpret,
+        out_shape=jax.ShapeDtypeStruct((C, K, V), Q_padded.dtype),
+        interpret=resolve_backend("pallas", interpret)[1],
         name=f"sddmm_v{V}_k{K}_w{W}_d{dblk}",
     )
-    return fn(colidx, lrow, trow, Q_padded, K_padded)
+    scores = fn(trow, colidx.reshape(C, 1, K), lrow.reshape(C, 1, K),
+                Q_padded, K_padded)
+    return jnp.swapaxes(scores, 1, 2)

@@ -38,7 +38,7 @@ def unpack_stats(stats, R: int):
     The fused SDDMM keeps per-row softmax stats in one aligned
     ``(SUBLANES, LANES)`` tile per block — row r of block b at
     ``[b·SUBLANES, r]`` — so the stats BlockSpec is exactly one hardware
-    tile and compiles on real TPU.  Plain-JAX consumers (the reference
+    tile, as the TPU compiler requires.  Plain-JAX consumers (the reference
     normalize, the flash-recompute backward, the distributed GAT
     branches) call this to recover the dense view."""
     lead = stats.shape[:-2]
@@ -79,7 +79,7 @@ def _call(colidx, lrow, trow, vals, Q, K_mat, *, H, n_blocks, R, W, V, K,
     return jnp.where(vals != 0, scores, 0.0)
 
 
-def sddmm(pcsr: PCSR, Q, K, *, interpret: bool = True):
+def sddmm(pcsr: PCSR, Q, K, *, interpret: bool | None = None):
     """E = (A≠0) ⊙ (Q·Kᵀ) in PCSR slot layout. Pallas path.
 
     ``Q``/``K`` of shape (n, d) return (C, V, K) slots; (H, n, d) stacks
@@ -141,7 +141,7 @@ def normalize_from_stats(logits, rowmax, rowsum, lrow, trow, *, R, V, K):
 
 
 def sddmm_softmax_stats(pcsr: PCSR, Q, K, *, scale: float | None = None,
-                        slope: float = 0.2, interpret: bool = True):
+                        slope: float = 0.2, interpret: bool | None = None):
     """The fused GAT attention front half, *stats form*: one kernel pass
     returning ``(logits, rowmax, rowsum)`` — raw post-LeakyReLU logits in
     slot layout (masked slots −inf) plus the per-row online-softmax
@@ -189,7 +189,7 @@ def _normalize_heads(logits, rowmax, rowsum, lrow, trow, *, R, V, K, H):
 
 
 def sddmm_softmax(pcsr: PCSR, Q, K, *, scale: float | None = None,
-                  slope: float = 0.2, interpret: bool = True,
+                  slope: float = 0.2, interpret: bool | None = None,
                   with_logits: bool = False):
     """Fused GAT attention weights: softmax_row(LeakyReLU(scale·Q·Kᵀ)) on
     A's sparsity pattern, in PCSR slot layout. Pallas path.

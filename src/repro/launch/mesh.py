@@ -14,19 +14,14 @@ import jax
 import numpy as np
 
 
-def _axis_type_kwargs(n_axes: int) -> dict:
-    """``axis_types`` only where the installed jax has it (≥ 0.5 introduced
-    ``jax.sharding.AxisType``); on older releases every axis is Auto anyway."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
+def _auto(n_axes: int) -> tuple:
+    return (jax.sharding.AxisType.Auto,) * n_axes
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
 
 
 def make_host_mesh(model_parallel: int = 1):
@@ -35,7 +30,7 @@ def make_host_mesh(model_parallel: int = 1):
     assert n % model_parallel == 0
     return jax.make_mesh(
         (n // model_parallel, model_parallel), ("data", "model"),
-        **_axis_type_kwargs(2))
+        axis_types=_auto(2))
 
 
 def make_partition_mesh(n_parts: int, devices=None):
@@ -44,19 +39,15 @@ def make_partition_mesh(n_parts: int, devices=None):
     Kept separate from the data/model training meshes: graph partitions
     are a *spatial* split of one sparse operator, not batch parallelism
     (a ``DistGraph`` can later be nested under an outer data axis by
-    passing a submesh here via ``devices``).  Axes are explicitly Auto
-    where the installed jax distinguishes axis types, matching the
-    training meshes above."""
+    passing a submesh here via ``devices``).  The axis is Auto, matching
+    the training meshes above."""
     devs = list(jax.devices()) if devices is None else list(devices)
     if n_parts > len(devs):
         raise ValueError(
             f"{n_parts} partitions need {n_parts} devices, have {len(devs)} "
             "(CPU: set XLA_FLAGS=--xla_force_host_platform_device_count=N)")
-    try:
-        return jax.sharding.Mesh(np.asarray(devs[:n_parts]), ("parts",),
-                                 **_axis_type_kwargs(1))
-    except TypeError:          # older jax: Mesh has no axis_types kwarg
-        return jax.sharding.Mesh(np.asarray(devs[:n_parts]), ("parts",))
+    return jax.sharding.Mesh(np.asarray(devs[:n_parts]), ("parts",),
+                             axis_types=_auto(1))
 
 
 def data_axes(mesh) -> tuple:

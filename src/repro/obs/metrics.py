@@ -170,10 +170,7 @@ def _install_pallas_probe() -> None:
     global _PROBE_ORIG
     if _PROBE_ORIG is not None:
         return
-    try:
-        from jax.experimental import pallas as pl
-    except ImportError:                               # pragma: no cover
-        return
+    from jax.experimental import pallas as pl
     orig = pl.pallas_call
 
     def probed(*a, **kw):

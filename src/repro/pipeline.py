@@ -62,8 +62,8 @@ class ParamSpMM:
                  config: Optional[SpMMConfig] = None,
                  decider: Optional[SpMMDecider] = None,
                  reorder: bool = True,
-                 backend: str = "engine",
-                 interpret: bool = True,
+                 backend: str | None = None,
+                 interpret: bool | None = None,
                  build_transpose: bool = True,
                  select: str = "model",
                  op: str = "spmm",

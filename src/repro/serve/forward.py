@@ -34,6 +34,7 @@ from repro.core.engine import (_engine, _engine_sddmm, _slot_rows,
                                apply_epilogue, attend_scores, engine_spmm,
                                engine_spmm_fused, make_gat_message_fn)
 from repro.core.pcsr import build_pcsr
+from repro.kernels import resolve_backend
 from repro.models.gnn import gat_forward, gcn_forward, gin_forward
 from repro.obs import metrics as _metrics
 
@@ -123,7 +124,7 @@ def _bucket_gat_msg(steer, geom: PackGeom, backend: str, interpret: bool,
 @functools.partial(jax.jit,
                    static_argnames=("geom", "model", "backend", "interpret"))
 def bucket_forward(steer, X, params, *, geom: PackGeom, model: str,
-                   backend: str = "engine", interpret: bool = True):
+                   backend: str, interpret: bool):
     """Full GNN forward on one bucket-padded batch.
 
     Traced: ``steer`` (steering dict from ``steering_arrays``), ``X``
@@ -148,12 +149,14 @@ def bucket_forward(steer, X, params, *, geom: PackGeom, model: str,
 
 
 def reference_forward(csr, X, params, *, model: str, config,
-                      backend: str = "engine", interpret: bool = True):
+                      backend: str | None = None,
+                      interpret: bool | None = None):
     """The full-pipeline forward on an *unpadded* subgraph — the serving
     exactness oracle.  Builds a fresh PCSR under ``config`` (pass the
     serving pack's config: GAT's softmax is layout-sensitive) and runs
     the same ``models.gnn`` forward through the standard closure
     builders."""
+    backend, interpret = resolve_backend(backend, interpret)
     p = build_pcsr(csr.indptr, csr.indices, csr.data, csr.n_rows,
                    csr.n_cols, config)
     X = jnp.asarray(X)

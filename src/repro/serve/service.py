@@ -26,6 +26,7 @@ import numpy as np
 
 from repro.core.sparse import CSRMatrix
 from repro.data.graphs import extract_subgraph, sample_khop
+from repro.kernels import resolve_backend
 from repro.obs import metrics as _metrics
 from repro.obs import span
 
@@ -83,8 +84,8 @@ class GNNService:
     """
 
     def __init__(self, csr: CSRMatrix, features, params, *,
-                 model: str = "gcn", backend: str = "engine",
-                 interpret: bool = True,
+                 model: str = "gcn", backend: str | None = None,
+                 interpret: bool | None = None,
                  policy: BucketPolicy | None = None,
                  cache_capacity: int = 8, decider=None,
                  max_batch: int = 32, keep_subgraphs: bool = False):
@@ -94,8 +95,7 @@ class GNNService:
         self.features = np.asarray(features, np.float32)
         self.params = params
         self.model = model
-        self.backend = backend
-        self.interpret = interpret
+        self.backend, self.interpret = resolve_backend(backend, interpret)
         self.policy = policy or BucketPolicy.default()
         self.keep_subgraphs = keep_subgraphs
         self.cache = SteeringPackCache(

@@ -1,0 +1,122 @@
+"""Compile the main-path kernels for a described TPU v5e, at the smoke
+graph's real steering sizes, without a chip.
+
+The smoke graph (``chip_smoke.py``: 65,536 nodes, 1.47M nonzeros after
+GCN normalization) packs under its picked ⟨W=16, F=1, V=2, S, B⟩ into
+2,066 chunks of K = 776 slots — 1,603,216 slots — over 2,048 output
+blocks of R = 32 rows.  Every kernel form of the GCN/GIN/GAT path is
+compiled at that size, single-head and 4-head tiled; then one whole
+jitted GCN value-and-grad step, whose device memory must fit a v5e.
+
+The topology is described inside a module fixture, never at import: only
+the test worker that runs this file loads the TPU compiler.
+"""
+from __future__ import annotations
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core.pcsr import LANES, SUBLANES
+
+C, K, V, W, DBLK, N = 2066, 776, 2, 16, 128, 65536
+R, NB = V * W, 2048
+V5E_HBM_BYTES = 16 * 1024**3
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:                       # noqa: BLE001
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _form(name: str, H: int, sd):
+    """(fn, argument shapes) of one kernel form over H head-tiled
+    copies of the smoke graph's steering."""
+    from repro.kernels.paramspmm.kernel import paramspmm_kernel
+    from repro.kernels.sddmm.kernel import sddmm_kernel, sddmm_softmax_kernel
+    c, nb, n = H * C, H * NB, H * N
+    f32, i32 = jnp.float32, jnp.int32
+    steer = [sd((c * K,), i32), sd((c * K,), i32), sd((c,), i32),
+             sd((c,), i32), sd((c,), i32)]
+    vals, B = sd((c, V, K), f32), sd((n, DBLK), f32)
+    stats = sd((nb * SUBLANES, LANES), f32)
+    kw = dict(n_blocks=nb, R=R, V=V, K=K, dblk=DBLK, interpret=False)
+    if name == "spmm":
+        return (lambda *a: paramspmm_kernel(*a, **kw)), steer + [vals, B]
+    if name == "spmm_epilogue":        # GCN: degree scale + bias + ReLU
+        return (lambda *a: paramspmm_kernel(
+            *a[:7], scale=a[7], bias=a[8], activation="relu", **kw),
+            steer + [vals, B, sd((nb * R, 1), f32),
+                     sd((SUBLANES, DBLK), f32)])
+    if name == "spmm_residual":        # GIN: (1+ε)h + A·h
+        return (lambda *a: paramspmm_kernel(*a[:7], residual=a[7], **kw),
+                steer + [vals, B, sd((nb * R, DBLK), f32)])
+    if name == "spmm_softmax_prologue":  # GAT aggregation
+        return (lambda *a: paramspmm_kernel(
+            *a[:7], rowmax=a[7], rowsum=a[8], **kw),
+            steer + [vals, B, stats, stats])
+    if name == "sddmm_softmax":        # GAT scores + row stats
+        return (lambda *a: sddmm_softmax_kernel(
+            *a, n_blocks=nb, W=W, V=V, K=K, dblk=DBLK, scale=0.25,
+            slope=0.2, interpret=False),
+            steer[:4] + [vals, sd((nb * R, DBLK), f32), B])
+    assert name == "sddmm"             # GAT backward dα
+    return (lambda *a: sddmm_kernel(*a, W=W, V=V, K=K, dblk=DBLK,
+                                    interpret=False),
+            steer[:3] + [sd((nb * R, DBLK), f32), B])
+
+
+@pytest.mark.parametrize("H", [1, 4])
+@pytest.mark.parametrize("name", ["spmm", "spmm_epilogue", "spmm_residual",
+                                  "spmm_softmax_prologue", "sddmm_softmax",
+                                  "sddmm"])
+def test_kernel_compiles_for_v5e(one_chip, name, H):
+    sd = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    fn, args = _form(name, H, sd)
+    hlo = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in hlo
+
+
+def test_gcn_train_step_compiles_and_fits_v5e(one_chip):
+    """One whole jitted GCN value-and-grad step (configs/gcn.py CONFIG)
+    on the smoke graph through the default ParamSpMM operator: kernels
+    in the HLO, device memory within a v5e's 16 GB."""
+    from repro.configs.gcn import CONFIG
+    from repro.data.tasks import community_task
+    from repro.models.gnn import gcn_forward, init_gcn, node_ce_loss
+    from repro.pipeline import ParamSpMM
+
+    task = community_task(n_blocks=16, block_size=4096, p_in=0.005)
+    op = ParamSpMM(task.csr.gcn_normalize(), CONFIG["hidden"],
+                   backend="pallas", interpret=False)
+    p = op.op.pcsr
+    assert (p.num_chunks, p.K, p.config.V, p.config.W) == (C, K, V, W)
+    dims = ([CONFIG["in_dim"]] + [CONFIG["hidden"]] * (CONFIG["n_layers"] - 1)
+            + [CONFIG["out_dim"]])
+    params = init_gcn(jax.random.PRNGKey(0), dims)
+    sd = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+    n = task.csr.n_rows
+    args = (jax.tree.map(sd, params), sd(jnp.zeros((n, CONFIG["in_dim"]))),
+            sd(jnp.zeros(n, jnp.int32)), sd(jnp.zeros(n)))
+
+    def loss(params, X, labels, mask):
+        return node_ce_loss(gcn_forward(params, X, op), labels, mask)
+
+    compiled = jax.jit(jax.value_and_grad(loss)).lower(*args).compile()
+    # forward: 5 aggregations; backward: 4 transpose aggregations (the
+    # first layer's input needs no gradient)
+    assert compiled.as_text().count("tpu_custom_call") >= 9
+    mem = compiled.memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes + mem.generated_code_size_in_bytes)
+    assert used < V5E_HBM_BYTES
