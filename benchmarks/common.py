@@ -109,12 +109,12 @@ def gflops(csr, dim, seconds):
 
 def count_pallas_calls(fn):
     """Run ``fn`` with the Pallas dispatch intercepted; return the kernel
-    names in launch order (trace-time count == launch count per call).
-    The ONE shared counter — `tests/test_fusion.py` asserts on it,
-    `bench_fusion` records it into BENCH_spmm.json, and the obs layer's
-    tracing probe feeds ``pallas_calls_total`` through the same
-    ``repro.obs.metrics.intercept_pallas`` hook, so none of the three
-    can disagree about what counts as a kernel launch."""
+    names in launch order.  The dispatch is seen while ``fn`` is traced,
+    so this counts the launches of one call of an uncompiled ``fn``; a
+    call that hits the jit cache re-traces nothing and counts none.
+    The one shared counter — `tests/test_fusion.py` asserts on it and
+    `bench_fusion` records it into BENCH_spmm.json, both through
+    ``repro.obs.metrics.intercept_pallas``."""
     from repro.obs.metrics import intercept_pallas
 
     calls: list[str] = []

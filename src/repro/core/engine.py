@@ -18,6 +18,7 @@ function docstring and docs/OPERATORS.md for the exact pipelines.
 from __future__ import annotations
 
 import functools
+from time import time_ns
 from typing import Optional
 
 import jax
@@ -25,6 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import resolve_backend
+from repro.obs import metrics as _obs_metrics
 
 from .pcsr import PCSR, SpMMConfig, build_pcsr
 from .sparse import CSRMatrix
@@ -115,6 +117,22 @@ def attend_scores(scores, mask, rows, n_segments: int, *,
     return edge_softmax(scaled, mask, rows, n_segments)
 
 
+def _observed_build(op: str):
+    """Record each call's wall time in ``spmm_build_seconds{op}``: the
+    host set-up of an aggregation operator, beside ``ParamSpMM``'s."""
+    def wrap(build):
+        @functools.wraps(build)
+        def observed(*args, **kwargs):
+            t0 = time_ns()
+            out = build(*args, **kwargs)
+            _obs_metrics.histogram("spmm_build_seconds").observe(
+                (time_ns() - t0) * 1e-9, op=op)
+            return out
+        return observed
+    return wrap
+
+
+@_observed_build("gat_message")
 def make_gat_message_fn(pcsr: PCSR, pcsr_t: Optional[PCSR] = None, *,
                         backend: str | None = None,
                         interpret: bool | None = None, slope: float = 0.2):

@@ -62,10 +62,9 @@ from jax.sharding import PartitionSpec
 
 from repro.core import (CostModel, CSRMatrix, SpMMConfig, build_pcsr,
                         config_space, extract_features)
-from repro.core.cost_model import halo_exchange_cost, overlap_exposed_cost
 from repro.core.engine import _engine, apply_epilogue, epilogue_grad
 from repro.kernels import resolve_backend
-from repro.obs import metrics as _obs_metrics, trace as _obs_trace
+from repro.obs import trace as _obs_trace
 
 from .gat import build_dist_gat, build_gat_pack
 from .halo import HaloSpec, build_halo, halo_exchange, halo_scatter_back
@@ -253,22 +252,6 @@ class DistGraph:
                                              hal.n_cols, hc))
             self._loc = pack_shards(loc_pcsrs)
             self._halo_pack = pack_shards(halo_pcsrs)
-            if _obs_trace.trace_enabled():
-                # priced overlap decomposition per shard: the wire time
-                # the schedule is trying to hide vs what stays exposed
-                exch = halo_exchange_cost(self.halo.gathered_rows, dim)
-                _obs_metrics.gauge("halo_exchange_priced_seconds").set(exch)
-                for i, ((loc, hal), (lc, hc)) in enumerate(
-                        zip(self._split_csrs, self.overlap_configs)):
-                    tl = CostModel(loc, calibration=calibration).time(
-                        dim, lc, H=heads)
-                    th = CostModel(hal, calibration=calibration).time(
-                        dim, hc, H=heads)
-                    _obs_metrics.gauge("overlap_exposed_seconds").set(
-                        overlap_exposed_cost(tl, th, exch), shard=i)
-                    _obs_metrics.gauge("overlap_serialized_seconds").set(
-                        tl + th + exch, shard=i)
-
         self._bwd_pack = None              # transpose PCSRs built on first
         self._bwd_split_pack = None        # backward only — forward-only /
         # GAT (engine-autodiff) use skips them

@@ -19,7 +19,7 @@ from __future__ import annotations
 
 import threading
 from dataclasses import dataclass, field
-from time import time as _walltime
+from time import time_ns
 from typing import Any, Optional
 
 from repro.obs import metrics as _metrics, trace as _trace
@@ -96,7 +96,7 @@ class DecisionRecord:
     topk: list                      # [{"config": [...], "seconds"|"score"}]
     snapshot: dict                  # input features the pick was based on
     calibration: Optional[str]      # artifact id, None = analytic prices
-    walltime: float = field(default_factory=_walltime)
+    walltime: float = field(default_factory=lambda: time_ns() * 1e-9)
 
     def to_dict(self) -> dict:
         return {

@@ -1,28 +1,26 @@
 """Labeled counter/gauge/histogram registry for the obs layer.
 
 Instruments are cheap named handles — ``counter("pack_cache_hits_total")``
-returns the same object every call — and every mutating method
-(``inc``/``set``/``observe``) is a no-op unless a tracing session is
-active, so instrumented hot paths cost a dict lookup and a boolean check
-when the layer is off.  Label sets distinguish series within one
-instrument (``inc(kind="steering")``); ``metrics_snapshot()`` renders
-everything into plain JSON-ready dicts keyed ``"k=v,k2=v2"``.
+returns the same object every call.  Every mutating method
+(``inc``/``set``/``observe``) records whether or not a tracing session
+is open: each is a lock and a dict update, placed at layer boundaries
+and never inside a jitted step.  ``start_tracing`` resets the registry,
+so a session's snapshot covers its own window.  Label sets distinguish
+series within one instrument (``inc(kind="steering")``);
+``metrics_snapshot()`` renders everything into plain JSON-ready dicts
+keyed ``"k=v,k2=v2"``.
 
 The module also owns the one Pallas launch-count definition:
 ``intercept_pallas(callback)`` patches ``pl.pallas_call`` so each
 dispatch reports ``kw.get("name", "?")`` — trace-time count == launch
-count per call.  ``benchmarks.common.count_pallas_calls`` delegates here
-and a probe installed for the duration of a tracing session feeds the
-``pallas_calls_total{kernel=...}`` counter, so the bench, the fusion
-tests, and the trace can never disagree about what counts as a launch.
+count per call.  ``benchmarks.common.count_pallas_calls`` and the fusion
+tests both count through it.
 """
 from __future__ import annotations
 
 import threading
 from contextlib import contextmanager
 from typing import Callable
-
-from repro.obs import trace as _trace
 
 __all__ = [
     "counter", "gauge", "histogram",
@@ -54,8 +52,6 @@ class Counter(_Instrument):
     kind = "counter"
 
     def inc(self, value: float = 1.0, **labels) -> None:
-        if not _trace.trace_enabled():
-            return
         key = _label_key(labels)
         with _LOCK:
             self._series[key] = self._series.get(key, 0.0) + value
@@ -67,8 +63,6 @@ class Gauge(_Instrument):
     kind = "gauge"
 
     def set(self, value: float, **labels) -> None:
-        if not _trace.trace_enabled():
-            return
         with _LOCK:
             self._series[_label_key(labels)] = float(value)
 
@@ -80,8 +74,6 @@ class Histogram(_Instrument):
     kind = "histogram"
 
     def observe(self, value: float, **labels) -> None:
-        if not _trace.trace_enabled():
-            return
         value = float(value)
         key = _label_key(labels)
         with _LOCK:
@@ -122,9 +114,17 @@ def histogram(name: str) -> Histogram:
     return _get(name, Histogram)
 
 
+def _fold_pending_stalls() -> None:
+    """Fold in the GC records queued where taking ``_LOCK`` is unsafe
+    (``repro.obs.stalls``), so a read or reset sees them."""
+    from repro.obs import stalls
+    stalls._drain()
+
+
 def metrics_snapshot() -> dict:
     """``{metric_name: {"k=v,...": value_or_stats}}`` for every series
     with at least one observation (JSON-ready)."""
+    _fold_pending_stalls()
     with _LOCK:
         return {name: {k: (dict(v) if isinstance(v, dict) else v)
                        for k, v in inst._series.items()}
@@ -133,6 +133,7 @@ def metrics_snapshot() -> dict:
 
 def reset_metrics() -> None:
     """Zero every series (instruments stay registered)."""
+    _fold_pending_stalls()
     with _LOCK:
         for inst in _REGISTRY.values():
             inst._reset()
@@ -143,8 +144,7 @@ def reset_metrics() -> None:
 def intercept_pallas(callback: Callable[[str], None]):
     """Patch ``pl.pallas_call`` for the body; ``callback(kernel_name)``
     fires per dispatch.  THE shared launch-count definition —
-    ``count_pallas_calls``, the fusion tests, and the tracing probe all
-    route through here."""
+    ``count_pallas_calls`` and the fusion tests route through here."""
     from jax.experimental import pallas as pl
     orig = pl.pallas_call
 
@@ -157,36 +157,3 @@ def intercept_pallas(callback: Callable[[str], None]):
         yield
     finally:
         pl.pallas_call = orig
-
-
-_PROBE_ORIG = None
-
-
-def _install_pallas_probe() -> None:
-    """Patch ``pl.pallas_call`` for the tracing session: every dispatch
-    increments ``pallas_calls_total{kernel=...}`` and drops an instant
-    event.  Launches are observed at trace time — a program compiled
-    before the session started will not re-trace and thus not count."""
-    global _PROBE_ORIG
-    if _PROBE_ORIG is not None:
-        return
-    from jax.experimental import pallas as pl
-    orig = pl.pallas_call
-
-    def probed(*a, **kw):
-        name = kw.get("name", "?")
-        counter("pallas_calls_total").inc(kernel=name)
-        _trace.instant("pallas_call", cat="kernel", kernel=name)
-        return orig(*a, **kw)
-
-    _PROBE_ORIG = orig
-    pl.pallas_call = probed
-
-
-def _remove_pallas_probe() -> None:
-    global _PROBE_ORIG
-    if _PROBE_ORIG is None:
-        return
-    from jax.experimental import pallas as pl
-    pl.pallas_call = _PROBE_ORIG
-    _PROBE_ORIG = None

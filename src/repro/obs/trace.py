@@ -1,20 +1,25 @@
 """Nestable spans + instant events with Chrome-trace-event JSON export.
 
-One process-wide switch gates the whole ``repro.obs`` layer: tracing is
-off by default and every instrumentation point degrades to a handful of
-attribute loads (``span`` returns a shared null context manager,
-``instant``/counters return immediately).  Enable it with the
+The session switch gates what costs: the span/instant event buffer, the
+decision log and the export.  It is off by default; then ``span``
+returns a shared null context manager and ``instant`` returns
+immediately.  Counters, gauges, histograms and the host-stall record
+(``repro.obs.stalls``) stay on either way.  Open a session with the
 ``tracing(path)`` context manager, ``start_tracing()``/``stop_tracing()``,
 or the ``REPRO_TRACE=path`` environment variable (checked once at import;
 the trace is written atexit).
 
+Every time is taken from ``time.time_ns()``, the wall clock a JAX
+profiler trace is anchored to, so a session's start (``t0_ns``, exported
+under ``otherData``) plus an event's offset is an epoch time.
+
 Exported files follow the Chrome trace event format — ``"X"`` complete
-events (``ts``/``dur`` in microseconds) nest by containment per thread,
-``"i"`` instant events mark points in time, and one ``"C"`` counter
-event per metric series is appended at export so Perfetto /
-``chrome://tracing`` render the final counter values.  Two extra
-top-level keys, ``repro_metrics`` and ``repro_decisions``, carry the
-full metric snapshot and the structured decision log (extra keys are
+events (``ts``/``dur`` in microseconds from the session start) nest by
+containment per thread, ``"i"`` instant events mark points in time, and
+one ``"C"`` counter event per metric series is appended at export so
+Perfetto / ``chrome://tracing`` render the final counter values.  Two
+extra top-level keys, ``repro_metrics`` and ``repro_decisions``, carry
+the full metric snapshot and the structured decision log (extra keys are
 legal in the format and ignored by viewers).
 """
 from __future__ import annotations
@@ -22,7 +27,7 @@ from __future__ import annotations
 import json
 import os
 import threading
-from time import perf_counter, time as _walltime
+from time import time_ns
 from typing import Any, Optional
 
 __all__ = [
@@ -35,15 +40,15 @@ _STATE: Optional["_TraceState"] = None
 
 
 class _TraceState:
-    __slots__ = ("events", "t0", "path")
+    __slots__ = ("events", "t0_ns", "path")
 
     def __init__(self, path=None):
         self.events: list[dict] = []
-        self.t0 = perf_counter()
+        self.t0_ns = time_ns()
         self.path = path
 
     def now_us(self) -> float:
-        return (perf_counter() - self.t0) * 1e6
+        return (time_ns() - self.t0_ns) * 1e-3
 
     def add(self, event: dict) -> None:
         with _LOCK:
@@ -51,8 +56,8 @@ class _TraceState:
 
 
 def trace_enabled() -> bool:
-    """True while a tracing session is active (the one switch the whole
-    obs layer gates on)."""
+    """True while a tracing session is active (the switch that gates the
+    event buffer, the decision log and the export)."""
     return _STATE is not None
 
 
@@ -139,8 +144,8 @@ def _jsonable(obj):
 
 def start_tracing(path: Optional[str] = None) -> None:
     """Open a tracing session: fresh event buffer, metrics registry and
-    decision log reset (a trace captures its own window), and the Pallas
-    launch probe installed.  Raises if a session is already active."""
+    decision log reset (a trace captures its own window).  Raises if a
+    session is already active."""
     global _STATE
     if _STATE is not None:
         raise RuntimeError("tracing already active")
@@ -148,7 +153,6 @@ def start_tracing(path: Optional[str] = None) -> None:
     _STATE = _TraceState(path)
     _metrics.reset_metrics()
     _decisions.clear_decisions()
-    _metrics._install_pallas_probe()
 
 
 def export_trace(path: str) -> str:
@@ -173,7 +177,8 @@ def export_trace(path: str) -> str:
         "displayTimeUnit": "ms",
         "repro_metrics": snapshot,
         "repro_decisions": [r.to_dict() for r in _decisions.decision_log()],
-        "otherData": {"walltime": _walltime()},
+        "otherData": {"walltime": time_ns() * 1e-9,
+                      "t0_ns": st.t0_ns if st is not None else None},
     }
     with open(path, "w") as fh:
         json.dump(payload, fh, indent=1, default=_jsonable)
@@ -190,8 +195,6 @@ def stop_tracing(path: Optional[str] = None) -> Optional[str]:
         return None
     out = path or st.path
     written = export_trace(out) if out else None
-    from repro.obs import metrics as _metrics
-    _metrics._remove_pallas_probe()
     _STATE = None
     return written
 

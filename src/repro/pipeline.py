@@ -3,6 +3,7 @@ configuration prediction → PCSR generation → computing engine.
 """
 from __future__ import annotations
 
+from time import time_ns
 from typing import Optional
 
 import numpy as np
@@ -12,6 +13,7 @@ from .core import (CostModel, CSRMatrix, SpMMConfig, config_space,
 from .core.decider import SpMMDecider
 from .core.engine import ParamSpMMOperator
 from .core.reorder import rabbit_reorder, apply_reorder
+from .obs import metrics as _obs_metrics, trace as _obs_trace
 
 
 def pick_config(csr: CSRMatrix, dim: int, *,
@@ -68,32 +70,40 @@ class ParamSpMM:
                  select: str = "model",
                  op: str = "spmm",
                  heads: int = 1):
-        self.perm = None
-        if reorder:                       # paper §4.4: default preprocessing
-            perm = rabbit_reorder(csr)
-            cand = apply_reorder(csr, perm)
-            # keep whichever ordering has better V=2 locality — reordering
-            # an already well-ordered graph (e.g. co-citation clones) can
-            # only hurt, and the metric is cheap (pcsr_stats)
-            from .core import pcsr_stats
-            pr_old = pcsr_stats(csr.indptr, csr.indices, csr.n_rows,
-                                csr.n_cols, 2, 4).padding_ratio
-            pr_new = pcsr_stats(cand.indptr, cand.indices, cand.n_rows,
-                                cand.n_cols, 2, 4).padding_ratio
-            if pr_new <= pr_old:
-                self.perm = perm
-                csr = cand
-            else:
-                self.perm = np.arange(csr.n_rows)
-        self.csr = csr
-        self.dim = dim
-        if config is None:
-            config = pick_config(csr, dim, decider=decider, select=select,
-                                 op=op, heads=heads)
-        self.config = config
-        self.op = ParamSpMMOperator(csr, config, backend=backend,
-                                    interpret=interpret,
-                                    build_transpose=build_transpose)
+        # reorder, configuration pick and both packs: the host set-up an
+        # aggregation operator costs, observed with or without a session
+        t0 = time_ns()
+        with _obs_trace.span("spmm.build", op=op, heads=heads, dim=dim,
+                             n_rows=int(csr.n_rows), nnz=int(csr.nnz)):
+            self.perm = None
+            if reorder:              # paper §4.4: default preprocessing
+                perm = rabbit_reorder(csr)
+                cand = apply_reorder(csr, perm)
+                # keep whichever ordering has better V=2 locality —
+                # reordering an already well-ordered graph (e.g.
+                # co-citation clones) can only hurt, and the metric is
+                # cheap (pcsr_stats)
+                from .core import pcsr_stats
+                pr_old = pcsr_stats(csr.indptr, csr.indices, csr.n_rows,
+                                    csr.n_cols, 2, 4).padding_ratio
+                pr_new = pcsr_stats(cand.indptr, cand.indices, cand.n_rows,
+                                    cand.n_cols, 2, 4).padding_ratio
+                if pr_new <= pr_old:
+                    self.perm = perm
+                    csr = cand
+                else:
+                    self.perm = np.arange(csr.n_rows)
+            self.csr = csr
+            self.dim = dim
+            if config is None:
+                config = pick_config(csr, dim, decider=decider,
+                                     select=select, op=op, heads=heads)
+            self.config = config
+            self.op = ParamSpMMOperator(csr, config, backend=backend,
+                                        interpret=interpret,
+                                        build_transpose=build_transpose)
+        _obs_metrics.histogram("spmm_build_seconds").observe(
+            (time_ns() - t0) * 1e-9, op=op)
 
     def __call__(self, B):
         return self.op(B)

@@ -1,8 +1,11 @@
 """Runtime telemetry layer (repro.obs): span nesting + Chrome-trace
-schema, counter/label semantics, the structured decision log, drift
-advisories, and the disabled-mode guarantees (zero events recorded,
-traced and untraced training bit-identical)."""
+schema, counter/label semantics, the host-stall record (compiles and GC
+pauses on the wall clock), the structured decision log, drift
+advisories, and the session-off guarantees (no events or decisions
+recorded, aggregates kept, traced and untraced training bit-identical)."""
+import gc
 import json
+import time
 
 import numpy as np
 import pytest
@@ -35,6 +38,8 @@ def _clean_obs():
 
 # ------------------------------------------------------- disabled mode
 def test_disabled_mode_records_nothing():
+    """With no session open, spans, instants and decisions leave nothing
+    behind, while counters, gauges and histograms keep recording."""
     assert not obs.trace_enabled()
     with obs.span("work", step=1):
         obs.instant("tick")
@@ -42,10 +47,14 @@ def test_disabled_mode_records_nothing():
     obs.gauge("g_test").set(3.0)
     obs.histogram("h_test").observe(1.0)
     assert obs.trace_events() == []
-    assert obs.metrics_snapshot() == {}
     assert obs_decisions.record_decision(
         source="cost_model", dim=32, chosen=(8, 1, 1, False, False)) is None
     assert obs.decision_log() == []
+    snap = obs.metrics_snapshot()
+    assert snap["c_test"] == {"": 1.0}
+    assert snap["g_test"] == {"": 3.0}
+    assert snap["h_test"] == {"": {"count": 1, "sum": 1.0,
+                                   "min": 1.0, "max": 1.0}}
 
 
 def test_disabled_span_is_the_shared_null_singleton():
@@ -132,9 +141,14 @@ def test_metric_kind_mismatch_raises():
         obs.gauge("m_kind")
 
 
+def _compiles(phase):
+    series = obs.metrics_snapshot().get("compiles_total", {})
+    return sum(v for k, v in series.items() if f"phase={phase}" in k)
+
+
 def test_pallas_probe_counts_launches(rng):
-    """A kernel traced during the session shows up in
-    pallas_calls_total — same interception ``count_pallas_calls`` uses."""
+    """A kernel traced during the session shows up in compiles_total
+    once; calling it again compiles nothing."""
     from repro.kernels.paramspmm.ops import paramspmm
 
     csr, _ = random_csr(rng, 43, 0.2)      # fresh shape → no jit cache hit
@@ -143,9 +157,137 @@ def test_pallas_probe_counts_launches(rng):
     B = np.asarray(rng.standard_normal((43, 8)), np.float32)
     with obs.tracing():
         paramspmm(p, B, interpret=True)
-        snap = obs.metrics_snapshot()
-    series = snap.get("pallas_calls_total", {})
-    assert series and sum(series.values()) >= 1, snap.keys()
+        traced, built = _compiles("trace"), _compiles("backend")
+        assert traced >= 1 and built >= 1, obs.metrics_snapshot().keys()
+        paramspmm(p, B, interpret=True)
+        assert (_compiles("trace"), _compiles("backend")) == (traced, built)
+
+
+# --------------------------------------------------------- host stalls
+def _stalls_since(t0_ns):
+    return [r for r in obs.host_stalls() if r.start_ns >= t0_ns]
+
+
+def test_fresh_jit_records_each_compile_phase_on_the_wall_clock():
+    import jax
+    import jax.numpy as jnp
+
+    f = jax.jit(lambda x: jnp.tanh(x) * 3.0 - x)
+    x = jnp.ones((7, 13), jnp.float32)
+    t0 = time.time_ns()
+    f(x).block_until_ready()
+    t1 = time.time_ns()
+    recs = [r for r in _stalls_since(t0) if r.kind != "gc"]
+    mine = [r for r in recs if "<lambda>" in r.what]
+    assert {r.kind for r in mine} == {"compile.trace", "compile.lower",
+                                      "compile.backend"}
+    for r in recs:
+        assert t0 <= r.start_ns <= r.end_ns <= t1, r
+    assert all(r.cache is None for r in recs if r.kind != "compile.backend")
+    # the aggregates saw them with no session open
+    assert _compiles("trace") >= 1 and _compiles("backend") >= 1
+    secs = obs.metrics_snapshot()["compile_seconds"]
+    assert secs["phase=backend"]["count"] >= 1
+    # a steady call compiles nothing
+    t2 = time.time_ns()
+    f(x).block_until_ready()
+    assert [r for r in _stalls_since(t2) if r.kind != "gc"] == []
+
+
+def test_gc_collect_adds_a_gc_record():
+    t0 = time.time_ns()
+    gc.collect()
+    t1 = time.time_ns()
+    full = [r for r in _stalls_since(t0) if r.what == "gen2"]
+    assert full and full[0].kind == "gc"
+    assert t0 <= full[0].start_ns <= full[0].end_ns <= t1
+    pauses = obs.metrics_snapshot()["gc_pause_seconds"][""]
+    assert pauses["count"] >= 1 and pauses["sum"] > 0
+
+
+def test_gc_inside_a_metric_update_neither_deadlocks_nor_loses_counts(
+        monkeypatch):
+    """Collections forced while threads hold the metrics lock (a GC
+    callback runs inside whatever allocation triggered it) must not
+    deadlock, and concurrent increments must not be lost."""
+    import sys
+    import threading
+
+    from repro.obs import stalls as obs_stalls
+
+    monkeypatch.setattr(obs_stalls, "GC_MIN_NS", 0)    # record every pass
+    n_threads, n_ops = 12, 3000
+    t0 = time.time_ns()
+
+    def work(i):
+        c, h = obs.counter("c_stress"), obs.histogram("h_stress")
+        for k in range(n_ops):
+            c.inc(worker=i % 3)
+            h.observe(float(k), worker=i % 3)
+            if k % 500 == 0:
+                obs.metrics_snapshot()
+
+    threshold, interval = gc.get_threshold(), sys.getswitchinterval()
+    gc.set_threshold(5, 1, 1)          # a collection every few allocations
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(target=work, args=(i,), daemon=True)
+                   for i in range(n_threads)]
+        for t in threads:
+            t.start()
+        deadline = time.monotonic() + 120
+        for t in threads:
+            t.join(timeout=max(0.0, deadline - time.monotonic()))
+        assert not any(t.is_alive() for t in threads), "deadlocked"
+    finally:
+        gc.set_threshold(*threshold)
+        sys.setswitchinterval(interval)
+    snap = obs.metrics_snapshot()
+    assert sum(snap["c_stress"].values()) == n_threads * n_ops
+    assert sum(v["count"] for v in snap["h_stress"].values()) == \
+        n_threads * n_ops
+    assert [r for r in _stalls_since(t0) if r.kind == "gc"]
+    assert snap["gc_pause_seconds"][""]["count"] >= 1
+
+
+def test_param_spmm_build_observed_without_a_session(rng):
+    from repro.core.engine import make_gat_message_fn
+    from repro.pipeline import ParamSpMM
+
+    csr, _ = random_csr(rng, 40, 0.15)
+    assert not obs.trace_enabled()
+    ParamSpMM(csr, 8, backend="engine")
+    p = ParamSpMM(csr, 8, op="gat", heads=2, backend="engine")
+    make_gat_message_fn(p.op.pcsr, p.op.pcsr_t, backend="engine")
+    snap = obs.metrics_snapshot()["spmm_build_seconds"]
+    assert set(snap) == {"op=spmm", "op=gat", "op=gat_message"}
+    assert all(v["count"] == 1 and v["sum"] > 0 for v in snap.values())
+    assert obs.trace_events() == []
+
+
+def test_spmm_build_span_in_a_session(rng):
+    from repro.pipeline import ParamSpMM
+
+    csr, _ = random_csr(rng, 40, 0.15)
+    with obs.tracing():
+        ParamSpMM(csr, 8, backend="engine")
+        spans = [e for e in obs.trace_events() if e["name"] == "spmm.build"]
+    assert len(spans) == 1 and spans[0]["args"]["op"] == "spmm"
+    assert spans[0]["args"]["nnz"] == csr.nnz
+
+
+def test_exported_trace_carries_its_wall_clock_start(tmp_path):
+    path = tmp_path / "t.json"
+    t0 = time.time_ns()
+    with obs.tracing(str(path)):
+        with obs.span("s"):
+            pass
+    t1 = time.time_ns()
+    payload = json.loads(path.read_text())
+    start = payload["otherData"]["t0_ns"]
+    assert t0 <= start <= t1
+    [ev] = [e for e in payload["traceEvents"] if e["name"] == "s"]
+    assert t0 <= start + ev["ts"] * 1e3 <= t1
 
 
 # -------------------------------------------------------- decision log

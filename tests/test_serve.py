@@ -9,7 +9,7 @@ The serving contract under test (docs/SERVING.md):
   is summed in layout order), on BOTH backends.
 * **Zero recompiles** — after one warm-up per shape bucket, the jitted
   bucket forward never retraces: asserted via the trace-time
-  ``serve_recompiles_total`` counter and (pallas) ``pallas_calls_total``.
+  ``serve_recompiles_total`` counter and (pallas) ``compiles_total``.
 * **Determinism** — same seeded stream → same batch composition and
   bit-identical outputs.
 * **Cache semantics** — distinct buckets never alias, identical buckets
@@ -306,9 +306,9 @@ def test_soak_replay_deterministic_and_zero_recompiles():
 
 
 def test_soak_pallas_calls_flat_after_warmup():
-    """Pallas backend: ``pallas_calls_total`` increments at trace time
-    only, so a flat counter across a replayed stream proves the kernels
-    compiled once per bucket."""
+    """Pallas backend: ``compiles_total`` moves only when JAX traces or
+    compiles, so a flat counter across a replayed stream proves the
+    kernels compiled once per bucket."""
     import jax
     from repro.models.gnn import init_gcn
 
@@ -319,19 +319,20 @@ def test_soak_pallas_calls_flat_after_warmup():
     pol = BucketPolicy([ShapeBucket(128, 1024)])
     stream = synthetic_stream(4, g.n_rows, seed=17)
 
-    def pallas_total():
+    def compiles_total():
         snap = obs.metrics_snapshot()
-        return sum(snap.get("pallas_calls_total", {}).values())
+        return sum(snap.get("compiles_total", {}).values())
 
     with obs.tracing():
         svc = GNNService(g, feats, params, model="gcn", backend="pallas",
                          policy=pol)
         replay(svc, stream, tick_every=2)
-        warm = pallas_total()
+        warm = compiles_total()
+        assert warm > 0, "warm-up compiled nothing"
         svc2 = GNNService(g, feats, params, model="gcn", backend="pallas",
                           policy=pol)
         replay(svc2, stream, tick_every=2)
-        assert pallas_total() == warm, "pallas kernels re-traced"
+        assert compiles_total() == warm, "pallas kernels re-traced"
 
 
 # ------------------------------------------------------ cache semantics
