@@ -11,7 +11,11 @@ Grid ``(J, C)`` = (dim-tiles, chunks).  Each grid step owns one chunk of
    1 MiB SMEM of a v5e at ~130k slots);
 2. the chunk's ``K`` rows of ``B`` (one ``Dblk``-lane tile each) are
    gathered HBM → VMEM with manual async copies — the TPU replacement
-   for the CUDA warp's irregular global load;
+   for the CUDA warp's irregular global load — by ``gather_rows``, a
+   two-slot pipeline across grid steps: each step issues the *next*
+   step's rows into the other slot (their ids from a second SMEM view of
+   ``colidx``, one step ahead) before it waits, once, for its own slot,
+   so the next chunk's copies fly while this chunk computes;
 3. the slots are consumed in order, eight gathered rows per aligned
    ``(8, Dblk)`` load, each scaled by its slot value and added into the
    ``V`` output rows of its panel.
@@ -19,7 +23,8 @@ Grid ``(J, C)`` = (dim-tiles, chunks).  Each grid step owns one chunk of
 The ``(R, Dblk)`` output block is revisited across consecutive chunks
 with the same ``trow`` and accumulated in VMEM: with ``S=True`` several
 chunks target one block (the paper's ``TRow`` + ``atomicAdd``, made
-race-free by the sequential grid).
+race-free by the sequential grid; both grid axes are ``"arbitrary"``,
+since the gather's slots also carry state from one step to the next).
 
 Parameter mapping (paper → here):
   V → rows fed per gathered B row (slot values ``(V, K)`` per chunk);
@@ -64,33 +69,77 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.pcsr import LANES, SUBLANES
 from repro.kernels import resolve_backend
+from repro.obs import metrics as _obs_metrics
 
 ACTIVATIONS = ("none", "relu", "leaky_relu")
 
 
-def gather_rows(src_hbm, row_of, buf, sem, *, n: int, lane0, width: int):
-    """Copy ``src[row_of(k), lane0:lane0+width]`` into ``buf[k]`` for
-    ``k < n`` (HBM → VMEM, one async copy per row on one semaphore) and
-    wait for all of them."""
-    def start(k, carry):
-        pltpu.make_async_copy(
-            src_hbm.at[pl.ds(row_of(k), 1), pl.ds(lane0, width)],
-            buf.at[pl.ds(k, 1)], sem).start()
-        return carry
+def gather_rows(src_hbm, rows_now, rows_next, buf, sems, *, step, steps,
+                lane_now, lane_next):
+    """Two-slot row gather, pipelined across a sequential grid.
 
-    def wait(k, carry):
-        pltpu.make_async_copy(src_hbm.at[pl.ds(0, 1), pl.ds(0, width)],
-                              buf.at[pl.ds(0, 1)], sem).wait()
-        return carry
+    ``buf`` is ``(2, K, width)`` VMEM (``K`` a multiple of ``SUBLANES``)
+    with one DMA semaphore per slot in ``sems``; grid step ``step`` of
+    ``steps`` owns slot ``step % 2``.  Row ``k`` of this step's chunk is
+    ``src[rows_now(k), lane_now:lane_now+width]``, of the next step's
+    ``src[rows_next(k), lane_next:lane_next+width]`` (HBM → VMEM, one
+    async copy per row).  Step 0 issues its own rows; every step but the
+    last issues the next step's rows into the other slot, then waits for
+    its own slot — so the next chunk's copies are in flight while this
+    one computes.  Returns this step's slot."""
+    K, width = buf.shape[1], buf.shape[2]
+    slot = step % 2
 
-    jax.lax.fori_loop(0, n, start, 0)
-    jax.lax.fori_loop(0, n, wait, 0)
+    def issue(rows, s, lane0):
+        def start(g, carry):
+            # eight copies per iteration: the scalar loop's own overhead
+            # is paid once per eight rows, not once per row
+            for i in range(SUBLANES):
+                k = g * SUBLANES + i
+                pltpu.make_async_copy(
+                    src_hbm.at[pl.ds(rows(k), 1), pl.ds(lane0, width)],
+                    buf.at[s, pl.ds(k, 1)], sems.at[s]).start()
+            return carry
+        jax.lax.fori_loop(0, K // SUBLANES, start, 0)
+
+    @pl.when(step == 0)
+    def _prime():
+        issue(rows_now, 0, lane_now)
+
+    @pl.when(step + 1 < steps)
+    def _prefetch():
+        issue(rows_next, 1 - slot, lane_next)
+
+    # one wait for the whole slot: its K row copies signal one semaphore
+    # with their bytes, and a descriptor the slot's size waits for all
+    pltpu.make_async_copy(buf.at[slot], buf.at[slot], sems.at[slot]).wait()
+    return slot
+
+
+def gather_scratch(K: int, dblk: int, dtype) -> list:
+    """Scratch of ``gather_rows``: the two-slot buffer and its semaphores."""
+    return [pltpu.VMEM((2, K, dblk), dtype), pltpu.SemaphoreType.DMA((2,))]
+
+
+def record_prefetched_share(name: str, steps: int) -> None:
+    """Gauge ``kernel_gather_prefetched_share{kernel=name}``: the share of
+    a call's grid steps whose rows were in flight before the step began
+    (all but step 0), set when the call is traced."""
+    _obs_metrics.gauge("kernel_gather_prefetched_share").set(
+        (steps - 1) / steps, kernel=name)
+
+
+SEQUENTIAL = pltpu.CompilerParams(
+    dimension_semantics=("arbitrary", "arbitrary"))
+"""Both grid axes run in order: the gather's slots carry state across
+steps, and output blocks are revisited by consecutive steps."""
 
 
 def _kernel(trow_ref, init_ref, fini_ref,                   # scalar prefetch
-            colidx_ref, lrow_ref, vals_ref, b_hbm, *refs,
-            V: int, K: int, dblk: int, prologue: bool, has_scale: bool,
-            has_bias: bool, has_resid: bool, activation: str, slope: float):
+            colidx_ref, next_colidx_ref, lrow_ref, vals_ref, b_hbm, *refs,
+            V: int, K: int, C: int, J: int, dblk: int, prologue: bool,
+            has_scale: bool, has_bias: bool, has_resid: bool,
+            activation: str, slope: float):
     j = pl.program_id(0)
     c = pl.program_id(1)
 
@@ -100,19 +149,22 @@ def _kernel(trow_ref, init_ref, fini_ref,                   # scalar prefetch
     scale_ref = next(it) if has_scale else None
     bias_ref = next(it) if has_bias else None
     resid_ref = next(it) if has_resid else None
-    out_ref, gbuf, sem = next(it), next(it), next(it)
+    out_ref, gbuf, sems = next(it), next(it), next(it)
 
     # First visit of this output block in this dim-tile pass → zero it.
     @pl.when(init_ref[c] == 1)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    gather_rows(b_hbm, lambda k: colidx_ref[0, 0, k], gbuf, sem, n=K,
-                lane0=j * dblk, width=dblk)
+    # the next grid step is (j, c+1), or (j+1, 0) after the last chunk
+    slot = gather_rows(b_hbm, lambda k: colidx_ref[0, 0, k],
+                       lambda k: next_colidx_ref[0, 0, k], gbuf, sems,
+                       step=j * C + c, steps=J * C, lane_now=j * dblk,
+                       lane_next=(j + (c == C - 1)) % J * dblk)
 
     def group(g, carry):
         base = pl.multiple_of(g * SUBLANES, SUBLANES)
-        tile = gbuf[pl.ds(base, SUBLANES), :]    # 8 gathered B rows
+        tile = gbuf[slot, pl.ds(base, SUBLANES), :]    # 8 gathered B rows
         for i in range(SUBLANES):
             k = base + i
             row = lrow_ref[0, 0, k] * V           # panel's first row
@@ -203,10 +255,14 @@ def paramspmm_kernel(colidx, lrow, trow, init, fini, vals, B_padded, *,
         return pl.BlockSpec((1, rows, K), lambda j, c, tr, it, fi: (c, 0, 0),
                             memory_space=smem)
 
-    in_specs = [chunk_spec(1), chunk_spec(1), chunk_spec(V),
+    # the next grid step's row ids: a second view of colidx, one chunk on
+    next_ids = pl.BlockSpec((1, 1, K),
+                            lambda j, c, tr, it, fi: ((c + 1) % C, 0, 0),
+                            memory_space=smem)
+    in_specs = [chunk_spec(1), next_ids, chunk_spec(1), chunk_spec(V),
                 pl.BlockSpec(memory_space=pl.ANY)]          # B stays in HBM
-    operands = [colidx.reshape(C, 1, K), lrow.reshape(C, 1, K), vals,
-                B_padded]
+    colidx = colidx.reshape(C, 1, K)
+    operands = [colidx, colidx, lrow.reshape(C, 1, K), vals, B_padded]
     if prologue:
         stats_spec = pl.BlockSpec((SUBLANES, LANES),
                                   lambda j, c, tr, it, fi: (tr[c], 0),
@@ -235,21 +291,24 @@ def paramspmm_kernel(colidx, lrow, trow, init, fini, vals, B_padded, *,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((R, dblk),
                                lambda j, c, tr, it, fi: (tr[c], j)),
-        scratch_shapes=[pltpu.VMEM((K, dblk), B_padded.dtype),
-                        pltpu.SemaphoreType.DMA(())],
+        scratch_shapes=gather_scratch(K, dblk, B_padded.dtype),
     )
+    name = (f"paramspmm_v{V}_k{K}_r{R}_d{dblk}"
+            f"{'_pro' if prologue else ''}"
+            f"{'_res' if residual is not None else ''}"
+            f"{'' if activation == 'none' else '_' + activation}")
+    record_prefetched_share(name, C * J)
     fn = pl.pallas_call(
-        functools.partial(_kernel, V=V, K=K, dblk=dblk, prologue=prologue,
+        functools.partial(_kernel, V=V, K=K, C=C, J=J, dblk=dblk,
+                          prologue=prologue,
                           has_scale=scale is not None,
                           has_bias=bias is not None,
                           has_resid=residual is not None,
                           activation=activation, slope=slope),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_blocks * R, dim_pad), B_padded.dtype),
+        compiler_params=SEQUENTIAL,
         interpret=resolve_backend("pallas", interpret)[1],
-        name=f"paramspmm_v{V}_k{K}_r{R}_d{dblk}"
-             f"{'_pro' if prologue else ''}"
-             f"{'_res' if residual is not None else ''}"
-             f"{'' if activation == 'none' else '_' + activation}",
+        name=name,
     )
     return fn(trow, init, fini, *operands)

@@ -5,15 +5,18 @@ drive the grid, but the data flow is reversed — instead of scattering
 ``val · B[col]`` into an output block, each slot *reduces*
 ``Q[row] · K[col]`` over a ``Dblk``-lane tile into the slot's score.
 
-Grid ``(C, J)`` = (chunks, dim-tiles).  Per step the chunk's
-``colidx``/``lrow`` slices arrive in SMEM as pipelined blocks (only
-``trow``/``init`` are scalar-prefetched whole), its ``K`` key rows are
-gathered HBM → VMEM with manual async copies
-(``paramspmm.kernel.gather_rows``), and the query rows come from the
-target block's ``(R, Dblk)`` tile of ``Q`` — the paper's coalesced
-dense-row access.  Eight slots at a time, the query rows are staged
-into an aligned ``(8, Dblk)`` tile, multiplied with the eight gathered
-key rows and lane-reduced into an ``(8, 1)`` column of partial scores.
+Grid ``(C, J)`` = (chunks, dim-tiles), run in order.  Per step the
+chunk's ``colidx``/``lrow`` slices arrive in SMEM as pipelined blocks
+(only ``trow``/``init`` are scalar-prefetched whole), its ``K`` key rows
+are gathered HBM → VMEM by the SpMM's two-slot pipelined gather
+(``paramspmm.kernel.gather_rows``: the next step's rows — the same chunk
+at the next dim tile, or the next chunk after the last — are issued into
+the other slot before this step waits for its own), and the query rows
+come from the target block's ``(R, Dblk)`` tile of ``Q`` — the paper's
+coalesced dense-row access.  Eight slots at a time, the query rows are
+staged into an aligned ``(8, Dblk)`` tile, multiplied with the eight
+gathered key rows and lane-reduced into an ``(8, 1)`` column of partial
+scores.
 Scores are kept slot-major — ``(C, K, V)``, slots on sublanes — inside
 the kernel and transposed to the PCSR slot layout ``(C, V, K)`` by the
 wrappers; the score block stays VMEM-resident across the ``J`` steps of
@@ -42,20 +45,27 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.pcsr import LANES, SUBLANES
 from repro.kernels import resolve_backend
-from repro.kernels.paramspmm.kernel import gather_rows
+from repro.kernels.paramspmm.kernel import (SEQUENTIAL, gather_rows,
+                                            gather_scratch,
+                                            record_prefetched_share)
 
 
-def _accumulate_scores(colidx_ref, lrow_ref, q_ref, k_hbm, score_ref, kbuf,
-                       qbuf, sem, *, V: int, K: int, dblk: int):
+def _accumulate_scores(colidx_ref, next_colidx_ref, lrow_ref, q_ref, k_hbm,
+                       score_ref, kbuf, qbuf, sems, *, V: int, K: int, C: int,
+                       J: int, dblk: int):
     """score[k, v] += Q[row_k + v, tile j] · K[colidx_k, tile j] for every
     slot of the chunk (``score_ref`` is the chunk's ``(1, K, V)`` block)."""
+    c = pl.program_id(0)
     j = pl.program_id(1)
-    gather_rows(k_hbm, lambda k: colidx_ref[0, 0, k], kbuf, sem, n=K,
-                lane0=j * dblk, width=dblk)
+    # the next grid step is (c, j+1), or (c+1, 0) after the last dim tile
+    slot = gather_rows(k_hbm, lambda k: colidx_ref[0, 0, k],
+                       lambda k: next_colidx_ref[0, 0, k], kbuf, sems,
+                       step=c * J + j, steps=C * J, lane_now=j * dblk,
+                       lane_next=(j + 1) % J * dblk)
 
     def group(g, carry):
         base = pl.multiple_of(g * SUBLANES, SUBLANES)
-        ktile = kbuf[pl.ds(base, SUBLANES), :]       # 8 gathered key rows
+        ktile = kbuf[slot, pl.ds(base, SUBLANES), :]   # 8 gathered key rows
         for v in range(V):
             for i in range(SUBLANES):                # stage 8 query rows
                 row = lrow_ref[0, 0, base + i] * V + v
@@ -69,30 +79,40 @@ def _accumulate_scores(colidx_ref, lrow_ref, q_ref, k_hbm, score_ref, kbuf,
 
 
 def _scratch(K: int, dblk: int, dtype):
-    return [pltpu.VMEM((K, dblk), dtype), pltpu.VMEM((SUBLANES, dblk), dtype),
-            pltpu.SemaphoreType.DMA(())]
+    kbuf, sems = gather_scratch(K, dblk, dtype)
+    return [kbuf, pltpu.VMEM((SUBLANES, dblk), dtype), sems]
 
 
-def _chunk_spec(K: int, index_map):
-    return pl.BlockSpec((1, 1, K), index_map, memory_space=pltpu.SMEM)
+def _chunk_specs(K: int, C: int, J: int) -> list:
+    """SMEM views of ``colidx`` at this grid step's chunk and at the next
+    step's (the same chunk until its last dim tile), then ``lrow``."""
+    def spec(index_map):
+        return pl.BlockSpec((1, 1, K), lambda c, j, *_: index_map(c, j),
+                            memory_space=pltpu.SMEM)
+    return [spec(lambda c, j: (c, 0, 0)),
+            spec(lambda c, j: ((c + (j == J - 1)) % C, 0, 0)),
+            spec(lambda c, j: (c, 0, 0))]
 
 
-def _kernel(trow_ref, colidx_ref, lrow_ref, q_ref, k_hbm,
-            out_ref, kbuf, qbuf, sem, *, V: int, K: int, dblk: int):
+def _kernel(trow_ref, colidx_ref, next_colidx_ref, lrow_ref, q_ref, k_hbm,
+            out_ref, kbuf, qbuf, sems, *, V: int, K: int, C: int, J: int,
+            dblk: int):
     # first dim tile of this chunk → zero its score block
     @pl.when(pl.program_id(1) == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    _accumulate_scores(colidx_ref, lrow_ref, q_ref, k_hbm, out_ref, kbuf,
-                       qbuf, sem, V=V, K=K, dblk=dblk)
+    _accumulate_scores(colidx_ref, next_colidx_ref, lrow_ref, q_ref, k_hbm,
+                       out_ref, kbuf, qbuf, sems, V=V, K=K, C=C, J=J,
+                       dblk=dblk)
 
 
 def _fused_kernel(trow_ref, init_ref,                       # scalar prefetch
-                  colidx_ref, lrow_ref, vals_ref, q_ref, k_hbm,
+                  colidx_ref, next_colidx_ref, lrow_ref, vals_ref,
+                  q_ref, k_hbm,
                   score_ref, rowmax_ref, rowsum_ref,        # outputs
-                  kbuf, qbuf, sem,
-                  *, V: int, K: int, J: int, dblk: int, scale: float,
+                  kbuf, qbuf, sems,
+                  *, V: int, K: int, C: int, J: int, dblk: int, scale: float,
                   slope: float):
     c = pl.program_id(0)
     j = pl.program_id(1)
@@ -108,8 +128,9 @@ def _fused_kernel(trow_ref, init_ref,                       # scalar prefetch
                                    rowmax_ref.dtype)
         rowsum_ref[...] = jnp.zeros_like(rowsum_ref)
 
-    _accumulate_scores(colidx_ref, lrow_ref, q_ref, k_hbm, score_ref, kbuf,
-                       qbuf, sem, V=V, K=K, dblk=dblk)
+    _accumulate_scores(colidx_ref, next_colidx_ref, lrow_ref, q_ref, k_hbm,
+                       score_ref, kbuf, qbuf, sems, V=V, K=K, C=C, J=J,
+                       dblk=dblk)
 
     # Softmax epilogue: once the chunk's dot products are complete (last
     # dim tile), scale + LeakyReLU every slot and fold it, in slot order,
@@ -183,9 +204,7 @@ def sddmm_softmax_kernel(colidx, lrow, trow, init, vals, Q_padded, K_padded, *,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(C, J),
-        in_specs=[
-            _chunk_spec(K, lambda c, j, tr, it: (c, 0, 0)),
-            _chunk_spec(K, lambda c, j, tr, it: (c, 0, 0)),
+        in_specs=_chunk_specs(K, C, J) + [
             # the chunk's slot values, slot-major (the edge mask)
             pl.BlockSpec((1, K, V), lambda c, j, tr, it: (c, 0, 0)),
             # the target block's query rows
@@ -199,8 +218,10 @@ def sddmm_softmax_kernel(colidx, lrow, trow, init, vals, Q_padded, K_padded, *,
         ],
         scratch_shapes=_scratch(K, dblk, Q_padded.dtype),
     )
+    name = f"sddmm_softmax_v{V}_k{K}_w{W}_d{dblk}"
+    record_prefetched_share(name, C * J)
     fn = pl.pallas_call(
-        functools.partial(_fused_kernel, V=V, K=K, J=J, dblk=dblk,
+        functools.partial(_fused_kernel, V=V, K=K, C=C, J=J, dblk=dblk,
                           scale=scale, slope=slope),
         grid_spec=grid_spec,
         out_shape=[
@@ -208,10 +229,12 @@ def sddmm_softmax_kernel(colidx, lrow, trow, init, vals, Q_padded, K_padded, *,
             jax.ShapeDtypeStruct((n_blocks * SUBLANES, LANES), Q_padded.dtype),
             jax.ShapeDtypeStruct((n_blocks * SUBLANES, LANES), Q_padded.dtype),
         ],
+        compiler_params=SEQUENTIAL,
         interpret=resolve_backend("pallas", interpret)[1],
-        name=f"sddmm_softmax_v{V}_k{K}_w{W}_d{dblk}",
+        name=name,
     )
-    logits, rowmax, rowsum = fn(trow, init, colidx.reshape(C, 1, K),
+    colidx = colidx.reshape(C, 1, K)
+    logits, rowmax, rowsum = fn(trow, init, colidx, colidx,
                                 lrow.reshape(C, 1, K),
                                 jnp.swapaxes(vals, 1, 2), Q_padded, K_padded)
     return jnp.swapaxes(logits, 1, 2), rowmax, rowsum
@@ -236,9 +259,7 @@ def sddmm_kernel(colidx, lrow, trow, Q_padded, K_padded, *,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(C, J),
-        in_specs=[
-            _chunk_spec(K, lambda c, j, tr: (c, 0, 0)),
-            _chunk_spec(K, lambda c, j, tr: (c, 0, 0)),
+        in_specs=_chunk_specs(K, C, J) + [
             # the target block's query rows
             pl.BlockSpec((R, dblk), lambda c, j, tr: (tr[c], j)),
             pl.BlockSpec(memory_space=pl.ANY),        # keys stay in HBM
@@ -246,13 +267,17 @@ def sddmm_kernel(colidx, lrow, trow, Q_padded, K_padded, *,
         out_specs=pl.BlockSpec((1, K, V), lambda c, j, tr: (c, 0, 0)),
         scratch_shapes=_scratch(K, dblk, Q_padded.dtype),
     )
+    name = f"sddmm_v{V}_k{K}_w{W}_d{dblk}"
+    record_prefetched_share(name, C * J)
     fn = pl.pallas_call(
-        functools.partial(_kernel, V=V, K=K, dblk=dblk),
+        functools.partial(_kernel, V=V, K=K, C=C, J=J, dblk=dblk),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((C, K, V), Q_padded.dtype),
+        compiler_params=SEQUENTIAL,
         interpret=resolve_backend("pallas", interpret)[1],
-        name=f"sddmm_v{V}_k{K}_w{W}_d{dblk}",
+        name=name,
     )
-    scores = fn(trow, colidx.reshape(C, 1, K), lrow.reshape(C, 1, K),
-                Q_padded, K_padded)
+    colidx = colidx.reshape(C, 1, K)
+    scores = fn(trow, colidx, colidx, lrow.reshape(C, 1, K), Q_padded,
+                K_padded)
     return jnp.swapaxes(scores, 1, 2)

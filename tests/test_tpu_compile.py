@@ -81,10 +81,16 @@ def _form(name: str, H: int, sd):
                                   "spmm_softmax_prologue", "sddmm_softmax",
                                   "sddmm"])
 def test_kernel_compiles_for_v5e(one_chip, name, H):
+    """Each form compiles with its two-slot gather, and tracing it sets the
+    prefetched-share gauge: every grid step but the first (J = 1 here)."""
+    from repro.obs import metrics_snapshot, reset_metrics
     sd = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
     fn, args = _form(name, H, sd)
+    reset_metrics()
     hlo = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in hlo
+    share = metrics_snapshot()["kernel_gather_prefetched_share"]
+    assert list(share.values()) == [(H * C - 1) / (H * C)]
 
 
 def test_gcn_train_step_compiles_and_fits_v5e(one_chip):
