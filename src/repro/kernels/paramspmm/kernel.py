@@ -15,10 +15,13 @@ Grid ``(J, C)`` = (dim-tiles, chunks).  Each grid step owns one chunk of
    two-slot pipeline across grid steps: each step issues the *next*
    step's rows into the other slot (their ids from a second SMEM view of
    ``colidx``, one step ahead) before it waits, once, for its own slot,
-   so the next chunk's copies fly while this chunk computes;
-3. the slots are consumed in order, eight gathered rows per aligned
-   ``(8, Dblk)`` load, each scaled by its slot value and added into the
-   ``V`` output rows of its panel.
+   so the next chunk's copies fly while this chunk computes.  ``B`` is
+   read as ``lane_rows(B)``, one 128-lane row per lane tile, so a
+   gathered row of ``F`` lane tiles is one copy of ``F`` whole rows;
+3. the slots are consumed in order, eight gathered rows per
+   ``(8, Dblk)`` tile (``load_rows``: one aligned load at ``F = 1``, ``F``
+   sublane-strided loads beyond), each scaled by its slot value and
+   added into the ``V`` output rows of its panel.
 
 The ``(R, Dblk)`` output block is revisited across consecutive chunks
 with the same ``trow`` and accumulated in VMEM: with ``S=True`` several
@@ -74,41 +77,63 @@ from repro.obs import metrics as _obs_metrics
 ACTIVATIONS = ("none", "relu", "leaky_relu")
 
 
+def lane_rows(X):
+    """``X`` of shape ``(n, T·LANES)`` as the ``(n·T, LANES)`` array the
+    gather copies from: lane tile ``t`` of row ``r`` becomes row
+    ``r·T + t``, so a gathered row of ``F`` lane tiles is ``F`` whole
+    consecutive rows (the v5e's DMA takes no one-row slice of an HBM
+    array wider than one lane tile)."""
+    return X if X.shape[1] == LANES else X.reshape(-1, LANES)
+
+
+def tile_rows(ids, tiles: int, first):
+    """Row ids of ``lane_rows`` for a gather of dim tiles: row ``ids(k)``
+    of an operand of ``tiles`` lane tiles, from its lane tile ``first``
+    on.  One lane tile keeps the ids as they are."""
+    if tiles == 1:
+        return ids
+    return lambda k: ids(k) * tiles + first
+
+
 def gather_rows(src_hbm, rows_now, rows_next, buf, sems, *, step, steps,
-                lane_now, lane_next):
+                span: int):
     """Two-slot row gather, pipelined across a sequential grid.
 
-    ``buf`` is ``(2, K, width)`` VMEM (``K`` a multiple of ``SUBLANES``)
-    with one DMA semaphore per slot in ``sems``; grid step ``step`` of
-    ``steps`` owns slot ``step % 2``.  Row ``k`` of this step's chunk is
-    ``src[rows_now(k), lane_now:lane_now+width]``, of the next step's
-    ``src[rows_next(k), lane_next:lane_next+width]`` (HBM → VMEM, one
-    async copy per row).  Step 0 issues its own rows; every step but the
-    last issues the next step's rows into the other slot, then waits for
-    its own slot — so the next chunk's copies are in flight while this
-    one computes.  Returns this step's slot."""
-    K, width = buf.shape[1], buf.shape[2]
+    ``src_hbm`` is a ``lane_rows`` operand ``(m, LANES)``; ``buf`` is
+    ``(2, K·span, LANES)`` VMEM (``K`` a multiple of ``SUBLANES``) with one
+    DMA semaphore per slot in ``sems``; grid step ``step`` of ``steps``
+    owns slot ``step % 2``.  Gathered row ``k`` of this step's chunk is
+    the ``span`` source rows from ``rows_now(k)`` on (``Dblk = span·LANES``
+    lanes), of the next step's those from ``rows_next(k)``; each lands in
+    ``buf`` rows ``k·span …`` by one async copy (HBM → VMEM).  Step 0
+    issues its own rows; every step but the last issues the next step's
+    rows into the other slot, then waits for its own slot — so the next
+    chunk's copies are in flight while this one computes.  Returns this
+    step's slot; ``load_rows`` reads it."""
+    K = buf.shape[1] // span
     slot = step % 2
 
-    def issue(rows, s, lane0):
+    def issue(rows, s):
         def start(g, carry):
             # eight copies per iteration: the scalar loop's own overhead
             # is paid once per eight rows, not once per row
             for i in range(SUBLANES):
                 k = g * SUBLANES + i
+                # (no multiply in the loop at one lane tile)
                 pltpu.make_async_copy(
-                    src_hbm.at[pl.ds(rows(k), 1), pl.ds(lane0, width)],
-                    buf.at[s, pl.ds(k, 1)], sems.at[s]).start()
+                    src_hbm.at[pl.ds(rows(k), span)],
+                    buf.at[s, pl.ds(k * span if span > 1 else k, span)],
+                    sems.at[s]).start()
             return carry
         jax.lax.fori_loop(0, K // SUBLANES, start, 0)
 
     @pl.when(step == 0)
     def _prime():
-        issue(rows_now, 0, lane_now)
+        issue(rows_now, 0)
 
     @pl.when(step + 1 < steps)
     def _prefetch():
-        issue(rows_next, 1 - slot, lane_next)
+        issue(rows_next, 1 - slot)
 
     # one wait for the whole slot: its K row copies signal one semaphore
     # with their bytes, and a descriptor the slot's size waits for all
@@ -116,9 +141,21 @@ def gather_rows(src_hbm, rows_now, rows_next, buf, sems, *, step, steps,
     return slot
 
 
+def load_rows(buf, slot, base, span: int):
+    """The ``(SUBLANES, span·LANES)`` tile of gathered rows ``base …
+    base+7`` (``base`` a multiple of ``SUBLANES``): lane tile ``f`` is a
+    sublane-strided load of ``buf`` rows ``(base+i)·span + f``."""
+    if span == 1:
+        return buf[slot, pl.ds(base, SUBLANES), :]
+    return jnp.concatenate(
+        [buf[slot, pl.ds(base * span + f, SUBLANES, stride=span), :]
+         for f in range(span)], axis=1)
+
+
 def gather_scratch(K: int, dblk: int, dtype) -> list:
     """Scratch of ``gather_rows``: the two-slot buffer and its semaphores."""
-    return [pltpu.VMEM((2, K, dblk), dtype), pltpu.SemaphoreType.DMA((2,))]
+    return [pltpu.VMEM((2, K * (dblk // LANES), LANES), dtype),
+            pltpu.SemaphoreType.DMA((2,))]
 
 
 def record_prefetched_share(name: str, steps: int) -> None:
@@ -127,6 +164,17 @@ def record_prefetched_share(name: str, steps: int) -> None:
     (all but step 0), set when the call is traced."""
     _obs_metrics.gauge("kernel_gather_prefetched_share").set(
         (steps - 1) / steps, kernel=name)
+
+
+def record_gather_lanes(name: str, *, C: int, J: int, K: int, dblk: int,
+                        width: int) -> None:
+    """Counter ``kernel_gather_lanes_total{kernel=name, lanes}``, added to
+    when a call is traced: the lanes its C·J grid steps gather,
+    C·J·K·``dblk`` (``moved``), and those that carry one of the
+    operand's ``width`` columns, C·K·``width`` (``carried``)."""
+    lanes = _obs_metrics.counter("kernel_gather_lanes_total")
+    lanes.inc(C * J * K * dblk, kernel=name, lanes="moved")
+    lanes.inc(C * K * width, kernel=name, lanes="carried")
 
 
 SEQUENTIAL = pltpu.CompilerParams(
@@ -156,15 +204,19 @@ def _kernel(trow_ref, init_ref, fini_ref,                   # scalar prefetch
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    # the next grid step is (j, c+1), or (j+1, 0) after the last chunk
-    slot = gather_rows(b_hbm, lambda k: colidx_ref[0, 0, k],
-                       lambda k: next_colidx_ref[0, 0, k], gbuf, sems,
-                       step=j * C + c, steps=J * C, lane_now=j * dblk,
-                       lane_next=(j + (c == C - 1)) % J * dblk)
+    # the next grid step is (j, c+1), or (j+1, 0) after the last chunk;
+    # B is read as lane_rows, J·F rows of LANES per row of B
+    F = dblk // LANES
+    slot = gather_rows(
+        b_hbm,
+        tile_rows(lambda k: colidx_ref[0, 0, k], J * F, j * F),
+        tile_rows(lambda k: next_colidx_ref[0, 0, k], J * F,
+                  (j + (c == C - 1)) % J * F),
+        gbuf, sems, step=j * C + c, steps=J * C, span=F)
 
     def group(g, carry):
         base = pl.multiple_of(g * SUBLANES, SUBLANES)
-        tile = gbuf[slot, pl.ds(base, SUBLANES), :]    # 8 gathered B rows
+        tile = load_rows(gbuf, slot, base, F)     # 8 gathered B rows
         for i in range(SUBLANES):
             k = base + i
             row = lrow_ref[0, 0, k] * V           # panel's first row
@@ -213,10 +265,12 @@ def paramspmm_kernel(colidx, lrow, trow, init, fini, vals, B_padded, *,
                      n_blocks: int, R: int, V: int, K: int, dblk: int,
                      rowmax=None, rowsum=None, scale=None, bias=None,
                      residual=None, activation: str = "none",
-                     slope: float = 0.2, interpret: bool | None = None):
+                     slope: float = 0.2, width: int | None = None,
+                     interpret: bool | None = None):
     """Invoke the Pallas kernel on pre-padded operands.
 
-    B_padded: (n_b, J·dblk).  Returns C_padded (n_blocks·R, J·dblk).
+    B_padded: (n_b, J·dblk), of which the first ``width`` columns (all by
+    default) carry data.  Returns C_padded (n_blocks·R, J·dblk).
 
     Optional fusion operands:
       rowmax/rowsum (n_blocks·SUBLANES, LANES) — softmax prologue stats
@@ -262,7 +316,8 @@ def paramspmm_kernel(colidx, lrow, trow, init, fini, vals, B_padded, *,
     in_specs = [chunk_spec(1), next_ids, chunk_spec(1), chunk_spec(V),
                 pl.BlockSpec(memory_space=pl.ANY)]          # B stays in HBM
     colidx = colidx.reshape(C, 1, K)
-    operands = [colidx, colidx, lrow.reshape(C, 1, K), vals, B_padded]
+    operands = [colidx, colidx, lrow.reshape(C, 1, K), vals,
+                lane_rows(B_padded)]
     if prologue:
         stats_spec = pl.BlockSpec((SUBLANES, LANES),
                                   lambda j, c, tr, it, fi: (tr[c], 0),
@@ -298,6 +353,8 @@ def paramspmm_kernel(colidx, lrow, trow, init, fini, vals, B_padded, *,
             f"{'_res' if residual is not None else ''}"
             f"{'' if activation == 'none' else '_' + activation}")
     record_prefetched_share(name, C * J)
+    record_gather_lanes(name, C=C, J=J, K=K, dblk=dblk,
+                        width=dim_pad if width is None else width)
     fn = pl.pallas_call(
         functools.partial(_kernel, V=V, K=K, C=C, J=J, dblk=dblk,
                           prologue=prologue,

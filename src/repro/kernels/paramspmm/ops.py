@@ -83,7 +83,8 @@ def _call(colidx, lrow, trow, init, fini, vals, B, rowmax=None, rowsum=None,
                            n_blocks=n_blocks, R=R, V=V, K=K, dblk=dblk,
                            rowmax=rowmax, rowsum=rowsum, scale=scale,
                            bias=bias, residual=residual,
-                           activation=activation, interpret=interpret)
+                           activation=activation, width=dim,
+                           interpret=interpret)
     return out[:n_rows, :dim]
 
 

@@ -46,8 +46,10 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core.pcsr import LANES, SUBLANES
 from repro.kernels import resolve_backend
 from repro.kernels.paramspmm.kernel import (SEQUENTIAL, gather_rows,
-                                            gather_scratch,
-                                            record_prefetched_share)
+                                            gather_scratch, lane_rows,
+                                            load_rows,
+                                            record_prefetched_share,
+                                            tile_rows)
 
 
 def _accumulate_scores(colidx_ref, next_colidx_ref, lrow_ref, q_ref, k_hbm,
@@ -57,15 +59,18 @@ def _accumulate_scores(colidx_ref, next_colidx_ref, lrow_ref, q_ref, k_hbm,
     slot of the chunk (``score_ref`` is the chunk's ``(1, K, V)`` block)."""
     c = pl.program_id(0)
     j = pl.program_id(1)
-    # the next grid step is (c, j+1), or (c+1, 0) after the last dim tile
-    slot = gather_rows(k_hbm, lambda k: colidx_ref[0, 0, k],
-                       lambda k: next_colidx_ref[0, 0, k], kbuf, sems,
-                       step=c * J + j, steps=C * J, lane_now=j * dblk,
-                       lane_next=(j + 1) % J * dblk)
+    # the next grid step is (c, j+1), or (c+1, 0) after the last dim
+    # tile; K is read as lane_rows, J·F rows of LANES per key row
+    F = dblk // LANES
+    slot = gather_rows(
+        k_hbm, tile_rows(lambda k: colidx_ref[0, 0, k], J * F, j * F),
+        tile_rows(lambda k: next_colidx_ref[0, 0, k], J * F,
+                  (j + 1) % J * F),
+        kbuf, sems, step=c * J + j, steps=C * J, span=F)
 
     def group(g, carry):
         base = pl.multiple_of(g * SUBLANES, SUBLANES)
-        ktile = kbuf[slot, pl.ds(base, SUBLANES), :]   # 8 gathered key rows
+        ktile = load_rows(kbuf, slot, base, F)   # 8 gathered key rows
         for v in range(V):
             for i in range(SUBLANES):                # stage 8 query rows
                 row = lrow_ref[0, 0, base + i] * V + v
@@ -236,7 +241,8 @@ def sddmm_softmax_kernel(colidx, lrow, trow, init, vals, Q_padded, K_padded, *,
     colidx = colidx.reshape(C, 1, K)
     logits, rowmax, rowsum = fn(trow, init, colidx, colidx,
                                 lrow.reshape(C, 1, K),
-                                jnp.swapaxes(vals, 1, 2), Q_padded, K_padded)
+                                jnp.swapaxes(vals, 1, 2), Q_padded,
+                                lane_rows(K_padded))
     return jnp.swapaxes(logits, 1, 2), rowmax, rowsum
 
 
@@ -279,5 +285,5 @@ def sddmm_kernel(colidx, lrow, trow, Q_padded, K_padded, *,
     )
     colidx = colidx.reshape(C, 1, K)
     scores = fn(trow, colidx, colidx, lrow.reshape(C, 1, K), Q_padded,
-                K_padded)
+                lane_rows(K_padded))
     return jnp.swapaxes(scores, 1, 2)

@@ -5,8 +5,10 @@ The smoke graph (``chip_smoke.py``: 65,536 nodes, 1.47M nonzeros after
 GCN normalization) packs under its picked ⟨W=16, F=1, V=2, S, B⟩ into
 2,066 chunks of K = 776 slots — 1,603,216 slots — over 2,048 output
 blocks of R = 32 rows.  Every kernel form of the GCN/GIN/GAT path is
-compiled at that size, single-head and 4-head tiled; then one whole
-jitted GCN value-and-grad step, whose device memory must fit a v5e.
+compiled at that size, single-head and 4-head tiled, and the SpMM forms
+also over a 256-wide operand (256-lane rows, or two 128-lane dim tiles);
+then whole jitted GCN value-and-grad steps, at the smoke widths and at
+OGB's ogbn-arxiv widths, whose device memory must fit a v5e.
 
 The topology is described inside a module fixture, never at import: only
 the test worker that runs this file loads the TPU compiler.
@@ -39,6 +41,11 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
+# a wide form's suffix → (Dblk, operand width): 256-lane rows (F = 2),
+# or two 128-lane dim tiles (F = 1, J = 2), of a 256-wide operand
+WIDE = {"_wide_f2": (2 * LANES, 256), "_wide_j2": (LANES, 256)}
+
+
 def _form(name: str, H: int, sd):
     """(fn, argument shapes) of one kernel form over H head-tiled
     copies of the smoke graph's steering."""
@@ -48,16 +55,19 @@ def _form(name: str, H: int, sd):
     f32, i32 = jnp.float32, jnp.int32
     steer = [sd((c * K,), i32), sd((c * K,), i32), sd((c,), i32),
              sd((c,), i32), sd((c,), i32)]
-    vals, B = sd((c, V, K), f32), sd((n, DBLK), f32)
+    wide = next((w for w in WIDE if name.endswith(w)), "")
+    dblk, width = WIDE.get(wide, (DBLK, DBLK))
+    name = name.removesuffix(wide)
+    vals, B = sd((c, V, K), f32), sd((n, width), f32)
     stats = sd((nb * SUBLANES, LANES), f32)
-    kw = dict(n_blocks=nb, R=R, V=V, K=K, dblk=DBLK, interpret=False)
+    kw = dict(n_blocks=nb, R=R, V=V, K=K, dblk=dblk, interpret=False)
     if name == "spmm":
         return (lambda *a: paramspmm_kernel(*a, **kw)), steer + [vals, B]
     if name == "spmm_epilogue":        # GCN: degree scale + bias + ReLU
         return (lambda *a: paramspmm_kernel(
             *a[:7], scale=a[7], bias=a[8], activation="relu", **kw),
             steer + [vals, B, sd((nb * R, 1), f32),
-                     sd((SUBLANES, DBLK), f32)])
+                     sd((SUBLANES, width), f32)])
     if name == "spmm_residual":        # GIN: (1+ε)h + A·h
         return (lambda *a: paramspmm_kernel(*a[:7], residual=a[7], **kw),
                 steer + [vals, B, sd((nb * R, DBLK), f32)])
@@ -79,18 +89,26 @@ def _form(name: str, H: int, sd):
 @pytest.mark.parametrize("H", [1, 4])
 @pytest.mark.parametrize("name", ["spmm", "spmm_epilogue", "spmm_residual",
                                   "spmm_softmax_prologue", "sddmm_softmax",
-                                  "sddmm"])
+                                  "sddmm", "spmm_wide_f2",
+                                  "spmm_epilogue_wide_f2", "spmm_wide_j2",
+                                  "spmm_epilogue_wide_j2"])
 def test_kernel_compiles_for_v5e(one_chip, name, H):
     """Each form compiles with its two-slot gather, and tracing it sets the
-    prefetched-share gauge: every grid step but the first (J = 1 here)."""
+    prefetched-share gauge: every grid step but the first (J = 1, but for
+    the ``_wide_j2`` forms' two dim tiles).  A wide form gathers a
+    256-wide operand and counts every gathered lane as carrying data."""
     from repro.obs import metrics_snapshot, reset_metrics
     sd = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
     fn, args = _form(name, H, sd)
+    J = 2 if name.endswith("_wide_j2") else 1
     reset_metrics()
     hlo = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in hlo
     share = metrics_snapshot()["kernel_gather_prefetched_share"]
-    assert list(share.values()) == [(H * C - 1) / (H * C)]
+    assert list(share.values()) == [(H * C * J - 1) / (H * C * J)]
+    if "_wide_" in name:
+        lanes = metrics_snapshot()["kernel_gather_lanes_total"]
+        assert sorted(lanes.values()) == [H * C * K * 256] * 2
 
 
 def test_gcn_train_step_compiles_and_fits_v5e(one_chip):
@@ -122,6 +140,46 @@ def test_gcn_train_step_compiles_and_fits_v5e(one_chip):
     # forward: 5 aggregations; backward: 4 transpose aggregations (the
     # first layer's input needs no gradient)
     assert compiled.as_text().count("tpu_custom_call") >= 9
+    mem = compiled.memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes + mem.generated_code_size_in_bytes)
+    assert used < V5E_HBM_BYTES
+
+
+def test_gcn_arxiv_widths_train_step_compiles_and_fits_v5e(one_chip):
+    """The same step at OGB's ogbn-arxiv GCN widths, 128 → 256 → 256 → 40,
+    through ``ParamSpMM`` built for width 256: every aggregation, forward
+    and backward, runs a kernel gathering 256-lane rows (F = 2), the
+    128- and 40-wide ones padded; device memory within 16 GB."""
+    from repro.core.pcsr import SpMMConfig
+    from repro.data.tasks import community_task
+    from repro.models.gnn import gcn_forward, init_gcn, node_ce_loss
+    from repro.obs import metrics_snapshot, reset_metrics
+    from repro.pipeline import ParamSpMM
+
+    dims = [128, 256, 256, 40]
+    task = community_task(n_blocks=16, block_size=4096, p_in=0.005)
+    op = ParamSpMM(task.csr.gcn_normalize(), dims[1],
+                   config=SpMMConfig(V=V, S=True, F=2, W=W, B=True),
+                   backend="pallas", interpret=False)
+    params = init_gcn(jax.random.PRNGKey(0), dims)
+    sd = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+    n = task.csr.n_rows
+    args = (jax.tree.map(sd, params), sd(jnp.zeros((n, dims[0]))),
+            sd(jnp.zeros(n, jnp.int32)), sd(jnp.zeros(n)))
+
+    def loss(params, X, labels, mask):
+        return node_ce_loss(gcn_forward(params, X, op), labels, mask)
+
+    reset_metrics()
+    compiled = jax.jit(jax.value_and_grad(loss)).lower(*args).compile()
+    # forward: 3 aggregations; backward: 2 (the first layer aggregates
+    # the input features, which need no gradient)
+    assert compiled.as_text().count("tpu_custom_call") >= 5
+    kernels = {k.split(",")[0].removeprefix("kernel=")
+               for k in metrics_snapshot()["kernel_gather_lanes_total"]}
+    assert any(k.endswith("_d256_relu") for k in kernels), kernels
+    assert all(k.removesuffix("_relu").endswith("_d256") for k in kernels)
     mem = compiled.memory_analysis()
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes + mem.generated_code_size_in_bytes)

@@ -1,0 +1,159 @@
+"""Dense operands wider than one 128-lane tile through the Pallas kernels
+(interpreter): a 3-layer GCN at OGB's ogbn-arxiv widths, 128 → 256 → 256 →
+40, through ``ParamSpMM`` with ``Dblk`` = 128 (two dim tiles at 256) and
+``Dblk`` = 256 forced, against a plain gather + ``segment_sum`` reference
+in float32; a 40-wide operand under a 256-lane pack; and the
+``kernel_gather_lanes_total`` counter."""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from repro.core.pcsr import LANES, SpMMConfig, build_pcsr
+from repro.core.sparse import CSRMatrix
+from repro.kernels.paramspmm.kernel import paramspmm_kernel
+from repro.kernels.paramspmm.ops import paramspmm
+from repro.models.gnn import gcn_forward, node_ce_loss
+from repro.obs import metrics_snapshot, reset_metrics
+from repro.optim import AdamWConfig, adamw_init, adamw_update
+from repro.pipeline import ParamSpMM
+
+from conftest import random_csr
+
+DIMS = [128, 256, 256, 40]          # OGB's arxiv GCN: 3 layers of 256
+N = 48
+OPT = AdamWConfig(lr=0.01)
+HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def _graph(seed=3):
+    """A symmetric random graph, GCN-normalised (self loops included)."""
+    rng = np.random.default_rng(seed)
+    A = np.triu(rng.random((N, N)) < 0.12, 1)
+    A = (A | A.T).astype(np.float32)
+    return CSRMatrix.from_dense(A).gcn_normalize(), rng
+
+
+def _segment_spmm(csr):
+    """Â·H by gathering H's rows and summing them per output row."""
+    rows = jnp.asarray(np.repeat(np.arange(csr.n_rows), np.diff(csr.indptr)))
+    cols = jnp.asarray(csr.indices)
+    vals = jnp.asarray(csr.data)
+    return lambda H: jax.ops.segment_sum(vals[:, None] * H[cols], rows,
+                                         num_segments=csr.n_rows)
+
+
+def _reference_forward(params, X, agg):
+    """relu(Â·H·W + b) per layer, no ReLU after the last, at float32."""
+    h = X
+    for i, layer in enumerate(params):
+        h = jnp.matmul(agg(h), layer["w"], precision=HIGHEST) + layer["b"]
+        if i < len(params) - 1:
+            h = jax.nn.relu(h)
+    return h
+
+
+def _reference_adam(params, grads):
+    """One Adam step from zero moments: both bias corrections are exact,
+    so the update is lr · g / (|g| + eps)."""
+    return jax.tree.map(
+        lambda p, g: p - OPT.lr * g / (jnp.abs(g) + OPT.eps), params, grads)
+
+
+def _params(rng):
+    return [{"w": jnp.asarray(rng.standard_normal((a, b)) * np.sqrt(2 / a),
+                              jnp.float32),
+             "b": jnp.asarray(rng.standard_normal(b) * 0.1, jnp.float32)}
+            for a, b in zip(DIMS[:-1], DIMS[1:])]
+
+
+@pytest.mark.parametrize("F", [1, 2])
+def test_gcn_at_arxiv_widths_matches_reference(F):
+    """Logits, every leaf's gradient and one AdamW step of the program's
+    GCN (fused epilogues on the 256- and 40-wide layers) against the
+    reference.  With F = 1 the 256-wide aggregations run two 128-lane dim
+    tiles; with F = 2 every aggregation gathers 256-lane rows, the 128-
+    and 40-wide ones padded."""
+    csr, rng = _graph()
+    op = ParamSpMM(csr, 256, config=SpMMConfig(V=2, S=True, F=F, W=4),
+                   reorder=False, backend="pallas", interpret=True)
+    assert op.config.dblk == F * LANES
+    ref = _segment_spmm(csr)
+    params = _params(rng)
+    X = jnp.asarray(rng.standard_normal((N, DIMS[0])), jnp.float32)
+    y = jnp.asarray(rng.integers(0, DIMS[-1], N), jnp.int32)
+    mask = jnp.asarray(rng.random(N) < 0.5, jnp.float32)
+
+    def loss(fwd, agg):
+        return lambda p: node_ce_loss(fwd(p, X, agg), y, mask)
+
+    with jax.default_matmul_precision("highest"):
+        got = jax.jit(lambda p: gcn_forward(p, X, op))(params)
+        want = _reference_forward(params, X, ref)
+        g_got = jax.jit(jax.grad(loss(gcn_forward, op)))(params)
+        g_want = jax.grad(loss(_reference_forward, ref))(params)
+        stepped, _ = adamw_update(params, g_got, adamw_init(params), OPT)
+    # float32 sums of at most ~15 products per row, in another order and
+    # association (the program aggregates Â·(H·W) where it fuses): a few
+    # ulps of values of order 1
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+    for a, b in zip(jax.tree.leaves(g_got), jax.tree.leaves(g_want)):
+        # the same rounding, carried back through three layers; scaled by
+        # the leaf's largest entry, since entries near 0 carry no digits
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=0,
+                                   atol=1e-5 * float(jnp.max(jnp.abs(b))))
+    for a, b, g in zip(jax.tree.leaves(stepped),
+                       jax.tree.leaves(_reference_adam(params, g_want)),
+                       jax.tree.leaves(g_want)):
+        # the first Adam step moves each entry by lr·g/(|g|+eps): ±lr up
+        # to float32 rounding of the parameter wherever |g| stands clear of
+        # the gradient's rounding noise (the 1e-5 of its largest entry
+        # allowed above); under 1e-3 of it, the noise steers the step, and
+        # where it is exactly 0 (a unit no training node reaches) the step
+        # is exactly 0
+        g = np.abs(np.asarray(g))
+        clear = (g >= 1e-3 * g.max()) | (g == 0)
+        assert clear.mean() > 0.8
+        np.testing.assert_allclose(np.asarray(a)[clear], np.asarray(b)[clear],
+                                   rtol=0, atol=1e-6)
+
+
+@pytest.mark.parametrize("activation", ["none", "relu"])
+def test_narrow_operand_under_wide_pack(rng, activation):
+    """A 40-wide operand under a ``Dblk`` = 256 pack: 216 of the 256
+    gathered lanes are padding and must not reach the output."""
+    csr, A = random_csr(rng, 40, 0.15, skew=True)
+    p = build_pcsr(csr.indptr, csr.indices, csr.data, 40, 40,
+                   SpMMConfig(V=2, S=True, F=2, W=4, B=True))
+    B = rng.standard_normal((40, 40)).astype(np.float32)
+    bias = rng.standard_normal(40).astype(np.float32)
+    out = paramspmm(p, B, bias=bias, activation=activation, interpret=True)
+    want = A @ B + bias
+    if activation == "relu":
+        want = np.maximum(want, 0.0)
+    # float32 sums of a few products; padding lanes would add whole values
+    np.testing.assert_allclose(np.asarray(out), want, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("dim,F", [(40, 2), (128, 1), (256, 1), (256, 2),
+                                   (500, 2)])
+def test_gather_lanes_counter(dim, F):
+    """Tracing one call adds C·J·K·Dblk lanes moved and C·K·dim carried
+    to ``kernel_gather_lanes_total{kernel=<its name>}``."""
+    C, V, W, K, n_blocks, n = 3, 2, 4, 16, 2, 32
+    dblk = F * LANES
+    J = -(-dim // dblk)
+    i32, f32 = jnp.int32, jnp.float32
+    sd = jax.ShapeDtypeStruct
+    args = [sd((C * K,), i32), sd((C * K,), i32), sd((C,), i32),
+            sd((C,), i32), sd((C,), i32), sd((C, V, K), f32),
+            sd((n, J * dblk), f32)]
+    reset_metrics()
+    jax.eval_shape(lambda *a: paramspmm_kernel(
+        *a, n_blocks=n_blocks, R=V * W, V=V, K=K, dblk=dblk, width=dim,
+        interpret=True), *args)
+    name = f"paramspmm_v{V}_k{K}_r{V * W}_d{dblk}"
+    assert metrics_snapshot()["kernel_gather_lanes_total"] == {
+        f"kernel={name},lanes=moved": C * J * K * dblk,
+        f"kernel={name},lanes=carried": C * K * dim}
