@@ -60,7 +60,9 @@ class SpMMConfig:
 
     V: vector size of blocking (paper domain {1, 2}).
     S: workload balancing on/off.
-    F: coarsening factor — dim-tile width ``Dblk = F·128`` lanes.
+    F: coarsening factor — the widest dim tile the pick allows,
+       ``Dblk = F·128`` lanes.  Each call narrows it to its operand
+       (``dblk_for``): the packed steering arrays do not depend on F.
     W: panels per output block — block height ``R = V·W`` rows.
     B: nnz-balanced chunk schedule (distribution-derived capacity +
        round-robin slot packing + LPT chunk order).  Requires ``S=True``
@@ -87,6 +89,15 @@ class SpMMConfig:
     @property
     def dblk(self) -> int:
         return self.F * LANES
+
+    def dblk_for(self, width: int) -> int:
+        """The lane tile a call on a ``width``-wide operand gathers: the
+        narrowest whole tile that holds the row when ``dblk`` would (one
+        dim tile, padded lanes cost bytes and buy nothing), else ``dblk``
+        (wider operands keep the pick's dim tiling)."""
+        if width <= self.dblk:
+            return _round_up(width, LANES)
+        return self.dblk
 
     def astuple(self):
         return (self.W, self.F, self.V, self.S, self.B)

@@ -199,11 +199,12 @@ def _pallas_fwd_branch(pcsr, *, H: int, n_out: int, slope: float,
         # kernel 2: prologue SpMM — logits in, α rebuilt in-register;
         # coverage chunks carry −inf logits (exact α = 0)
         lg = _pad_chunk_vals(logits.reshape(H, C, V, K), Cc - C, -jnp.inf)
-        Bp, _ = _pad_cols(vfx.reshape(-1, dv), dblk)
+        tile = cfg.dblk_for(dv)
+        Bp, _ = _pad_cols(vfx.reshape(-1, dv), tile)
         out = paramspmm_kernel(
             colidx[:H * Cc * K], lrow[:H * Cc * K], trow[:H * Cc],
             init[:H * Cc], fini[:H * Cc], lg.reshape(H * Cc, V, K), Bp,
-            n_blocks=H * nb, R=R, V=V, K=K, dblk=dblk,
+            n_blocks=H * nb, R=R, V=V, K=K, dblk=tile, widest=dblk,
             rowmax=rowmax, rowsum=rowsum, interpret=interpret)
         out = out[:, :dv].reshape(H, nb * R, dv)[:, :n_out]
         pad1 = lambda x, L: jnp.pad(x.reshape(-1), (0, L - x.size))[None, :]
@@ -248,11 +249,12 @@ def _pallas_bwd_branch(pcsr, pcsr_t, *, H: int, n_out: int, slope: float,
         are the real chunks (coverage appended here, fill 0)."""
         d = B3.shape[2]
         v = _pad_chunk_vals(vals4, Cc_ - vals4.shape[1], 0.0)
-        Bp, _ = _pad_cols(B3.reshape(-1, d), dblk)
+        tile = cfg.dblk_for(d)
+        Bp, _ = _pad_cols(B3.reshape(-1, d), tile)
         out = paramspmm_kernel(
             col[:H * Cc_ * Kc], lr[:H * Cc_ * Kc], tr[:H * Cc_],
             it[:H * Cc_], fi[:H * Cc_], v.reshape(H * Cc_, V, Kc), Bp,
-            n_blocks=H * nb_, R=R, V=V, K=Kc, dblk=dblk,
+            n_blocks=H * nb_, R=R, V=V, K=Kc, dblk=tile, widest=dblk,
             interpret=interpret)
         return out[:, :d].reshape(H, nb_ * R, d)[:, :n_r]
 

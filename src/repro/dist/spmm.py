@@ -98,8 +98,8 @@ def _spmm_branch(pcsr, *, n_out: int, backend: str, interpret: bool,
             return _pallas_call(
                 colidx[:Sc], lrow[:Sc], trow[:Cc], init[:Cc], fini[:Cc],
                 vals[:VSc].reshape(Cc, V, K), b_ext,
-                n_blocks=nb, R=R, V=V, K=K, dblk=cfg.dblk,
-                n_rows=n_out, dim=b_ext.shape[1], interpret=interpret, **kw)
+                cfg=cfg, n_blocks=nb, K=K, n_rows=n_out,
+                dim=b_ext.shape[1], interpret=interpret, **kw)
         return branch
 
     def branch(colidx, lrow, trow, init, fini, vals, b_ext, *ep):

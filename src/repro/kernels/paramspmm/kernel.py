@@ -266,11 +266,15 @@ def paramspmm_kernel(colidx, lrow, trow, init, fini, vals, B_padded, *,
                      rowmax=None, rowsum=None, scale=None, bias=None,
                      residual=None, activation: str = "none",
                      slope: float = 0.2, width: int | None = None,
+                     widest: int | None = None,
                      interpret: bool | None = None):
     """Invoke the Pallas kernel on pre-padded operands.
 
     B_padded: (n_b, J·dblk), of which the first ``width`` columns (all by
     default) carry data.  Returns C_padded (n_blocks·R, J·dblk).
+    ``widest`` is the picked config's dim tile where the caller narrowed
+    ``dblk`` from it (``SpMMConfig.dblk_for``); a narrower ``dblk`` is
+    counted in ``spmm_dblk_narrowed_total{kernel}``.
 
     Optional fusion operands:
       rowmax/rowsum (n_blocks·SUBLANES, LANES) — softmax prologue stats
@@ -355,6 +359,8 @@ def paramspmm_kernel(colidx, lrow, trow, init, fini, vals, B_padded, *,
     record_prefetched_share(name, C * J)
     record_gather_lanes(name, C=C, J=J, K=K, dblk=dblk,
                         width=dim_pad if width is None else width)
+    if widest is not None and dblk < widest:
+        _obs_metrics.counter("spmm_dblk_narrowed_total").inc(kernel=name)
     fn = pl.pallas_call(
         functools.partial(_kernel, V=V, K=K, C=C, J=J, dblk=dblk,
                           prologue=prologue,

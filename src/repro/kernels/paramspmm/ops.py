@@ -48,12 +48,15 @@ def _pack_scale(x, n_blocks: int, R: int):
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "n_blocks", "R", "V", "K", "dblk", "n_rows", "dim", "activation",
-    "interpret"))
+    "cfg", "n_blocks", "K", "n_rows", "dim", "activation", "interpret"))
 def _call(colidx, lrow, trow, init, fini, vals, B, rowmax=None, rowsum=None,
-          scale=None, bias=None, residual=None, *, n_blocks, R, V, K, dblk,
-          n_rows, dim, activation="none", interpret):
+          scale=None, bias=None, residual=None, *, cfg, n_blocks, K, n_rows,
+          dim, activation="none", interpret):
     """Pallas dispatch on pre-packed (covered) steering arrays.
+
+    The call gathers ``cfg.dblk_for(dim)`` lanes per row: a ``dim``-wide
+    operand that fits one of the pick's dim tiles is padded to whole
+    128-lane tiles only, not to ``cfg.dblk``.
 
     ``scale`` is a flat per-row vector (≤ n_blocks·R entries), ``bias`` a
     flat per-feature vector (≤ dim entries), ``residual`` a dense
@@ -69,6 +72,7 @@ def _call(colidx, lrow, trow, init, fini, vals, B, rowmax=None, rowsum=None,
         assert arr is None or arr.shape == stats_shape, (
             f"{name} must be tile-aligned {stats_shape} "
             f"(the fused SDDMM's native layout), got {arr.shape}")
+    R, dblk = cfg.R, cfg.dblk_for(dim)
     B_padded, dim_pad = _pad_cols(B, dblk)
     if scale is not None:
         scale = _pack_scale(scale, n_blocks, R)
@@ -80,11 +84,11 @@ def _call(colidx, lrow, trow, init, fini, vals, B, rowmax=None, rowsum=None,
                            ((0, n_blocks * R - residual.shape[0]),
                             (0, dim_pad - residual.shape[1])))
     out = paramspmm_kernel(colidx, lrow, trow, init, fini, vals, B_padded,
-                           n_blocks=n_blocks, R=R, V=V, K=K, dblk=dblk,
-                           rowmax=rowmax, rowsum=rowsum, scale=scale,
-                           bias=bias, residual=residual,
+                           n_blocks=n_blocks, R=R, V=cfg.V, K=K,
+                           dblk=dblk, rowmax=rowmax, rowsum=rowsum,
+                           scale=scale, bias=bias, residual=residual,
                            activation=activation, width=dim,
-                           interpret=interpret)
+                           widest=cfg.dblk, interpret=interpret)
     return out[:n_rows, :dim]
 
 
@@ -99,19 +103,19 @@ def paramspmm(pcsr: PCSR, B, *, scale=None, bias=None, residual=None,
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "H", "n_blocks", "R", "V", "K", "dblk", "n_rows", "dim", "activation",
+    "H", "cfg", "n_blocks", "K", "n_rows", "dim", "activation",
     "interpret"))
 def _call_heads(colidx, lrow, trow, init, fini, vals, B, rowmax=None,
-                rowsum=None, *, H, n_blocks, R, V, K, dblk, n_rows, dim,
+                rowsum=None, *, H, cfg, n_blocks, K, n_rows, dim,
                 activation="none", interpret):
     out = _call(colidx, lrow, trow, init, fini,
                 vals.reshape((H * vals.shape[1],) + vals.shape[2:]),
                 B.reshape(H * B.shape[1], B.shape[2]),
                 rowmax, rowsum,
-                n_blocks=H * n_blocks, R=R, V=V, K=K, dblk=dblk,
-                n_rows=H * n_blocks * R, dim=dim, activation=activation,
+                cfg=cfg, n_blocks=H * n_blocks, K=K,
+                n_rows=H * n_blocks * cfg.R, dim=dim, activation=activation,
                 interpret=interpret)
-    return out.reshape(H, n_blocks * R, dim)[:, :n_rows]
+    return out.reshape(H, n_blocks * cfg.R, dim)[:, :n_rows]
 
 
 def _pad_chunk_vals(vals, n_extra: int, fill: float):
@@ -180,9 +184,9 @@ def paramspmm_with_vals(pcsr: PCSR, vals, B, *, stats=None, scale=None,
             vals = _pad_chunk_vals(vals, C_cov - vals.shape[1], fill)
         return _call_heads(t["colidx"], t["lrow"], t["trow"], t["init"],
                            t["fini"], vals, B, rowmax, rowsum, H=H,
-                           n_blocks=pcsr.n_blocks, R=cfg.R, V=cfg.V,
-                           K=pcsr.K, dblk=cfg.dblk, n_rows=pcsr.n_rows,
-                           dim=B.shape[2], interpret=interpret)
+                           cfg=cfg, n_blocks=pcsr.n_blocks, K=pcsr.K,
+                           n_rows=pcsr.n_rows, dim=B.shape[2],
+                           interpret=interpret)
     t = pcsr.steering(covered=True)
     C_cov = t["trow"].shape[0]
     if vals is None:
@@ -195,6 +199,6 @@ def paramspmm_with_vals(pcsr: PCSR, vals, B, *, stats=None, scale=None,
                  None if scale is None else jnp.asarray(scale),
                  None if bias is None else jnp.asarray(bias),
                  None if residual is None else jnp.asarray(residual),
-                 n_blocks=pcsr.n_blocks, R=cfg.R, V=cfg.V, K=pcsr.K,
-                 dblk=cfg.dblk, n_rows=pcsr.n_rows, dim=B.shape[1],
-                 activation=activation, interpret=interpret)
+                 cfg=cfg, n_blocks=pcsr.n_blocks, K=pcsr.K,
+                 n_rows=pcsr.n_rows, dim=B.shape[1], activation=activation,
+                 interpret=interpret)

@@ -53,11 +53,10 @@ def _bucket_spmm(steer, geom: PackGeom, backend: str, interpret: bool):
         def call(B, scale=None, bias=None, activation="none", residual=None):
             return _call(steer["colidx"], steer["lrow"], steer["trow"],
                          steer["init"], steer["fini"], steer["vals"], B,
-                         None, None, scale, bias, residual,
-                         n_blocks=geom.n_blocks, R=cfg.R, V=cfg.V,
-                         K=geom.K, dblk=cfg.dblk, n_rows=geom.n_rows,
-                         dim=B.shape[1], activation=activation,
-                         interpret=interpret)
+                         None, None, scale, bias, residual, cfg=cfg,
+                         n_blocks=geom.n_blocks, K=geom.K,
+                         n_rows=geom.n_rows, dim=B.shape[1],
+                         activation=activation, interpret=interpret)
 
         def spmm(B):
             return call(B)
@@ -102,9 +101,9 @@ def _bucket_gat_msg(steer, geom: PackGeom, backend: str, interpret: bool,
             logits = logits.reshape(geom.num_chunks, V, K)
             return _call(steer["colidx"], steer["lrow"], steer["trow"],
                          steer["init"], steer["fini"], logits, Vf,
-                         rowmax, rowsum, n_blocks=nb, R=R, V=V, K=K,
-                         dblk=cfg.dblk, n_rows=geom.n_rows,
-                         dim=Vf.shape[1], interpret=interpret)
+                         rowmax, rowsum, cfg=cfg, n_blocks=nb, K=K,
+                         n_rows=geom.n_rows, dim=Vf.shape[1],
+                         interpret=interpret)
     else:
         def msg(Q, K_mat, Vf):
             mask = steer["vals"] != 0

@@ -1,15 +1,17 @@
 """Dense operands wider than one 128-lane tile through the Pallas kernels
 (interpreter): a 3-layer GCN at OGB's ogbn-arxiv widths, 128 → 256 → 256 →
-40, through ``ParamSpMM`` with ``Dblk`` = 128 (two dim tiles at 256) and
-``Dblk`` = 256 forced, against a plain gather + ``segment_sum`` reference
-in float32; a 40-wide operand under a 256-lane pack; and the
-``kernel_gather_lanes_total`` counter."""
+40, through ``ParamSpMM`` with ``F`` = 1 (two dim tiles at 256) and ``F``
+= 2 forced, against a plain gather + ``segment_sum`` reference in float32;
+a 40-wide operand under a 256-lane pick; each call's lane tile narrowed to
+its operand (``SpMMConfig.dblk_for``); and the ``kernel_gather_lanes_total``
+and ``spmm_dblk_narrowed_total`` counters."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.pcsr import LANES, SpMMConfig, build_pcsr
+from repro.core.engine import make_spmm_fn
+from repro.core.pcsr import LANES, SpMMConfig, build_pcsr, transpose_pcsr
 from repro.core.sparse import CSRMatrix
 from repro.kernels.paramspmm.kernel import paramspmm_kernel
 from repro.kernels.paramspmm.ops import paramspmm
@@ -72,8 +74,8 @@ def test_gcn_at_arxiv_widths_matches_reference(F):
     """Logits, every leaf's gradient and one AdamW step of the program's
     GCN (fused epilogues on the 256- and 40-wide layers) against the
     reference.  With F = 1 the 256-wide aggregations run two 128-lane dim
-    tiles; with F = 2 every aggregation gathers 256-lane rows, the 128-
-    and 40-wide ones padded."""
+    tiles; with F = 2 they gather 256-lane rows, and the 128- and 40-wide
+    ones narrow to 128-lane rows."""
     csr, rng = _graph()
     op = ParamSpMM(csr, 256, config=SpMMConfig(V=2, S=True, F=F, W=4),
                    reorder=False, backend="pallas", interpret=True)
@@ -121,8 +123,8 @@ def test_gcn_at_arxiv_widths_matches_reference(F):
 
 @pytest.mark.parametrize("activation", ["none", "relu"])
 def test_narrow_operand_under_wide_pack(rng, activation):
-    """A 40-wide operand under a ``Dblk`` = 256 pack: 216 of the 256
-    gathered lanes are padding and must not reach the output."""
+    """A 40-wide operand under a ``Dblk`` = 256 pick, narrowed to 128
+    lanes: 88 of them are padding and must not reach the output."""
     csr, A = random_csr(rng, 40, 0.15, skew=True)
     p = build_pcsr(csr.indptr, csr.indices, csr.data, 40, 40,
                    SpMMConfig(V=2, S=True, F=2, W=4, B=True))
@@ -157,3 +159,72 @@ def test_gather_lanes_counter(dim, F):
     assert metrics_snapshot()["kernel_gather_lanes_total"] == {
         f"kernel={name},lanes=moved": C * J * K * dblk,
         f"kernel={name},lanes=carried": C * K * dim}
+
+
+@pytest.mark.parametrize("width,form,tile,J", [
+    (40, "plain", 128, 1), (40, "bias", 128, 1), (40, "relu", 128, 1),
+    (40, "grad", 128, 1), (128, "plain", 128, 1), (128, "bias", 128, 1),
+    (128, "relu", 128, 1), (128, "grad", 128, 1), (256, "plain", 256, 1),
+    (300, "plain", 256, 2)])
+def test_dim_tile_narrows_to_operand(rng, width, form, tile, J):
+    """Under an F = 2 pick each call gathers ``tile`` lanes in J dim tiles:
+    the 40- and 128-wide operands dispatch a ``_d128`` form (the transpose-
+    PCSR backward of ``jax.grad`` too), 256 keeps ``_d256`` and 300 two
+    ``_d256`` tiles.  The F = 2 and F = 1 packs of one graph have the same
+    steering arrays, F setting only the kernel's tile, so a narrowed call
+    is the F = 1 pack's call, to the bit."""
+    A = ((rng.random((40, 56)) < 0.15)
+         * rng.standard_normal((40, 56))).astype(np.float32)
+    csr = CSRMatrix.from_dense(A)
+    packs = {F: build_pcsr(csr.indptr, csr.indices, csr.data, 40, 56,
+                           SpMMConfig(V=2, S=True, F=F, W=4, B=True))
+             for F in (2, 1)}
+    steer = [p.steering(covered=True) for p in packs.values()]
+    assert steer[0].keys() == steer[1].keys()
+    for k in steer[0]:
+        np.testing.assert_array_equal(steer[0][k], steer[1][k])
+    B = rng.standard_normal((56, width)).astype(np.float32)
+    bias = rng.standard_normal(width).astype(np.float32)
+    G = rng.standard_normal((40, width)).astype(np.float32)
+
+    def run(p, pt):
+        if form == "grad":
+            f = make_spmm_fn(p, pt, backend="pallas", interpret=True)
+            return jax.grad(lambda b: jnp.sum(f(b) * G))(B)
+        kw = {"plain": {}, "bias": {"bias": bias},
+              "relu": {"activation": "relu"}}[form]
+        return paramspmm(p, B, interpret=True, **kw)
+
+    jax.clear_caches()              # trace every call anew, so each counts
+    reset_metrics()
+    pt = transpose_pcsr(packs[2])   # 56 rows: its own shapes, its own trace
+    got = run(packs[2], pt)
+    snap = metrics_snapshot()
+    lanes, narrowed = {}, {}
+    for p in [packs[2]] + ([pt] if form == "grad" else []):
+        C = p.steering(covered=True)["trow"].shape[0]
+        name = (f"paramspmm_v2_k{p.K}_r8_d{tile}"
+                f"{'_relu' if form == 'relu' else ''}")
+        for kind, n in (("moved", C * J * p.K * tile),
+                        ("carried", C * p.K * width)):
+            key = f"kernel={name},lanes={kind}"
+            lanes[key] = lanes.get(key, 0) + n
+        if tile < 2 * LANES:
+            narrowed[f"kernel={name}"] = narrowed.get(f"kernel={name}", 0) + 1
+    assert snap["kernel_gather_lanes_total"] == lanes
+    assert snap.get("spmm_dblk_narrowed_total", {}) == narrowed
+
+    same = run(packs[1], transpose_pcsr(packs[1]))
+    if tile == LANES:               # the very kernel the F = 1 pick runs
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(same))
+    else:                           # lanes independent: same sums per lane
+        np.testing.assert_allclose(np.asarray(got), np.asarray(same),
+                                   rtol=1e-6, atol=1e-6)
+    if form == "grad":
+        want = A.T @ G
+    else:
+        want = A @ B + (bias if form == "bias" else 0)
+        if form == "relu":
+            want = np.maximum(want, 0.0)
+    # float32 sums of a few products, in another order than numpy's
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5, atol=1e-5)
