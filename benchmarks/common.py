@@ -110,13 +110,15 @@ def gflops(csr, dim, seconds):
 def count_pallas_calls(fn):
     """Run ``fn`` with the Pallas dispatch intercepted; return the kernel
     names in launch order.  The dispatch is seen while ``fn`` is traced,
-    so this counts the launches of one call of an uncompiled ``fn``; a
-    call that hits the jit cache re-traces nothing and counts none.
+    so this counts the launches of one call of ``fn``: JAX's caches are
+    cleared first, since a kernel dispatch (a jitted function) whose
+    shapes were traced before would re-trace nothing and count none.
     The one shared counter — `tests/test_fusion.py` asserts on it and
     `bench_fusion` records it into BENCH_spmm.json, both through
     ``repro.obs.metrics.intercept_pallas``."""
     from repro.obs.metrics import intercept_pallas
 
+    jax.clear_caches()
     calls: list[str] = []
     with intercept_pallas(calls.append):
         jax.block_until_ready(fn())

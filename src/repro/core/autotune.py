@@ -11,6 +11,7 @@ from __future__ import annotations
 
 import time
 from dataclasses import dataclass
+from types import SimpleNamespace
 
 import jax
 import jax.numpy as jnp
@@ -100,7 +101,7 @@ def _oracle_times(csr, dim, space, mode, reps, rng_seed, cm, op, H,
         for cfg in space:
             times[cfg] = cm.time(dim, cfg, op, H=H)
     elif mode == "measured":
-        from .engine import _engine, _engine_sddmm
+        from .engine import steered_sddmm, steered_spmm
 
         rng = np.random.default_rng(rng_seed)
         d_head = -(-dim // H)
@@ -110,25 +111,23 @@ def _oracle_times(csr, dim, space, mode, reps, rng_seed, cm, op, H,
                 rng.standard_normal((H * csr.n_cols, dim_pad)), jnp.float32)
             pcsr = build_pcsr(csr.indptr, csr.indices, csr.data,
                               csr.n_rows, csr.n_cols, cfg)
-            st = pcsr.steering(H)
-            colidx, lrow, trow, vals = (
-                jnp.asarray(st[k]) for k in ("colidx", "lrow", "trow", "vals"))
+            st = {k: jnp.asarray(v) for k, v in pcsr.steering(H).items()
+                  if k in ("colidx", "lrow", "trow", "vals")}
+            # the H head-tiled copies traversed as one matrix
+            nb = H * pcsr.n_blocks
+            geom = SimpleNamespace(config=cfg, n_rows=nb * cfg.R,
+                                   n_blocks=nb, num_chunks=H * pcsr.num_chunks,
+                                   K=pcsr.K)
             t = 0.0
             if op in ("spmm", "gat"):
-                t += time_fn(
-                    lambda: _engine(colidx, lrow, trow, vals, B, V=cfg.V,
-                                    R=cfg.R, K=pcsr.K,
-                                    n_blocks=H * pcsr.n_blocks,
-                                    n_rows=H * pcsr.n_blocks * cfg.R),
-                    reps=reps)
+                spmm = steered_spmm(st, geom, backend="engine")
+                t += time_fn(lambda: spmm(B), reps=reps)
             if op in ("sddmm", "gat"):
                 Q = jnp.asarray(
                     rng.standard_normal((H * pcsr.n_blocks * cfg.R, dim_pad)),
                     jnp.float32)
-                t += time_fn(
-                    lambda: _engine_sddmm(colidx, lrow, trow, vals, Q, B,
-                                          V=cfg.V, R=cfg.R, K=pcsr.K),
-                    reps=reps)
+                sddmm = steered_sddmm(st, geom, backend="engine")
+                t += time_fn(lambda: sddmm(Q, B), reps=reps)
             times[cfg] = t
     else:
         raise ValueError(mode)

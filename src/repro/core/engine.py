@@ -1,19 +1,26 @@
-"""ParamSpMM computing engine (paper Alg. 2) — pure-JAX implementation.
+"""The operators: SpMM, epilogue-fused SpMM and the GAT attention message,
+over PCSR steering arrays, on either backend.
 
-Same PCSR traversal as the Pallas kernel, expressed as gather + scatter-add
-so it jit-compiles natively on any backend (CPU benchmarking, GNN training)
-and is differentiable.  The Pallas kernel in ``repro.kernels.paramspmm`` is
-the TPU artifact; both are validated against ``ref.py``.
+The pure-JAX engine (paper Alg. 2) walks the same PCSR traversal as the
+Pallas kernel, as gather + scatter-add, so it jit-compiles on any backend
+and is differentiable; the kernels in ``repro.kernels`` are the TPU
+artifact.  Both are validated against ``kernels/*/ref.py``.
 
-``make_spmm_fn`` builds the differentiable operator: the backward SpMM
-``dB = Aᵀ·dC`` runs a second PCSR built for ``Aᵀ`` — GNN training performs
-forward and backward SpMM exactly as the paper's PyTorch extension does.
+Layering: ``core.pcsr`` packs the format; ``kernels/*/ops.py`` hold each
+kernel's one dispatch; this module holds the operators, each written once
+over steering arrays that may be host constants (one device), per-shard
+traced values (``repro.dist``) or traced bucket arrays (``repro.serve``):
 
-``make_gat_message_fn`` builds the attention-GNN operator over the same
-PCSR: SDDMM → LeakyReLU → edge softmax → SpMM, single- or multi-head.  On
-the Pallas backend both the forward (fused softmax epilogue) and the
-dedicated backward (transpose-PCSR SpMMs) run entirely in kernels; see the
-function docstring and docs/OPERATORS.md for the exact pipelines.
+* ``steered_spmm`` / ``steered_sddmm`` — the one place an operator
+  chooses its backend;
+* ``gat_forward`` / ``gat_backward`` — the GAT attention pipeline: the
+  two-kernel forward and its flash-style all-kernel backward;
+* ``make_spmm_fn``, ``make_fused_spmm_fn``, ``make_gat_message_fn`` —
+  the differentiable closures over one PCSR (the backward SpMM
+  ``dB = Aᵀ·dC`` runs a second PCSR built for ``Aᵀ``, as the paper's
+  PyTorch extension does).
+
+See docs/OPERATORS.md for the exact pipelines.
 """
 from __future__ import annotations
 
@@ -107,11 +114,9 @@ def edge_softmax(scores, mask, rows, n_segments: int):
 
 def attend_scores(scores, mask, rows, n_segments: int, *,
                   dim_k: int, slope: float = 0.2):
-    """The GAT attention step shared by every backend: scale raw SDDMM
-    scores by 1/√d_k, LeakyReLU(slope), softmax over each destination
-    row's edge set.  Single source of truth — the single-device message
-    fn and the distributed per-shard branches (``repro.dist.spmm``) must
-    stay semantically identical."""
+    """The GAT attention step of the engine's ``gat_forward``: scale raw
+    SDDMM scores by 1/√d_k, LeakyReLU(slope), softmax over each
+    destination row's edge set."""
     scaled = scores / jnp.sqrt(jnp.asarray(dim_k, scores.dtype))
     scaled = jax.nn.leaky_relu(scaled, negative_slope=slope)
     return edge_softmax(scaled, mask, rows, n_segments)
@@ -132,151 +137,272 @@ def _observed_build(op: str):
     return wrap
 
 
+def _head0(st, C: int, K: int):
+    """The first ``C`` chunks of a steering dict: the uncovered arrays of
+    head 0, whose head offsets are zero — coverage chunks and the other
+    heads' copies pack after them.  No slice where there is nothing to
+    cut."""
+    if st["trow"].shape[0] == C:
+        return st
+    return {"colidx": st["colidx"][:C * K], "lrow": st["lrow"][:C * K],
+            "trow": st["trow"][:C], "vals": st["vals"][:C]}
+
+
+def steered_spmm(st, geom, *, backend: str, interpret: bool | None = None):
+    """The SpMM over steering arrays, on either backend:
+    ``spmm(B, *, vals=None, stats=None, scale=None, bias=None,
+    activation="none", residual=None)`` computes
+    ``act(scale ⊙ (A·B) + bias + residual)`` (``spmm.fused`` is the same
+    function, the fusion surface ``models.gnn`` looks for).
+
+    ``st`` holds covered steering arrays (``PCSR.steering(H,
+    covered=True)``'s keys and layout), as host constants or traced
+    values; ``geom`` is anything with the PCSR's static geometry
+    (``config``, ``n_rows``, ``n_blocks``, ``num_chunks``, ``K``): a
+    ``PCSR`` or a serving bucket's ``PackGeom``.  (H, n, d) operands
+    with (H, C, V, K) ``vals`` run every head.  Pallas goes through the
+    kernel's one dispatch (``kernels.paramspmm.ops.spmm_steered``); the
+    engine traverses head 0's uncovered arrays, vmapped over heads, and
+    applies the epilogue in plain JAX.  This is the one place an SpMM
+    operator chooses its backend."""
+    cfg = geom.config
+    if backend == "pallas":
+        from repro.kernels.paramspmm.ops import spmm_steered
+
+        def spmm(B, *, vals=None, stats=None, scale=None, bias=None,
+                 activation: str = "none", residual=None):
+            return spmm_steered(st, B, vals, cfg=cfg, n_blocks=geom.n_blocks,
+                                K=geom.K, n_rows=geom.n_rows, stats=stats,
+                                scale=scale, bias=bias, residual=residual,
+                                activation=activation, interpret=interpret)
+    else:
+        h0 = _head0(st, geom.num_chunks, geom.K)
+
+        def one(B, vals):
+            return _engine(h0["colidx"], h0["lrow"], h0["trow"], vals, B,
+                           V=cfg.V, R=cfg.R, K=geom.K,
+                           n_blocks=geom.n_blocks, n_rows=geom.n_rows)
+
+        def spmm(B, *, vals=None, stats=None, scale=None, bias=None,
+                 activation: str = "none", residual=None):
+            if stats is not None:
+                raise ValueError("the softmax prologue is a kernel form "
+                                 "(backend='pallas')")
+            if jnp.ndim(B) == 3:
+                out = jax.vmap(one, in_axes=(0, None if vals is None else 0))(
+                    B, h0["vals"] if vals is None else vals)
+            else:
+                out = one(B, h0["vals"] if vals is None else vals)
+            return apply_epilogue(out, scale, bias, activation,
+                                  residual=residual)
+    spmm.fused = spmm
+    return spmm
+
+
+def steered_sddmm(st, geom, *, backend: str, interpret: bool | None = None):
+    """The SDDMM over *uncovered* steering arrays (``PCSR.steering(H)``'s
+    layout, constant or traced), on either backend:
+    ``sddmm(Q, K_mat)`` returns E = (A≠0) ⊙ (Q·Kᵀ) in slot layout, (C, V,
+    K) for (n, d) operands and (H, C, V, K) for (H, n, d) stacks.  Pallas
+    goes through the kernel's one dispatch
+    (``kernels.sddmm.ops.sddmm_steered``); the engine gathers over head
+    0's arrays, vmapped over heads.  ``geom`` as in ``steered_spmm``."""
+    cfg = geom.config
+    if backend == "pallas":
+        from repro.kernels.sddmm.ops import sddmm_steered
+
+        def sddmm(Q, K_mat):
+            if jnp.ndim(Q) == 2:
+                return sddmm(Q[None], K_mat[None])[0]
+            return sddmm_steered(st, Q, K_mat, cfg=cfg,
+                                 n_blocks=geom.n_blocks, K=geom.K,
+                                 interpret=interpret)
+        return sddmm
+    h0 = _head0(st, geom.num_chunks, geom.K)
+
+    def sddmm(Q, K_mat):
+        if jnp.ndim(Q) == 3:
+            return jax.vmap(sddmm)(Q, K_mat)
+        return _engine_sddmm(h0["colidx"], h0["lrow"], h0["trow"],
+                             h0["vals"], Q, K_mat, V=cfg.V, R=cfg.R,
+                             K=geom.K)
+    return sddmm
+
+
+def _heads(x) -> int:
+    """Head count of an (n, d) operand (1) or an (H, n, d) stack."""
+    return x.shape[0] if jnp.ndim(x) == 3 else 1
+
+
+def _per_head(f, x, *args):
+    """``f`` on one head's slots (C, V, K), or vmapped over a stack's."""
+    return jax.vmap(f)(x, *args) if x.ndim == 4 else f(x, *args)
+
+
+def gat_forward(st, sd, Q, K_mat, Vf, *, geom, backend: str,
+                interpret: bool | None = None, slope: float = 0.2):
+    """The GAT attention message, forward: SDDMM → scale 1/√d_k →
+    LeakyReLU(slope) → softmax over each destination row's edges → SpMM.
+    Returns ``(out, residuals)``: out (n_rows, d) for (n, d) operands,
+    (H, n_rows, d) for (H, n, d) stacks.
+
+    ``st`` and ``sd`` hold the covered and the uncovered (head-tiled)
+    steering arrays — the SpMM's and the SDDMM's,
+    ``PCSR.steering(H, covered=True)`` and ``PCSR.steering(H)``'s
+    layouts, constant or traced — and ``geom`` the static geometry, as in
+    ``steered_spmm``.  On Pallas this is the **two-kernel forward**: the
+    stats SDDMM (row max and normaliser accumulated while the score block
+    is VMEM resident) hands (logits, rowmax, rowsum) to the SpMM's
+    softmax *prologue*, which rebuilds α in-register — α never reaches
+    HBM, and the three are the residuals ``gat_backward`` takes.  The
+    engine computes α in plain JAX and returns no residuals (it
+    differentiates natively)."""
+    cfg = geom.config
+    spmm = steered_spmm(st, geom, backend=backend, interpret=interpret)
+    if backend == "pallas":
+        from repro.kernels.sddmm.ops import sddmm_softmax_steered
+        single = jnp.ndim(Q) == 2
+        logits, rowmax, rowsum = sddmm_softmax_steered(
+            sd, Q[None] if single else Q, K_mat[None] if single else K_mat,
+            cfg=cfg, n_blocks=geom.n_blocks, K=geom.K, slope=slope,
+            interpret=interpret)
+        if single:
+            logits = logits[0]
+        return (spmm(Vf, vals=logits, stats=(rowmax, rowsum)),
+                (logits, rowmax, rowsum))
+    h0 = _head0(sd, geom.num_chunks, geom.K)
+    rows = _slot_rows(h0["lrow"], h0["trow"], V=cfg.V, R=cfg.R, K=geom.K)
+    mask = h0["vals"] != 0
+    scores = steered_sddmm(sd, geom, backend=backend)(Q, K_mat)
+    alpha = _per_head(lambda s: attend_scores(
+        s, mask, rows, geom.n_blocks * cfg.R, dim_k=Q.shape[-1],
+        slope=slope), scores)
+    return spmm(Vf, vals=alpha), None
+
+
+def gat_backward(st, sd, st_t, f_idx, t_idx, res, dOut, *, geom, geom_t,
+                 interpret: bool | None = None, slope: float = 0.2):
+    """``(dQ, dK, dVf)`` of the Pallas ``gat_forward``, flash-style: the
+    residuals ``res = (Q, K_mat, Vf, logits, rowmax, rowsum)`` hold no α,
+    which is recomputed from the stats; every heavy op is a kernel over
+    the forward or the transpose steering:
+
+        α   = exp(logits − rowmax)/rowsum       (recompute, no residual)
+        dα  = SDDMM(A,  dOut, Vf)              (dα_ij = dOut_i·Vf_j)
+        dx  = α ⊙ (dα − Σ_row α·dα)            (softmax vjp, per-slot)
+        de  = dx · scale · LeakyReLU'(x)        (activation chain)
+        dQ  = SpMM(A,  de, K)                  (row-gather of keys)
+        dK  = SpMM(Aᵀ, deᵀ, Q)                 (transpose-PCSR SpMM)
+        dVf = SpMM(Aᵀ, αᵀ, dOut)               (transpose-PCSR SpMM)
+
+    ``st``/``sd`` are A's covered and uncovered steering (as in
+    ``gat_forward``), ``st_t`` Aᵀ's covered one, with geometries
+    ``geom``/``geom_t``; slot tensors move onto Aᵀ's layout through the
+    transfer maps ``f_idx``/``t_idx`` (``core.pcsr.slot_transfer_map``;
+    entries past Aᵀ's slots drop, so the maps may be padded).  dK and
+    dVf span Aᵀ's rows (A's columns)."""
+    from repro.kernels.sddmm.ops import normalize_from_stats, unpack_stats
+    Q, K_mat, Vf, logits, rowmax, rowsum = res
+    cfg = geom.config
+    V, R, K, C, nb = cfg.V, cfg.R, geom.K, geom.num_chunks, geom.n_blocks
+    # head 0's slot→row map (its head offsets are zero); host steering
+    # makes these constants of the program
+    h0 = _head0(sd, C, K)
+    with jax.ensure_compile_time_eval():
+        lrow, trow = jnp.asarray(h0["lrow"]), jnp.asarray(h0["trow"])
+        flat_rows = _slot_rows(lrow, trow, V=V, R=R, K=K).reshape(-1)
+
+    def alpha_1h(lg, rm, rs):
+        """α recomputed from the stats residuals (one head) — the one
+        normalize implementation, shared with ``kernels.sddmm.ops``, so
+        the masked-slot/empty-row guard convention cannot drift."""
+        return normalize_from_stats(lg, rm, rs, lrow, trow, R=R, V=V, K=K)
+
+    def row_sum(x):
+        """Per-slot broadcast of Σ over each destination row's slots."""
+        s = jax.ops.segment_sum(x.reshape(-1), flat_rows, num_segments=nb * R)
+        return s[flat_rows].reshape(x.shape)
+
+    rm = unpack_stats(rowmax, R)           # tile-aligned → dense (·, R)
+    rs = unpack_stats(rowsum, R)
+    if logits.ndim == 4:                   # (H, C, V, K): stats per head
+        H = logits.shape[0]
+        rm, rs = rm.reshape(H, -1, R), rs.reshape(H, -1, R)
+    alpha = _per_head(alpha_1h, logits, rm, rs)
+    scale = 1.0 / jnp.sqrt(jnp.asarray(Q.shape[-1], dOut.dtype))
+    dalpha = steered_sddmm(sd, geom, backend="pallas",
+                           interpret=interpret)(dOut, Vf)
+    dx = alpha * (dalpha - _per_head(row_sum, alpha * dalpha))  # softmax vjp
+    # LeakyReLU' from the saved logits: LeakyReLU preserves sign, so
+    # sign(logits) = sign(pre-activation); masked slots (logit −inf)
+    # have dx = 0, so the slope branch they fall into is inert.
+    de = dx * scale * jnp.where(logits >= 0, 1.0, slope)
+    n_tslots = geom_t.num_chunks * V * geom_t.K
+
+    def to_transpose(x):
+        """Re-lay a (..., C, V, K) slot tensor onto Aᵀ's slots."""
+        lead = x.shape[:-3]
+        tf = jnp.zeros(lead + (n_tslots,), x.dtype)
+        tf = tf.at[..., t_idx].set(x.reshape(lead + (-1,))[..., f_idx],
+                                   mode="drop")
+        return tf.reshape(lead + (geom_t.num_chunks, V, geom_t.K))
+
+    spmm = steered_spmm(st, geom, backend="pallas", interpret=interpret)
+    spmm_t = steered_spmm(st_t, geom_t, backend="pallas", interpret=interpret)
+    dQ = spmm(K_mat, vals=de)
+    dK = spmm_t(Q, vals=to_transpose(de))
+    dVf = spmm_t(dOut, vals=to_transpose(alpha))
+    return dQ, dK, dVf
+
+
 @_observed_build("gat_message")
 def make_gat_message_fn(pcsr: PCSR, pcsr_t: Optional[PCSR] = None, *,
                         backend: str | None = None,
                         interpret: bool | None = None, slope: float = 0.2):
-    """Differentiable fused GAT message ``f(Q, K, Vf) -> (n_rows, d)``:
-    SDDMM → LeakyReLU → softmax-over-edges → SpMM, all over one PCSR.
-
-    Scores are scaled by 1/√d_k (dot-product attention) then passed through
-    LeakyReLU(slope) as in GAT.  Multi-head: rank-3 ``(H, n, d)`` operands
-    return ``(H, n_rows, d)`` — the Pallas backend batches every head
-    through one head-tiled kernel call (a single compilation), the engine
-    backend vmaps its jitted path.
+    """Differentiable fused GAT message ``f(Q, K, Vf) -> (n_rows, d)``
+    over one PCSR: ``gat_forward`` on its steering.  Multi-head: rank-3
+    ``(H, n, d)`` operands return ``(H, n_rows, d)`` — the Pallas backend
+    batches every head through one head-tiled kernel call.
 
     Backends (``None`` resolves from the platform, see
-    ``repro.kernels.resolve_backend``):
-
-    * ``"engine"`` — the pure-JAX path, returned as-is: natively
-      differentiable, no ``custom_vjp`` required.
-    * ``"pallas"`` — the **two-kernel forward**: the fused SDDMM→softmax
-      kernel (``sddmm_softmax_stats``: row max/normalizer accumulated in
-      the kernel epilogue while the score block is VMEM resident) hands
-      (logits, rowmax, rowsum) straight to the SpMM kernel's softmax
-      *prologue* (``paramspmm_with_vals(stats=...)``), which rebuilds
-      α = exp(logit − max)/Σ in-register while loading vals — NO
-      interstitial elementwise pass and α is never materialized in HBM.
-
-      The backward is flash-style recompute: residuals are only the raw
-      logits + the two tile-aligned row-stat arrays
-      ((n_blocks·SUBLANES, LANES), the kernel's native layout) — the
-      (C, V, K) α residual is dropped and α is recomputed from the stats
-      where the vjp needs it.  The pipeline is dedicated all-Pallas — no engine
-      fallback:
-
-        α   = exp(logits − rowmax)/rowsum       (recompute, no residual)
-        dα  = SDDMM(pcsr, dOut, Vf)            (dα_ij = dOut_i·Vf_j)
-        dx  = α ⊙ (dα − Σ_row α·dα)            (softmax vjp, per-slot)
-        de  = dx · scale · LeakyReLU'(x)        (activation chain)
-        dQ  = SpMM(pcsr,  de, K)               (row-gather of keys)
-        dK  = SpMM(pcsrᵀ, deᵀ, Q)              (transpose-PCSR SpMM)
-        dVf = SpMM(pcsrᵀ, αᵀ, dOut)            (transpose-PCSR SpMM)
-
-      The transpose PCSR is built once (``core.pcsr.transpose_pcsr``) when not
-      supplied — pass ``ParamSpMMOperator.pcsr_t`` to share the cached one
-      — and slot tensors move between the two layouts through a
-      precomputed ``slot_transfer_map`` gather/scatter.
-    """
+    ``repro.kernels.resolve_backend``): the engine is returned as-is,
+    natively differentiable; Pallas wraps the two-kernel forward in a
+    ``custom_vjp`` whose backward is ``gat_backward`` — all kernels, no
+    engine fallback.  The transpose PCSR is built once
+    (``core.pcsr.transpose_pcsr``) when not supplied — pass
+    ``ParamSpMMOperator.pcsr_t`` to share the cached one."""
     backend, interpret = resolve_backend(backend, interpret)
-    arrs = pcsr.to_jax()
-    cfg = pcsr.config
-    V, R, K, n_blocks = cfg.V, cfg.R, pcsr.K, pcsr.n_blocks
-    n_rows = pcsr.n_rows
-    mask = arrs["vals"] != 0
-    rows = _slot_rows(arrs["lrow"], arrs["trow"], V=V, R=R, K=K)
 
-    def _attend(scores, Q):
-        return attend_scores(scores, mask, rows, n_blocks * R,
-                             dim_k=Q.shape[1], slope=slope)
+    def steering(p, H):
+        return p.steering(H, covered=True), p.steering(H)
 
-    def engine_path(Q, K_mat, Vf):
-        scores = _engine_sddmm(arrs["colidx"], arrs["lrow"], arrs["trow"],
-                               arrs["vals"], Q, K_mat, V=V, R=R, K=K)
-        alpha = _attend(scores, Q)
-        return _engine(arrs["colidx"], arrs["lrow"], arrs["trow"], alpha,
-                       Vf, V=V, R=R, K=K, n_blocks=n_blocks, n_rows=n_rows)
-
-    def engine_fn(Q, K_mat, Vf):
-        if jnp.ndim(Q) == 3:
-            return jax.vmap(engine_path)(Q, K_mat, Vf)
-        return engine_path(Q, K_mat, Vf)
+    def forward(Q, K_mat, Vf):
+        return gat_forward(*steering(pcsr, _heads(Q)), Q, K_mat, Vf,
+                           geom=pcsr, backend=backend, interpret=interpret,
+                           slope=slope)
 
     if backend != "pallas":
-        return engine_fn            # natively differentiable, no vjp needed
-
-    from repro.kernels.paramspmm.ops import paramspmm_with_vals
-    from repro.kernels.sddmm.ops import (normalize_from_stats,
-                                         sddmm as _sddmm_call,
-                                         sddmm_softmax_stats, unpack_stats)
+        return lambda Q, K_mat, Vf: forward(Q, K_mat, Vf)[0]
 
     from .pcsr import slot_transfer_map, transpose_pcsr
     if pcsr_t is None:
         pcsr_t = transpose_pcsr(pcsr)
     f_idx, t_idx = slot_transfer_map(pcsr, pcsr_t)
-    n_tslots = pcsr_t.num_chunks * cfg.V * pcsr_t.K
-    flat_rows = rows.reshape(-1)
-
-    def _to_transpose(x):
-        """Re-lay a (..., C, V, K) slot tensor onto the Aᵀ PCSR's slots."""
-        lead = x.shape[:-3]
-        tf = jnp.zeros(lead + (n_tslots,), x.dtype)
-        tf = tf.at[..., t_idx].set(x.reshape(lead + (-1,))[..., f_idx])
-        return tf.reshape(lead + (pcsr_t.num_chunks, cfg.V, pcsr_t.K))
-
-    def _rowsum(x):
-        """Per-slot broadcast of Σ over each destination row's slots."""
-        s = jax.ops.segment_sum(x.reshape(-1), flat_rows,
-                                num_segments=n_blocks * R)
-        return s[flat_rows].reshape(x.shape)
-
-    def _alpha_1h(logits, rowmax, rowsum):
-        """Flash-style α recompute from the stats residuals (one head) —
-        the single normalize implementation shared with sddmm/ops, so the
-        masked-slot/empty-row guard convention cannot drift."""
-        return normalize_from_stats(logits, rowmax, rowsum, arrs["lrow"],
-                                    arrs["trow"], R=R, V=V, K=K)
-
-    def _alpha(logits, rowmax, rowsum):
-        rm = unpack_stats(rowmax, R)       # tile-aligned → dense (·, R)
-        rs = unpack_stats(rowsum, R)
-        if logits.ndim == 4:                            # (H, C, V, K)
-            H = logits.shape[0]
-            return jax.vmap(_alpha_1h)(logits, rm.reshape(H, -1, R),
-                                       rs.reshape(H, -1, R))
-        return _alpha_1h(logits, rm, rs)
-
-    def fwd_path(Q, K_mat, Vf):
-        logits, rowmax, rowsum = sddmm_softmax_stats(
-            pcsr, Q, K_mat, slope=slope, interpret=interpret)
-        out = paramspmm_with_vals(pcsr, logits, Vf, stats=(rowmax, rowsum),
-                                  interpret=interpret)
-        return out, (Q, K_mat, Vf, logits, rowmax, rowsum)
 
     @jax.custom_vjp
     def f(Q, K_mat, Vf):
-        return fwd_path(Q, K_mat, Vf)[0]
+        return forward(Q, K_mat, Vf)[0]
 
     def f_fwd(Q, K_mat, Vf):
-        return fwd_path(Q, K_mat, Vf)
+        out, res = forward(Q, K_mat, Vf)
+        return out, (Q, K_mat, Vf) + res
 
     def f_bwd(res, dOut):
-        Q, K_mat, Vf, logits, rowmax, rowsum = res
-        alpha = _alpha(logits, rowmax, rowsum)          # recompute, cheap
-        scale = 1.0 / jnp.sqrt(jnp.asarray(Q.shape[-1], dOut.dtype))
-        dalpha = _sddmm_call(pcsr, dOut, Vf, interpret=interpret)
-        rsum = (jax.vmap(_rowsum) if alpha.ndim == 4 else _rowsum)
-        dx = alpha * (dalpha - rsum(alpha * dalpha))       # softmax vjp
-        # LeakyReLU' from the saved logits: LeakyReLU preserves sign, so
-        # sign(logits) = sign(pre-activation); masked slots (logit −inf)
-        # have dx = 0, so the slope branch they fall into is inert.
-        de = dx * scale * jnp.where(logits >= 0, 1.0, slope)
-        dQ = paramspmm_with_vals(pcsr, de, K_mat, interpret=interpret)
-        dK = paramspmm_with_vals(pcsr_t, _to_transpose(de), Q,
-                                 interpret=interpret)
-        dVf = paramspmm_with_vals(pcsr_t, _to_transpose(alpha), dOut,
-                                  interpret=interpret)
-        return dQ, dK, dVf
+        H = _heads(dOut)
+        return gat_backward(*steering(pcsr, H),
+                            pcsr_t.steering(H, covered=True), f_idx, t_idx,
+                            res, dOut, geom=pcsr, geom_t=pcsr_t,
+                            interpret=interpret, slope=slope)
 
     f.defvjp(f_fwd, f_bwd)
     return f
@@ -292,20 +418,12 @@ def make_spmm_fn(pcsr: PCSR, pcsr_t: Optional[PCSR] = None, *,
     through the engine's gather/scatter directly.
     """
     backend, interpret = resolve_backend(backend, interpret)
-    if backend == "pallas":
-        from repro.kernels.paramspmm.ops import paramspmm as _fwd_call
-        fwd = lambda B: _fwd_call(pcsr, B, interpret=interpret)
-    else:
-        fwd = lambda B: engine_spmm(pcsr, B)
-
+    fwd = steered_spmm(pcsr.steering(covered=True), pcsr, backend=backend,
+                       interpret=interpret)
     if pcsr_t is None:
-        return fwd
-
-    if backend == "pallas":
-        from repro.kernels.paramspmm.ops import paramspmm as _bwd_call
-        bwd = lambda dC: _bwd_call(pcsr_t, dC, interpret=interpret)
-    else:
-        bwd = lambda dC: engine_spmm(pcsr_t, dC)
+        return lambda B: fwd(B)     # plain A·B: no fusion surface
+    bwd = steered_spmm(pcsr_t.steering(covered=True), pcsr_t,
+                       backend=backend, interpret=interpret)
 
     @jax.custom_vjp
     def f(B):
@@ -398,31 +516,14 @@ def make_fused_spmm_fn(pcsr: PCSR, pcsr_t: Optional[PCSR] = None, *,
     Pallas path requires it for gradients.
     """
     backend, interpret = resolve_backend(backend, interpret)
-    if backend == "pallas":
-        from repro.kernels.paramspmm.ops import paramspmm
-
-        def fwd_call(B, scale, bias, residual, activation):
-            return paramspmm(pcsr, B, scale=scale, bias=bias,
-                             residual=residual, activation=activation,
-                             interpret=interpret)
-
-        def bwd_call(dC):
-            return paramspmm(pcsr_t, dC, interpret=interpret)
-    else:
-        def fwd_call(B, scale, bias, residual, activation):
-            return engine_spmm_fused(pcsr, B, scale=scale, bias=bias,
-                                     residual=residual,
-                                     activation=activation)
-
-        def bwd_call(dC):
-            return engine_spmm(pcsr_t, dC)
+    fwd = steered_spmm(pcsr.steering(covered=True), pcsr, backend=backend,
+                       interpret=interpret)
 
     if backend != "pallas" and pcsr_t is None:
-        def fused(B, scale=None, bias=None, activation: str = "none",
-                  residual=None):
-            return fwd_call(B, scale, bias, residual,
-                            activation)  # native autodiff
-        return fused
+        return fwd                         # native autodiff
+    bwd = None if pcsr_t is None else steered_spmm(
+        pcsr_t.steering(covered=True), pcsr_t, backend=backend,
+        interpret=interpret)
 
     vjps: dict = {}                # one custom_vjp per activation
 
@@ -433,10 +534,12 @@ def make_fused_spmm_fn(pcsr: PCSR, pcsr_t: Optional[PCSR] = None, *,
         # trained parameter.
         @jax.custom_vjp
         def f(B, scale, bias, residual):
-            return fwd_call(B, scale, bias, residual, activation)
+            return fwd(B, scale=scale, bias=bias, activation=activation,
+                       residual=residual)
 
         def f_fwd(B, scale, bias, residual):
-            out = fwd_call(B, scale, bias, residual, activation)
+            out = fwd(B, scale=scale, bias=bias, activation=activation,
+                      residual=residual)
             return out, (out, scale, bias, residual is not None)
 
         def f_bwd(res, dOut):
@@ -449,7 +552,7 @@ def make_fused_spmm_fn(pcsr: PCSR, pcsr_t: Optional[PCSR] = None, *,
             dbias = None if bias is None else dpre.sum(axis=0)
             dresid = dpre if has_resid else None
             dcb = dpre if scale is None else dpre * scale[:, None]
-            dB = bwd_call(dcb)
+            dB = bwd(dcb)
             dscale = None if scale is None else jnp.zeros_like(scale)
             return dB, dscale, dbias, dresid
 

@@ -279,10 +279,6 @@ class PCSR:
                       "init": init, "fini": fini, "vals": vals}
         return cache[key]
 
-    def head_tiled(self, H: int):
-        """Back-compat alias for ``steering(H)`` (uncovered arrays)."""
-        return self.steering(H)
-
 
 def _vectorize(indptr, indices, data, n_rows, n_cols, V):
     """Group nonzeros into V×1 panel vectors.

@@ -1,37 +1,34 @@
-"""Distributed GAT message: multi-head shards + an all-Pallas backward.
+"""Distributed GAT message: multi-head shards, the GAT pipeline per shard.
 
-The single-device GAT hot path (``core.engine.make_gat_message_fn``) is a
-two-kernel forward — fused SDDMM→softmax *stats* kernel feeding the
-ParamSpMM softmax *prologue* — with a flash-style recompute backward
-whose heavy ops are three more kernels over the forward and transpose
-PCSRs.  This module runs exactly that pipeline **per shard inside one
-SPMD ``shard_map`` program**, multi-head:
+The GAT attention pipeline — the two-kernel forward and the flash-style
+all-kernel backward — is written once, in ``core.engine`` (``gat_forward``
+/ ``gat_backward``), over steering arrays.  This module runs it **per
+shard inside one SPMD ``shard_map`` program**, multi-head, and keeps only
+what is distributed:
 
 * **forward** — K/Vf are halo-exchanged jointly (one ``all_gather``
   serves every head of both operands: heads travel merged as
   ``(rows, H·d)`` columns), then each shard's branch splits the heads
-  and batches them through its OWN head-tiled steering arrays
+  and runs ``gat_forward`` over its OWN head-tiled steering
   (``PCSR.steering(H, covered=True)``, packed per partition by
-  ``packing.pack_shards(H=)``) — exactly two Pallas kernels per shard,
-  α never in HBM, one compilation for the whole head batch.
-* **backward** — a ``custom_vjp`` (Pallas backend): residuals are the
-  primals plus the per-shard raw logits and the tile-aligned
-  ``(H·n_blocks·SUBLANES, LANES)`` row stats (flash-style — no α
-  residual); the backward shard_map program
-  re-exchanges the K/Vf halo (recompute over memory), recomputes α from
-  the stats, runs dα-SDDMM, dQ-SpMM and the transpose-PCSR dK/dVf SpMMs
-  as Pallas kernels, and scatters the halo blocks of dK/dVf back to
-  their owner shards through ``halo_scatter_back`` — no engine fallback
-  anywhere (enforced by test).
+  ``packing.pack_shards(H=)``; ``_uncov`` views give the SDDMM's
+  uncovered arrays) — on Pallas exactly two kernels per shard.
+* **backward** — a ``custom_vjp`` (Pallas backend): the forward pads
+  its residuals (raw logits and tile-aligned row stats) to uniform
+  widths so they cross the ``shard_map`` boundary; the backward program
+  re-exchanges the K/Vf halo (recompute over memory), runs
+  ``gat_backward`` per shard and scatters the halo blocks of dK/dVf back
+  to their owner shards through ``halo_scatter_back`` — no engine
+  fallback anywhere (enforced by test).
 
 Row partitioning keeps every destination row's full edge set on one
 shard, so the softmax — forward stats and backward vjp alike — never
 communicates; only the operand halo exchange and the gradient
 scatter-back cross the mesh.
 
-The engine backend keeps the natively-differentiable pure-JAX pipeline
-(vmapped over heads); its halo gradients flow back through the autodiff
-transpose of ``all_gather`` (a ``psum_scatter``).
+The engine backend runs the same forward as one natively-differentiable
+program; its halo gradients flow back through the autodiff transpose of
+``all_gather`` (a ``psum_scatter``).
 """
 from __future__ import annotations
 
@@ -42,14 +39,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.engine import (_engine, _engine_sddmm, _slot_rows,
-                               attend_scores)
+from repro.core.engine import gat_backward, gat_forward
 from repro.core.pcsr import (LANES, SUBLANES, slot_transfer_map,
                              transpose_pcsr)
 from repro.obs import trace as _obs_trace
 
 from .halo import halo_exchange, halo_scatter_back
-from .packing import AXIS, PackedShards, pack_shards, shard_map_2d
+from .packing import (AXIS, PackedShards, pack_shards, shard_map_2d,
+                      shard_steering)
 
 # transfer-map padding: out-of-bounds target slots are dropped by the
 # scatter (mode="drop"), so padded map entries can never alias real slots
@@ -68,11 +65,19 @@ def head_merge(x3):
     return x3.transpose(1, 0, 2).reshape(n, H * d)
 
 
-def _uncov(a, per: int, H: int, Cc: int, C: int):
-    """Recover the uncovered H-tiled array from a covered one: per head,
-    the real chunks are the ``[:C·per]`` prefix of that head's
-    ``Cc·per``-long segment (coverage chunks pack last)."""
-    return a[:H * Cc * per].reshape(H, Cc * per)[:, :C * per].reshape(-1)
+def _uncov(st, H: int, pcsr):
+    """The uncovered head-tiled view of a shard's covered head-tiled
+    steering: per head, the real chunks are the ``[:C]`` prefix of that
+    head's ``C_cov``-chunk segment (coverage chunks pack last)."""
+    Cc, C = pcsr.covered_num_chunks, pcsr.num_chunks
+    if Cc == C:
+        return st
+
+    def cut(a):
+        per = a.shape[0] // (H * Cc)
+        return a.reshape((H, Cc * per) + a.shape[1:])[:, :C * per] \
+            .reshape((H * C * per,) + a.shape[1:])
+    return {k: cut(v) for k, v in st.items()}
 
 
 @dataclass
@@ -138,181 +143,47 @@ def _build_gat_bwd_pack(pack: GatShardPack) -> None:
 
 
 # ------------------------------------------------------------ branches
-def _engine_fwd_branch(pcsr, *, H: int, n_out: int, slope: float):
-    """Pure-JAX per-shard branch: SDDMM → attend → SpMM, vmapped over
-    heads.  Natively differentiable — the engine backend's whole
-    distributed GAT program is plain autodiff."""
-    cfg = pcsr.config
-    C, K, V, R, nb = pcsr.num_chunks, pcsr.K, cfg.V, cfg.R, pcsr.n_blocks
-    S, VS = C * K, C * V * K
-
+def _fwd_branch(pcsr, *, H: int, slope: float, backend: str,
+                interpret: bool, logits_pad: int, stats_pad: int):
+    """``gat_forward`` with shard-static shapes.  On Pallas it returns
+    (out, logits, rowmax, rowsum), the latter three flat and padded to
+    the pack-uniform residual widths (the backward's inputs)."""
     def branch(colidx, lrow, trow, init, fini, vals, q2, kx2, vfx2):
-        ci, lr, tr = colidx[:S], lrow[:S], trow[:C]
-        vv = vals[:VS].reshape(C, V, K)
-        rows = _slot_rows(lr, tr, V=V, R=R, K=K)
-
-        def one(qh, kh, vfh):
-            scores = _engine_sddmm(ci, lr, tr, vv, qh, kh, V=V, R=R, K=K)
-            alpha = attend_scores(scores, vv != 0, rows, nb * R,
-                                  dim_k=qh.shape[1], slope=slope)
-            return _engine(ci, lr, tr, alpha, vfh, V=V, R=R, K=K,
-                           n_blocks=nb, n_rows=n_out)
-
-        out = jax.vmap(one)(head_split(q2, H), head_split(kx2, H),
-                            head_split(vfx2, H))
-        return head_merge(out)
-    return branch
-
-
-def _pallas_fwd_branch(pcsr, *, H: int, n_out: int, slope: float,
-                       interpret: bool, logits_pad: int, stats_pad: int):
-    """The two-kernel fused forward with shard-static shapes: fused
-    SDDMM→softmax-stats kernel, then the ParamSpMM softmax-prologue
-    kernel over the covered head-tiled steering — α never materializes.
-    Returns (out, logits, rowmax, rowsum), the latter three padded to
-    the pack-uniform residual widths (flash-style backward inputs)."""
-    from repro.kernels.paramspmm.kernel import paramspmm_kernel
-    from repro.kernels.paramspmm.ops import _pad_chunk_vals, _pad_cols
-    from repro.kernels.sddmm.kernel import sddmm_softmax_kernel
-    from repro.kernels.sddmm.ops import _pad_q
-
-    cfg = pcsr.config
-    C, K, V, W = pcsr.num_chunks, pcsr.K, cfg.V, cfg.W
-    nb, R, dblk = pcsr.n_blocks, cfg.R, cfg.dblk
-    Cc = pcsr.covered_num_chunks
-
-    def branch(colidx, lrow, trow, init, fini, vals, q2, kx2, vfx2):
-        q, kx, vfx = (head_split(x, H) for x in (q2, kx2, vfx2))
-        da, dv = q.shape[2], vfx.shape[2]
-        # kernel 1: fused SDDMM → logits + online-softmax row stats, over
-        # the uncovered head-tiled steering (stats of visited blocks only)
-        Qp = _pad_q(q, nb * R, dblk).reshape(H * nb * R, -1)
-        Kp, _ = _pad_cols(kx.reshape(-1, da), dblk)
-        logits, rowmax, rowsum = sddmm_softmax_kernel(
-            _uncov(colidx, K, H, Cc, C), _uncov(lrow, K, H, Cc, C),
-            _uncov(trow, 1, H, Cc, C), _uncov(init, 1, H, Cc, C),
-            vals[:H * Cc * V * K].reshape(H, Cc, V, K)[:, :C]
-            .reshape(H * C, V, K),
-            Qp, Kp, n_blocks=H * nb, W=W, V=V, K=K, dblk=dblk,
-            scale=float(1.0 / np.sqrt(da)), slope=slope,
-            interpret=interpret)
-        # kernel 2: prologue SpMM — logits in, α rebuilt in-register;
-        # coverage chunks carry −inf logits (exact α = 0)
-        lg = _pad_chunk_vals(logits.reshape(H, C, V, K), Cc - C, -jnp.inf)
-        tile = cfg.dblk_for(dv)
-        Bp, _ = _pad_cols(vfx.reshape(-1, dv), tile)
-        out = paramspmm_kernel(
-            colidx[:H * Cc * K], lrow[:H * Cc * K], trow[:H * Cc],
-            init[:H * Cc], fini[:H * Cc], lg.reshape(H * Cc, V, K), Bp,
-            n_blocks=H * nb, R=R, V=V, K=K, dblk=tile, widest=dblk,
-            rowmax=rowmax, rowsum=rowsum, interpret=interpret)
-        out = out[:, :dv].reshape(H, nb * R, dv)[:, :n_out]
+        st = shard_steering(pcsr, colidx, lrow, trow, init, fini, vals, H)
+        out, res = gat_forward(st, _uncov(st, H, pcsr), head_split(q2, H),
+                               head_split(kx2, H), head_split(vfx2, H),
+                               geom=pcsr, backend=backend,
+                               interpret=interpret, slope=slope)
+        if res is None:
+            return head_merge(out)
         pad1 = lambda x, L: jnp.pad(x.reshape(-1), (0, L - x.size))[None, :]
-        return (head_merge(out), pad1(logits, logits_pad),
-                pad1(rowmax, stats_pad), pad1(rowsum, stats_pad))
+        return (head_merge(out),) + tuple(
+            pad1(x, L) for x, L in zip(res, (logits_pad, stats_pad,
+                                             stats_pad)))
     return branch
 
 
-def _pallas_bwd_branch(pcsr, pcsr_t, *, H: int, n_out: int, slope: float,
-                       interpret: bool):
-    """The flash-style all-Pallas per-shard backward: α recomputed from
-    the (logits, row-stats) residuals, then
-
-        dα   = SDDMM(pcsr, dOut, Vf_ext)        [Pallas]
-        dx   = α ⊙ (dα − Σ_row α·dα)            (softmax vjp, per slot)
-        de   = dx · scale · LeakyReLU'(logits)
-        dQ   = SpMM(pcsr,  de, K_ext)           [Pallas]
-        dK   = SpMM(pcsrᵀ, deᵀ, Q)              [Pallas, transpose PCSR]
-        dVf  = SpMM(pcsrᵀ, αᵀ, dOut)            [Pallas, transpose PCSR]
-
-    — the same pipeline as the single-device vjp, with slot tensors moved
-    onto the transpose layout through the packed transfer maps.  dK/dVf
-    come back over the extended column space; the caller scatters their
-    halo blocks home."""
-    from repro.kernels.paramspmm.kernel import paramspmm_kernel
-    from repro.kernels.paramspmm.ops import _pad_chunk_vals, _pad_cols
-    from repro.kernels.sddmm.kernel import sddmm_kernel
-    from repro.kernels.sddmm.ops import (_pad_q, normalize_from_stats,
-                                         unpack_stats)
-
-    cfg = pcsr.config
-    C, K, V, W = pcsr.num_chunks, pcsr.K, cfg.V, cfg.W
-    nb, R, dblk = pcsr.n_blocks, cfg.R, cfg.dblk
-    Cc = pcsr.covered_num_chunks
-    Ct, Kt, nbt = pcsr_t.num_chunks, pcsr_t.K, pcsr_t.n_blocks
-    Ctc = pcsr_t.covered_num_chunks
-    n_tslots = Ct * V * Kt
-    ext = pcsr.n_cols                      # = pcsr_t.n_rows
-
-    def spmm_heads(col, lr, tr, it, fi, vals4, B3, *, Cc_, Kc, nb_, n_r):
-        """One head-tiled Pallas SpMM over covered steering; ``vals4``
-        are the real chunks (coverage appended here, fill 0)."""
-        d = B3.shape[2]
-        v = _pad_chunk_vals(vals4, Cc_ - vals4.shape[1], 0.0)
-        tile = cfg.dblk_for(d)
-        Bp, _ = _pad_cols(B3.reshape(-1, d), tile)
-        out = paramspmm_kernel(
-            col[:H * Cc_ * Kc], lr[:H * Cc_ * Kc], tr[:H * Cc_],
-            it[:H * Cc_], fi[:H * Cc_], v.reshape(H * Cc_, V, Kc), Bp,
-            n_blocks=H * nb_, R=R, V=V, K=Kc, dblk=tile, widest=dblk,
-            interpret=interpret)
-        return out[:, :d].reshape(H, nb_ * R, d)[:, :n_r]
+def _bwd_branch(pcsr, pcsr_t, *, H: int, slope: float, interpret: bool):
+    """``gat_backward`` with shard-static shapes: the residuals are cut
+    back from their uniform widths; dK/dVf come back over the extended
+    column space, and the caller scatters their halo blocks home."""
+    C, K, V, nb = pcsr.num_chunks, pcsr.K, pcsr.config.V, pcsr.n_blocks
 
     def branch(fcol, flrow, ftrow, finit, ffini, fvals,
                tcol, tlrow, ttrow, tinit, tfini, tvals,
                fidx, tidx, do2, q2, kx2, vfx2, lgf, rmf, rsf):
-        do, q, kx, vfx = (head_split(x, H) for x in (do2, q2, kx2, vfx2))
-        da, dv = q.shape[2], do.shape[2]
-        uvals = fvals[:H * Cc * V * K].reshape(H, Cc, V, K)[:, :C]
-        # single-head slot→row map: head 0's prefix has zero offsets
-        lr1, tr1 = flrow[:C * K], ftrow[:C]
-        rows1 = _slot_rows(lr1, tr1, V=V, R=R, K=K).reshape(-1)
-        # α recompute from the stats residuals (no α residual saved);
-        # stats travel flat in the kernels' tile-aligned layout
-        logits = lgf[:H * C * V * K].reshape(H, C, V, K)
-        untile = lambda x: unpack_stats(
-            x[:H * nb * SUBLANES * LANES].reshape(H * nb * SUBLANES, LANES),
-            R).reshape(H, nb, R)
-        rowmax = untile(rmf)
-        rowsum = untile(rsf)
-        alpha = jax.vmap(lambda lg, rm, rs: normalize_from_stats(
-            lg, rm, rs, lr1, tr1, R=R, V=V, K=K))(logits, rowmax, rowsum)
-        # dα — raw SDDMM kernel over the uncovered head-tiled steering
-        Qp = _pad_q(do, nb * R, dblk).reshape(H * nb * R, -1)
-        Kp, _ = _pad_cols(vfx.reshape(-1, dv), dblk)
-        scores = sddmm_kernel(
-            _uncov(fcol, K, H, Cc, C), _uncov(flrow, K, H, Cc, C),
-            _uncov(ftrow, 1, H, Cc, C), Qp, Kp,
-            W=W, V=V, K=K, dblk=dblk, interpret=interpret)
-        dalpha = jnp.where(uvals.reshape(H * C, V, K) != 0, scores,
-                           0.0).reshape(H, C, V, K)
-
-        def rsum(x):
-            s = jax.ops.segment_sum(x.reshape(-1), rows1,
-                                    num_segments=nb * R)
-            return s[rows1].reshape(x.shape)
-
-        dx = alpha * (dalpha - jax.vmap(rsum)(alpha * dalpha))
-        # LeakyReLU' from the logits (sign-preserving); masked slots have
-        # logit −inf but dx = 0, so the slope branch they take is inert
-        de = dx * float(1.0 / np.sqrt(da)) * jnp.where(logits >= 0,
-                                                       1.0, slope)
-        dQ = spmm_heads(fcol, flrow, ftrow, finit, ffini, de, kx,
-                        Cc_=Cc, Kc=K, nb_=nb, n_r=n_out)
-
-        def to_t(x):
-            """Re-lay (H, C, V, K) slots onto the Aᵀ PCSR's slot tensor
-            through the packed transfer maps (padded entries drop)."""
-            buf = jnp.zeros((H, n_tslots), x.dtype)
-            buf = buf.at[:, tidx].set(x.reshape(H, -1)[:, fidx],
-                                      mode="drop")
-            return buf.reshape(H, Ct, V, Kt)
-
-        dK = spmm_heads(tcol, tlrow, ttrow, tinit, tfini, to_t(de), q,
-                        Cc_=Ctc, Kc=Kt, nb_=nbt, n_r=ext)
-        dVf = spmm_heads(tcol, tlrow, ttrow, tinit, tfini, to_t(alpha),
-                         do, Cc_=Ctc, Kc=Kt, nb_=nbt, n_r=ext)
-        return head_merge(dQ), head_merge(dK), head_merge(dVf)
+        st = shard_steering(pcsr, fcol, flrow, ftrow, finit, ffini, fvals, H)
+        st_t = shard_steering(pcsr_t, tcol, tlrow, ttrow, tinit, tfini,
+                              tvals, H)
+        stats = lambda x: x[:H * nb * SUBLANES * LANES].reshape(
+            H * nb * SUBLANES, LANES)
+        res = (head_split(q2, H), head_split(kx2, H), head_split(vfx2, H),
+               lgf[:H * C * V * K].reshape(H, C, V, K), stats(rmf),
+               stats(rsf))
+        grads = gat_backward(st, _uncov(st, H, pcsr), st_t, fidx, tidx, res,
+                             head_split(do2, H), geom=pcsr, geom_t=pcsr_t,
+                             interpret=interpret, slope=slope)
+        return tuple(head_merge(x) for x in grads)
     return branch
 
 
@@ -327,6 +198,7 @@ def build_dist_gat(g, *, slope: float, H: int):
     flash-recompute backward with halo gradient scatter-back."""
     rows_pad = g.part.rows_pad
     mesh = g.mesh
+    pallas = g.backend == "pallas"
 
     def exchange(k2, vf2, sidx, hsrc):
         """Joint K/Vf halo exchange: one all_gather serves both operands
@@ -337,33 +209,11 @@ def build_dist_gat(g, *, slope: float, H: int):
         return (jnp.concatenate([k2, halo[:, :dk]], axis=0),
                 jnp.concatenate([vf2, halo[:, dk:]], axis=0))
 
-    if g.backend != "pallas":
-        branches = [_engine_fwd_branch(p, H=H, n_out=rows_pad, slope=slope)
-                    for p in g._fwd.pcsrs]
-
-        def body(q2, k2, vf2, colidx, lrow, trow, init, fini, vals,
-                 sidx, hsrc):
-            kx, vfx = exchange(k2, vf2, sidx[0], hsrc[0])
-            i = jax.lax.axis_index(AXIS)
-            return jax.lax.switch(i, branches, colidx[0], lrow[0], trow[0],
-                                  init[0], fini[0], vals[0], q2, kx, vfx)
-
-        sm = shard_map_2d(body, mesh, 11)
-
-        def f(Q, K, Vf):
-            out = sm(g.pad_heads(Q), g.pad_heads(K), g.pad_heads(Vf),
-                     *g._fwd.arrays, g._send_idx, g._halo_src)
-            return g.unpad_heads(out, H)
-
-        return jax.jit(f)
-
-    # ------------------------- pallas: custom_vjp over the SPMD programs
     pack = g.gat_pack(H)
     fwd_branches = [
-        _pallas_fwd_branch(p, H=H, n_out=rows_pad, slope=slope,
-                           interpret=g.interpret,
-                           logits_pad=pack.logits_pad,
-                           stats_pad=pack.stats_pad)
+        _fwd_branch(p, H=H, slope=slope, backend=g.backend,
+                    interpret=g.interpret, logits_pad=pack.logits_pad,
+                    stats_pad=pack.stats_pad)
         for p in pack.fwd.pcsrs]
 
     def fwd_body(q2, k2, vf2, colidx, lrow, trow, init, fini, vals,
@@ -374,14 +224,19 @@ def build_dist_gat(g, *, slope: float, H: int):
                               trow[0], init[0], fini[0], vals[0],
                               q2, kx, vfx)
 
-    fwd_sm = shard_map_2d(fwd_body, mesh, 11, n_out=4)
+    fwd_sm = shard_map_2d(fwd_body, mesh, 11, n_out=4 if pallas else 1)
 
     @jax.jit
     def run_fwd(Q, K, Vf):
-        out2, lg, rm, rs = fwd_sm(g.pad_heads(Q), g.pad_heads(K),
-                                  g.pad_heads(Vf), *pack.fwd.arrays,
-                                  g._send_idx, g._halo_src)
+        out = fwd_sm(g.pad_heads(Q), g.pad_heads(K), g.pad_heads(Vf),
+                     *pack.fwd.arrays, g._send_idx, g._halo_src)
+        if not pallas:
+            return g.unpad_heads(out, H)
+        out2, lg, rm, rs = out
         return g.unpad_heads(out2, H), lg, rm, rs
+
+    if not pallas:
+        return run_fwd             # natively differentiable
 
     state = {}                 # the backward program, built on first use
 
@@ -389,10 +244,9 @@ def build_dist_gat(g, *, slope: float, H: int):
         if "fn" in state:
             return state["fn"]
         ensure_gat_bwd_pack(pack)
-        branches = [
-            _pallas_bwd_branch(p, pt, H=H, n_out=rows_pad, slope=slope,
-                               interpret=g.interpret)
-            for p, pt in zip(pack.fwd.pcsrs, pack.bwd.pcsrs)]
+        branches = [_bwd_branch(p, pt, H=H, slope=slope,
+                                interpret=g.interpret)
+                    for p, pt in zip(pack.fwd.pcsrs, pack.bwd.pcsrs)]
         n_parts, max_send = g.halo.n_parts, g.halo.max_send
 
         def bwd_body(do2, q2, k2, vf2, fc, fl, ft, fi_, ff, fv,

@@ -83,6 +83,17 @@ class PackedShards:
                 self.vals)
 
 
+def shard_steering(pcsr, colidx, lrow, trow, init, fini, vals, H: int = 1):
+    """One shard's covered (``H``-head-tiled) steering dict, cut from its
+    row of a pack (padded to the widest shard) inside a branch: the
+    operand ``core.engine``'s operators take."""
+    S, C = H * pcsr.covered_num_chunks * pcsr.K, H * pcsr.covered_num_chunks
+    return {"colidx": colidx[:S], "lrow": lrow[:S], "trow": trow[:C],
+            "init": init[:C], "fini": fini[:C],
+            "vals": vals[:C * pcsr.config.V * pcsr.K].reshape(
+                C, pcsr.config.V, pcsr.K)}
+
+
 def pack_shards(pcsrs, H: int = 1) -> PackedShards:
     """Stack the shards' covered (optionally ``H``-head-tiled) steering
     arrays into mesh-shardable tensors, zero-padded to the maxima."""

@@ -18,8 +18,7 @@ Execution is one SPMD ``shard_map`` program:
    dispatches to a per-partition branch closed over that shard's
    *static* PCSR shapes (C, K, V, R, n_blocks), so partitions genuinely
    run different configurations inside a single SPMD program.  Branches
-   call the existing engine traversal (pure JAX) or the Pallas kernel
-   (``backend="pallas"``).
+   run ``core.engine``'s operators over the shard's steering.
 3. **``dist_spmm`` backward** — a ``custom_vjp`` whose backward runs the
    per-shard *transpose* PCSR (``dB_ext = A_pᵀ·dC_p``) and scatters the
    halo block of the gradient back to its owner shards through
@@ -62,53 +61,35 @@ from jax.sharding import PartitionSpec
 
 from repro.core import (CostModel, CSRMatrix, SpMMConfig, build_pcsr,
                         config_space, extract_features)
-from repro.core.engine import _engine, apply_epilogue, epilogue_grad
+from repro.core.engine import apply_epilogue, epilogue_grad, steered_spmm
 from repro.kernels import resolve_backend
 from repro.obs import trace as _obs_trace
 
 from .gat import build_dist_gat, build_gat_pack
 from .halo import HaloSpec, build_halo, halo_exchange, halo_scatter_back
-from .packing import AXIS, PackedShards, pack_shards, shard_map_2d
+from .packing import (AXIS, PackedShards, pack_shards, shard_map_2d,
+                      shard_steering)
 from .partition import RowPartition, partition_csr, split_local_halo
 
 
-def _spmm_branch(pcsr, *, n_out: int, backend: str, interpret: bool,
+def _spmm_branch(pcsr, *, backend: str, interpret: bool,
                  epilogue: bool = False, activation: str = "none"):
-    """Branch computing ``A_p · B_ext`` with shard-``p``-static shapes.
+    """Branch computing ``A_p · B_ext`` with shard-``p``-static shapes:
+    ``core.engine.steered_spmm`` over the shard's steering.
 
     With ``epilogue=True`` the branch takes two extra operands — the
     shard's per-row scale column and the replicated per-feature bias row —
     and applies scale/bias/activation per shard: in-kernel on the Pallas
     backend (the fused epilogue), XLA-fused into the SPMD program on the
     engine backend."""
-    cfg = pcsr.config
-    C, K, V, R, nb = pcsr.num_chunks, pcsr.K, cfg.V, cfg.R, pcsr.n_blocks
-    S, VS = C * K, C * V * K
-
-    if backend == "pallas":
-        from repro.kernels.paramspmm.ops import _call as _pallas_call
-        Cc = pcsr.covered_num_chunks
-        Sc, VSc = Cc * K, Cc * V * K
-
-        def branch(colidx, lrow, trow, init, fini, vals, b_ext, *ep):
-            kw = {}
-            if epilogue:
-                kw = dict(scale=ep[0][:, 0], bias=ep[1][0],
-                          activation=activation)
-            return _pallas_call(
-                colidx[:Sc], lrow[:Sc], trow[:Cc], init[:Cc], fini[:Cc],
-                vals[:VSc].reshape(Cc, V, K), b_ext,
-                cfg=cfg, n_blocks=nb, K=K, n_rows=n_out,
-                dim=b_ext.shape[1], interpret=interpret, **kw)
-        return branch
-
     def branch(colidx, lrow, trow, init, fini, vals, b_ext, *ep):
-        out = _engine(colidx[:S], lrow[:S], trow[:C],
-                      vals[:VS].reshape(C, V, K), b_ext,
-                      V=V, R=R, K=K, n_blocks=nb, n_rows=n_out)
+        spmm = steered_spmm(
+            shard_steering(pcsr, colidx, lrow, trow, init, fini, vals),
+            pcsr, backend=backend, interpret=interpret)
         if epilogue:
-            out = apply_epilogue(out, ep[0][:, 0], ep[1][0], activation)
-        return out
+            return spmm(b_ext, scale=ep[0][:, 0], bias=ep[1][0],
+                        activation=activation)
+        return spmm(b_ext)
     return branch
 
 
@@ -316,8 +297,8 @@ class DistGraph:
 
     def gat_pack(self, H: int):
         """Head-tiled covered steering pack for an ``H``-head GAT
-        (cached per head count; Pallas backend only).  H = 1 reuses the
-        graph's own forward pack — the covered arrays are identical."""
+        (cached per head count).  H = 1 reuses the graph's own forward
+        pack — the covered arrays are identical."""
         if H not in self._gat_packs:
             self._gat_packs[H] = build_gat_pack(
                 self._fwd.pcsrs, H, fwd=self._fwd if H == 1 else None)
@@ -448,8 +429,7 @@ def _bwd_core(g: DistGraph):
                                  axis_name=AXIS)
 
     if not g.overlap:
-        branches = [_spmm_branch(p, n_out=g.part.ext_cols,
-                                 backend=g.backend, interpret=g.interpret)
+        branches = [_spmm_branch(p, backend=g.backend, interpret=g.interpret)
                     for p in g._bwd.pcsrs]
 
         def core(dc, colidx, lrow, trow, init, fini, vals, sidx, hsrc):
@@ -462,11 +442,10 @@ def _bwd_core(g: DistGraph):
         return core, (*g._bwd.arrays, g._send_idx, g._halo_src)
 
     loc_t, halo_t = g._bwd_split
-    loc_branches = [_spmm_branch(p, n_out=rows_pad, backend=g.backend,
+    loc_branches = [_spmm_branch(p, backend=g.backend,
                                  interpret=g.interpret)
                     for p in loc_t.pcsrs]
-    halo_branches = [_spmm_branch(p, n_out=g.part.halo_pad,
-                                  backend=g.backend, interpret=g.interpret)
+    halo_branches = [_spmm_branch(p, backend=g.backend, interpret=g.interpret)
                      for p in halo_t.pcsrs]
 
     def core(dc, lc, ll, lt, li, lf, lv, hc, hl, ht, hi, hf, hv,
@@ -535,8 +514,7 @@ def _build_dist_spmm(g: DistGraph):
     if g.overlap:
         run_fwd = _build_overlap_fwd(g)
     else:
-        fwd_branches = [_spmm_branch(p, n_out=g.part.rows_pad,
-                                     backend=g.backend,
+        fwd_branches = [_spmm_branch(p, backend=g.backend,
                                      interpret=g.interpret)
                         for p in g._fwd.pcsrs]
 
@@ -580,11 +558,9 @@ def _build_overlap_fwd(g: DistGraph, *, epilogue: bool = False,
     for the landed rows.  With ``epilogue=True`` scale/bias/activation
     apply per shard after the add (XLA-fused; an in-kernel epilogue
     would force the two partial SpMMs to accumulate in one kernel)."""
-    loc_branches = [_spmm_branch(p, n_out=g.part.rows_pad,
-                                 backend=g.backend, interpret=g.interpret)
+    loc_branches = [_spmm_branch(p, backend=g.backend, interpret=g.interpret)
                     for p in g._loc.pcsrs]
-    halo_branches = [_spmm_branch(p, n_out=g.part.rows_pad,
-                                  backend=g.backend, interpret=g.interpret)
+    halo_branches = [_spmm_branch(p, backend=g.backend, interpret=g.interpret)
                      for p in g._halo_pack.pcsrs]
 
     def body(b, lc, ll, lt, li, lf, lv, hc, hl, ht, hi, hf, hv,
@@ -628,8 +604,7 @@ def _build_dist_fused_spmm(g: DistGraph, *, activation: str):
         def run_fwd(B, scale, bias):
             return overlap_fwd(B, g.pad(scale[:, None]), bias[None, :])
     else:
-        rows_pad = g.part.rows_pad
-        branches = [_spmm_branch(p, n_out=rows_pad, backend=g.backend,
+        branches = [_spmm_branch(p, backend=g.backend,
                                  interpret=g.interpret, epilogue=True,
                                  activation=activation)
                     for p in g._fwd.pcsrs]

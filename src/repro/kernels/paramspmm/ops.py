@@ -1,23 +1,28 @@
-"""Jit'd wrapper around the ParamSpMM Pallas kernel: padding, dispatch,
-and the high-level ``paramspmm(pcsr, B)`` entry point.
+"""The ParamSpMM kernel's one dispatch, ``spmm_steered``, and the PCSR
+entry points ``paramspmm(pcsr, B)`` / ``paramspmm_with_vals``.
 
-All Pallas dispatch goes through *covered* steering arrays
+``spmm_steered`` is the only caller of the kernel: it picks the lane tile
+(``SpMMConfig.dblk_for``), pads the operand's columns, appends coverage
+chunks to call-time values, tiles heads, packs the epilogue operands and
+crops the output.  Every operator above the kernels (``core.engine``, and
+through it ``dist``, ``serve`` and ``dynamic``) reaches the kernel
+through it, over constant or traced steering arrays.
+
+All dispatch goes through *covered* steering arrays
 (``PCSR.steering(covered=True)``): every output block — including empty
 ones — is visited and zero-initialized by the kernel's own ``init`` path,
-so no post-kernel unvisited-block mask pass (the old ``jnp.where`` +
-``jnp.repeat`` over the full padded output) remains.
+so no post-kernel unvisited-block mask pass remains.
 
 Fusion surface (see ``kernel.py``):
 
-* ``paramspmm_with_vals(..., stats=(rowmax, rowsum))`` — softmax
-  *prologue*: ``vals`` are raw logits (masked slots −inf) and α is
-  computed in-register from the per-row stats.  The GAT hot path feeds
-  the fused SDDMM's stats straight in: two kernels, zero interstitial
-  elementwise pass.
-* ``paramspmm(..., scale=, bias=, residual=, activation=)`` — fused
-  *epilogue*: per-row degree-norm scale, per-feature bias, dense
-  residual add (GIN's ``(1+ε)h`` operand), activation applied on the
-  last visit of each VMEM-resident output block.
+* ``stats=(rowmax, rowsum)`` — softmax *prologue*: ``vals`` are raw
+  logits (masked slots −inf) and α is computed in-register from the
+  per-row stats.  The GAT hot path feeds the stats SDDMM's stats straight
+  in: two kernels, zero interstitial elementwise pass.
+* ``scale=, bias=, residual=, activation=`` — fused *epilogue*: per-row
+  degree-norm scale, per-feature bias, dense residual add (GIN's
+  ``(1+ε)h`` operand), activation applied on the last visit of each
+  VMEM-resident output block.
 """
 from __future__ import annotations
 
@@ -92,16 +97,6 @@ def _call(colidx, lrow, trow, init, fini, vals, B, rowmax=None, rowsum=None,
     return out[:n_rows, :dim]
 
 
-def paramspmm(pcsr: PCSR, B, *, scale=None, bias=None, residual=None,
-              activation: str = "none", interpret: bool | None = None):
-    """C = act(scale ⊙ (A·B) + bias + residual) where A is held as PCSR —
-    the epilogue operands default to the identity (plain A·B).  Pallas
-    path (``interpret=None``: compiled on a TPU, interpreted elsewhere)."""
-    return paramspmm_with_vals(pcsr, None, B, scale=scale, bias=bias,
-                               residual=residual, activation=activation,
-                               interpret=interpret)
-
-
 @functools.partial(jax.jit, static_argnames=(
     "H", "cfg", "n_blocks", "K", "n_rows", "dim", "activation",
     "interpret"))
@@ -127,36 +122,35 @@ def _pad_chunk_vals(vals, n_extra: int, fill: float):
     return jnp.pad(vals, pad, constant_values=fill)
 
 
-def paramspmm_with_vals(pcsr: PCSR, vals, B, *, stats=None, scale=None,
-                        bias=None, residual=None, activation: str = "none",
-                        interpret: bool | None = None):
-    """SpMM over A's *pattern* with per-slot values supplied at call time —
-    the aggregation step of attention GNNs, where the PCSR topology is fixed
-    but the edge weights change every step.  ``vals=None`` uses the values
-    stored in the PCSR.
+def spmm_steered(steer, B, vals=None, *, cfg, n_blocks: int, K: int,
+                 n_rows: int, stats=None, scale=None, bias=None,
+                 residual=None, activation: str = "none",
+                 interpret: bool | None = None):
+    """The one dispatch of the ParamSpMM kernel:
+    ``act(scale ⊙ (A·B) + bias + residual)`` over *covered* steering
+    arrays ``steer`` (``PCSR.steering(H, covered=True)``'s keys and
+    layout, as host constants or traced values) with static geometry
+    ``cfg``, ``n_blocks``, ``K`` and ``n_rows``.
+
+    ``B`` of shape (n, d) runs one head; (H, n, d) runs every head in one
+    kernel call over head-tiled steering and returns (H, n_rows, d).
+    ``vals=None`` uses the values in ``steer``; call-time ``vals``
+    ((C, V, K), or (H, C, V, K)) cover the real chunks only, and the
+    coverage chunks are appended here: −inf under ``stats``, else 0.
 
     ``stats=(rowmax, rowsum)`` enables the fused softmax **prologue**:
-    ``vals`` are then the raw logits from ``sddmm_softmax_stats`` (masked
-    slots −inf) and α = exp(logit − rowmax)/rowsum is computed in-register —
-    no interstitial normalize pass.  Stats use the fused SDDMM's native
-    tile-aligned layout ``(n_blocks·SUBLANES, LANES)`` single-head,
-    ``(H·n_blocks·SUBLANES, LANES)`` multi-head (one (8, 128) tile per
-    head-tiled block; ``repro.kernels.sddmm.ops.unpack_stats`` gives the
-    dense view).
+    ``vals`` are then the raw logits of the stats SDDMM (masked slots
+    −inf) and α = exp(logit − rowmax)/rowsum is computed in-register.
+    Stats use the stats SDDMM's tile-aligned layout
+    ``(H·n_blocks·SUBLANES, LANES)`` (``repro.kernels.sddmm.ops.
+    unpack_stats`` gives the dense view).
 
     ``scale``/``bias``/``residual``/``activation`` enable the fused
     **epilogue** (single-head only): per-row scale (flat, ≤ n_rows),
-    per-feature bias (flat, ≤ dim), dense residual addend ((n, dim) —
-    GIN's ``(1+ε)h`` term rides the VMEM-resident output block), then
-    activation, applied inside the kernel on the last visit of each
-    output block.
-
-    Multi-head: ``vals`` of shape (H, C, V, K) with ``B`` of shape
-    (H, n, d) run all heads in one kernel call over head-tiled steering
-    arrays (``PCSR.steering``) and return (H, n_rows, d) — one
-    compilation for the whole head batch.
+    per-feature bias (flat, ≤ d), dense residual addend ((n, d) — GIN's
+    ``(1+ε)h`` term rides the VMEM-resident output block), then
+    activation, applied on the last visit of each output block.
     """
-    cfg = pcsr.config
     B = jnp.asarray(B)
     if stats is not None and vals is None:
         # the prologue interprets vals as logits; stored edge weights (and
@@ -172,33 +166,56 @@ def paramspmm_with_vals(pcsr: PCSR, vals, B, *, stats=None, scale=None,
                 or activation != "none"):
             raise NotImplementedError("epilogue fusion is single-head")
         H = B.shape[0]
-        t = pcsr.steering(H, covered=True)
-        C_cov = t["trow"].shape[0] // H
+        C_cov = steer["trow"].shape[0] // H
         if vals is None:                  # stored values, same for each head
-            vals = t["vals"].reshape(H, C_cov, cfg.V, pcsr.K)
+            vals = steer["vals"].reshape(H, C_cov, cfg.V, K)
         else:
             vals = jnp.asarray(vals)
             if vals.ndim != 4 or vals.shape[0] != H:
                 raise ValueError(f"multi-head vals must be (H={H}, C, V, K), "
                                  f"got {vals.shape}")
             vals = _pad_chunk_vals(vals, C_cov - vals.shape[1], fill)
-        return _call_heads(t["colidx"], t["lrow"], t["trow"], t["init"],
-                           t["fini"], vals, B, rowmax, rowsum, H=H,
-                           cfg=cfg, n_blocks=pcsr.n_blocks, K=pcsr.K,
-                           n_rows=pcsr.n_rows, dim=B.shape[2],
+        return _call_heads(steer["colidx"], steer["lrow"], steer["trow"],
+                           steer["init"], steer["fini"], vals, B, rowmax,
+                           rowsum, H=H, cfg=cfg, n_blocks=n_blocks, K=K,
+                           n_rows=n_rows, dim=B.shape[2],
                            interpret=interpret)
-    t = pcsr.steering(covered=True)
-    C_cov = t["trow"].shape[0]
+    C_cov = steer["trow"].shape[0]
     if vals is None:
-        vals = t["vals"]
+        vals = steer["vals"]
     else:
         vals = _pad_chunk_vals(jnp.asarray(vals),
                                C_cov - jnp.shape(vals)[-3], fill)
-    return _call(t["colidx"], t["lrow"], t["trow"], t["init"], t["fini"],
-                 vals, B, rowmax, rowsum,
+    return _call(steer["colidx"], steer["lrow"], steer["trow"],
+                 steer["init"], steer["fini"], vals, B, rowmax, rowsum,
                  None if scale is None else jnp.asarray(scale),
                  None if bias is None else jnp.asarray(bias),
                  None if residual is None else jnp.asarray(residual),
-                 cfg=cfg, n_blocks=pcsr.n_blocks, K=pcsr.K,
-                 n_rows=pcsr.n_rows, dim=B.shape[1], activation=activation,
-                 interpret=interpret)
+                 cfg=cfg, n_blocks=n_blocks, K=K, n_rows=n_rows,
+                 dim=B.shape[1], activation=activation, interpret=interpret)
+
+
+def paramspmm(pcsr: PCSR, B, *, scale=None, bias=None, residual=None,
+              activation: str = "none", interpret: bool | None = None):
+    """C = act(scale ⊙ (A·B) + bias + residual) where A is held as PCSR —
+    the epilogue operands default to the identity (plain A·B).  Pallas
+    path (``interpret=None``: compiled on a TPU, interpreted elsewhere)."""
+    return paramspmm_with_vals(pcsr, None, B, scale=scale, bias=bias,
+                               residual=residual, activation=activation,
+                               interpret=interpret)
+
+
+def paramspmm_with_vals(pcsr: PCSR, vals, B, *, stats=None, scale=None,
+                        bias=None, residual=None, activation: str = "none",
+                        interpret: bool | None = None):
+    """``spmm_steered`` over a PCSR's own covered steering: SpMM over A's
+    *pattern* with per-slot values supplied at call time (``vals=None``
+    uses the stored ones) — the aggregation step of attention GNNs, where
+    the topology is fixed but the edge weights change every step."""
+    B = jnp.asarray(B)
+    H = B.shape[0] if B.ndim == 3 else 1
+    return spmm_steered(pcsr.steering(H, covered=True), B, vals,
+                        cfg=pcsr.config, n_blocks=pcsr.n_blocks, K=pcsr.K,
+                        n_rows=pcsr.n_rows, stats=stats, scale=scale,
+                        bias=bias, residual=residual, activation=activation,
+                        interpret=interpret)

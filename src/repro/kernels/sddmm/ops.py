@@ -1,8 +1,12 @@
-"""Jit'd wrappers around the SDDMM Pallas kernels.
+"""The SDDMM kernels' dispatches and their PCSR entry points.
 
-Two entry points, both multi-head aware (rank-3 ``(H, n, d)`` operands run
-every head in ONE kernel call over head-tiled PCSR steering arrays — see
-``PCSR.head_tiled`` — so multi-head GAT compiles once):
+Each kernel has one dispatch over steering arrays, constant or traced —
+``sddmm_steered`` (raw masked scores) and ``sddmm_softmax_steered`` (the
+stats form) — which pads the operands and picks the lane tile
+(``_static``); the PCSR entry points below and every operator above the
+kernels reach the kernels only through them.  Both are multi-head:
+``(H, n, d)`` operands run every head in ONE kernel call over head-tiled
+steering arrays (``PCSR.steering(H)``), so multi-head GAT compiles once.
 
 * ``sddmm(pcsr, Q, K)`` — raw masked edge scores in slot layout;
 * ``sddmm_softmax_stats(pcsr, Q, K)`` — the fused GAT attention front
@@ -79,6 +83,26 @@ def _call(colidx, lrow, trow, vals, Q, K_mat, *, H, n_blocks, R, W, V, K,
     return jnp.where(vals != 0, scores, 0.0)
 
 
+def _static(cfg, n_blocks: int, K: int, interpret):
+    """The static arguments of an SDDMM kernel call.  The lane tile is
+    the pick's ``Dblk``, for every operand width."""
+    return dict(n_blocks=n_blocks, R=cfg.R, W=cfg.W, V=cfg.V, K=K,
+                dblk=cfg.dblk, interpret=interpret)
+
+
+def sddmm_steered(steer, Q, K_mat, *, cfg, n_blocks: int, K: int,
+                  interpret: bool | None = None):
+    """The plain SDDMM kernel's one dispatch: E = (A≠0) ⊙ (Q·Kᵀ) over
+    *uncovered* head-tiled steering arrays ``steer``
+    (``PCSR.steering(H)``'s keys and layout, constant or traced) for
+    (H, n, d) stacks, returning (H, C, V, K) slots."""
+    H = Q.shape[0]
+    scores = _call(steer["colidx"], steer["lrow"], steer["trow"],
+                   steer["vals"], Q, K_mat, H=H,
+                   **_static(cfg, n_blocks, K, interpret))
+    return scores.reshape(H, -1, cfg.V, K)
+
+
 def sddmm(pcsr: PCSR, Q, K, *, interpret: bool | None = None):
     """E = (A≠0) ⊙ (Q·Kᵀ) in PCSR slot layout. Pallas path.
 
@@ -90,13 +114,9 @@ def sddmm(pcsr: PCSR, Q, K, *, interpret: bool | None = None):
     single = Q.ndim == 2
     if single:
         Q, K_mat = Q[None], K_mat[None]
-    H = Q.shape[0]
-    t = pcsr.head_tiled(H)
-    cfg = pcsr.config
-    scores = _call(t["colidx"], t["lrow"], t["trow"], t["vals"], Q, K_mat,
-                   H=H, n_blocks=pcsr.n_blocks, R=cfg.R, W=cfg.W, V=cfg.V,
-                   K=pcsr.K, dblk=cfg.dblk, interpret=interpret)
-    scores = scores.reshape(H, pcsr.num_chunks, cfg.V, pcsr.K)
+    scores = sddmm_steered(pcsr.steering(Q.shape[0]), Q, K_mat,
+                           cfg=pcsr.config, n_blocks=pcsr.n_blocks,
+                           K=pcsr.K, interpret=interpret)
     return scores[0] if single else scores
 
 
@@ -140,6 +160,24 @@ def normalize_from_stats(logits, rowmax, rowsum, lrow, trow, *, R, V, K):
     return jnp.exp(logits - rm[rows]) / denom[rows]
 
 
+def sddmm_softmax_steered(steer, Q, K_mat, *, cfg, n_blocks: int, K: int,
+                          scale: float | None = None, slope: float = 0.2,
+                          interpret: bool | None = None):
+    """The stats SDDMM kernel's one dispatch, over *uncovered* head-tiled
+    steering (as ``sddmm_steered``) for (H, n, d) stacks: returns
+    ``(logits, rowmax, rowsum)``, logits (H, C, V, K), stats in the
+    kernel's tile-aligned ``(H·n_blocks·SUBLANES, LANES)`` layout.
+    ``scale`` defaults to 1/√d."""
+    H = Q.shape[0]
+    if scale is None:
+        scale = float(1.0 / np.sqrt(Q.shape[-1]))
+    logits, rowmax, rowsum = _stats_call(
+        steer["colidx"], steer["lrow"], steer["trow"], steer["init"],
+        steer["vals"], Q, K_mat, H=H, scale=float(scale), slope=float(slope),
+        **_static(cfg, n_blocks, K, interpret))
+    return logits.reshape(H, -1, cfg.V, K), rowmax, rowsum
+
+
 def sddmm_softmax_stats(pcsr: PCSR, Q, K, *, scale: float | None = None,
                         slope: float = 0.2, interpret: bool | None = None):
     """The fused GAT attention front half, *stats form*: one kernel pass
@@ -161,17 +199,10 @@ def sddmm_softmax_stats(pcsr: PCSR, Q, K, *, scale: float | None = None,
     single = Q.ndim == 2
     if single:
         Q, K_mat = Q[None], K_mat[None]
-    H = Q.shape[0]
-    if scale is None:
-        scale = float(1.0 / np.sqrt(Q.shape[-1]))
-    t = pcsr.steering(H)
-    cfg = pcsr.config
-    logits, rowmax, rowsum = _stats_call(
-        t["colidx"], t["lrow"], t["trow"], t["init"], t["vals"], Q, K_mat,
-        H=H, n_blocks=pcsr.n_blocks, R=cfg.R, W=cfg.W, V=cfg.V, K=pcsr.K,
-        dblk=cfg.dblk, scale=float(scale), slope=float(slope),
+    logits, rowmax, rowsum = sddmm_softmax_steered(
+        pcsr.steering(Q.shape[0]), Q, K_mat, cfg=pcsr.config,
+        n_blocks=pcsr.n_blocks, K=pcsr.K, scale=scale, slope=slope,
         interpret=interpret)
-    logits = logits.reshape(H, pcsr.num_chunks, cfg.V, pcsr.K)
     if single:
         logits = logits[0]
     return logits, rowmax, rowsum

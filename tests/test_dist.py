@@ -425,7 +425,6 @@ def test_dist_gat_pallas_backward_no_engine_fallback(monkeypatch):
     """The distributed GAT backward is dedicated all-Pallas: grads must
     come out with every engine path stubbed to raise."""
     import repro.core.engine as emod
-    import repro.dist.gat as gmod
 
     csr = sbm(5, 64, 0.25, 1.0, seed=7)
     rng = np.random.default_rng(2)
@@ -438,10 +437,10 @@ def test_dist_gat_pallas_backward_no_engine_fallback(monkeypatch):
     def _boom(*a, **kw):
         raise AssertionError("engine fallback in the dist Pallas GAT path")
 
-    for mod in (emod, gmod):
-        monkeypatch.setattr(mod, "_engine", _boom)
-        monkeypatch.setattr(mod, "_engine_sddmm", _boom)
-    monkeypatch.setattr(gmod, "attend_scores", _boom)
+    # the GAT pipeline the shards run lives in core.engine
+    monkeypatch.setattr(emod, "_engine", _boom)
+    monkeypatch.setattr(emod, "_engine_sddmm", _boom)
+    monkeypatch.setattr(emod, "attend_scores", _boom)
     monkeypatch.setattr(emod, "edge_softmax", _boom)
     g = DistGraph(csr, 16, 3, strategy="balanced", op="gat", heads=H,
                   backend="pallas", interpret=True)
