@@ -2,8 +2,8 @@
 SDDMM→softmax→SpMM pair; layer count/dims match the GCN setup.
 
 ``heads`` batches the attention head dimension through the kernels — the
-Pallas backend runs all heads in one head-tiled kernel call per operator
-(one compilation), hidden layers concatenate heads, the output layer
+Pallas backend runs all heads in one kernel call per operator (one
+compilation), hidden layers concatenate heads, the output layer
 averages them (``hidden`` must divide by ``heads``)."""
 GAT = {"model": "gat", "n_layers": 3, "in_dim": 16, "out_dim": 16,
        "hidden": 64, "heads": 1}

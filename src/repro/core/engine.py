@@ -362,7 +362,8 @@ def make_gat_message_fn(pcsr: PCSR, pcsr_t: Optional[PCSR] = None, *,
     """Differentiable fused GAT message ``f(Q, K, Vf) -> (n_rows, d)``
     over one PCSR: ``gat_forward`` on its steering.  Multi-head: rank-3
     ``(H, n, d)`` operands return ``(H, n_rows, d)`` — the Pallas backend
-    batches every head through one head-tiled kernel call.
+    batches every head through one call of each kernel (the SpMM packs
+    heads that fit one lane tile into the lanes of each gathered row).
 
     Backends (``None`` resolves from the platform, see
     ``repro.kernels.resolve_backend``): the engine is returned as-is,

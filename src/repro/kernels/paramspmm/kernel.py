@@ -46,6 +46,13 @@ Fusion:
   is computed in-register while the gathered B row is consumed — the
   normalize pass between SDDMM and SpMM disappears, making the GAT
   forward exactly TWO kernels.
+* **Packed heads** (``heads`` = H > 1): the operand holds H attention
+  heads side by side, head h in lanes ``h·d … h·d+d−1`` of one row, and
+  the grid is one head's chunks, so one gathered row serves every head.
+  Per-head slot values ``(C, V, K, H)`` arrive as a VMEM block and are
+  spread across lanes (``spread_heads``); the prologue's stats are
+  per-row lane vectors ``(n_blocks·R, Dblk)`` read as an ``(R, Dblk)``
+  block.  Stored values are every head's, broadcast across lanes.
 * **Epilogue** (``scale``/``bias``/``residual``/``activation``): on the
   last visit of each output block — ``fini[c] == 1``, the moment the
   completed ``(R, Dblk)`` tile is still VMEM-resident — a per-row
@@ -158,6 +165,18 @@ def gather_scratch(K: int, dblk: int, dtype) -> list:
             pltpu.SemaphoreType.DMA((2,))]
 
 
+def spread_heads(x, width: int, head_dim: int):
+    """``x`` (rows, H) as (rows, ``width``) lane vectors: lane l holds
+    head ``l // head_dim``'s entry, and lanes past H·``head_dim`` hold 0
+    — one select per head over a lane iota."""
+    head = jax.lax.broadcasted_iota(jnp.int32, (x.shape[0], width),
+                                    1) // head_dim
+    out = jnp.zeros((x.shape[0], width), x.dtype)
+    for h in range(x.shape[1]):
+        out = jnp.where(head == h, x[:, h:h + 1], out)
+    return out
+
+
 def record_prefetched_share(name: str, steps: int) -> None:
     """Gauge ``kernel_gather_prefetched_share{kernel=name}``: the share of
     a call's grid steps whose rows were in flight before the step began
@@ -186,8 +205,8 @@ steps, and output blocks are revisited by consecutive steps."""
 def _kernel(trow_ref, init_ref, fini_ref,                   # scalar prefetch
             colidx_ref, next_colidx_ref, lrow_ref, vals_ref, b_hbm, *refs,
             V: int, K: int, C: int, J: int, dblk: int, prologue: bool,
-            has_scale: bool, has_bias: bool, has_resid: bool,
-            activation: str, slope: float):
+            head_dim: int | None, has_scale: bool, has_bias: bool,
+            has_resid: bool, activation: str, slope: float):
     j = pl.program_id(0)
     c = pl.program_id(1)
 
@@ -214,23 +233,37 @@ def _kernel(trow_ref, init_ref, fini_ref,                   # scalar prefetch
                   (j + (c == C - 1)) % J * F),
         gbuf, sems, step=j * C + c, steps=J * C, span=F)
 
+    spread = head_dim and len(vals_ref.shape) == 4   # per-head values
+
     def group(g, carry):
         base = pl.multiple_of(g * SUBLANES, SUBLANES)
         tile = load_rows(gbuf, slot, base, F)     # 8 gathered B rows
+        if spread:                                # their lane vectors
+            lane_vals = [spread_heads(vals_ref[0, v, pl.ds(base, SUBLANES)],
+                                      dblk, head_dim) for v in range(V)]
         for i in range(SUBLANES):
             k = base + i
             row = lrow_ref[0, 0, k] * V           # panel's first row
             brow = tile[i:i + 1, :]               # (1, Dblk)
             for v in range(V):
-                vv = jnp.full((1, 1), vals_ref[0, v, k], brow.dtype)
+                if spread:
+                    vv = lane_vals[v][i:i + 1, :]
+                else:
+                    vv = jnp.full((1, 1), vals_ref[0, v, k], brow.dtype)
                 if prologue:
                     # α = exp(logit − rowmax)/rowsum in-register.  Guards:
                     # empty rows have rowmax = −inf / rowsum = 0 (or
                     # garbage when the row's block was never visited by
                     # the SDDMM); masked and padding slots have logit
                     # = −inf, so α must come out exactly 0.
-                    m = jnp.full((1, 1), rowmax_ref[0, row + v], brow.dtype)
-                    s = jnp.full((1, 1), rowsum_ref[0, row + v], brow.dtype)
+                    if head_dim:                  # the row's stats per lane
+                        m = rowmax_ref[pl.ds(row + v, 1), :]
+                        s = rowsum_ref[pl.ds(row + v, 1), :]
+                    else:
+                        m = jnp.full((1, 1), rowmax_ref[0, row + v],
+                                     brow.dtype)
+                        s = jnp.full((1, 1), rowsum_ref[0, row + v],
+                                     brow.dtype)
                     m = jnp.where(jnp.isfinite(m), m, 0.0)
                     den = jnp.where(s > 0, s, 1.0)
                     vv = jnp.exp(vv - m) / den
@@ -266,7 +299,7 @@ def paramspmm_kernel(colidx, lrow, trow, init, fini, vals, B_padded, *,
                      rowmax=None, rowsum=None, scale=None, bias=None,
                      residual=None, activation: str = "none",
                      slope: float = 0.2, width: int | None = None,
-                     widest: int | None = None,
+                     widest: int | None = None, heads: int = 1,
                      interpret: bool | None = None):
     """Invoke the Pallas kernel on pre-padded operands.
 
@@ -276,11 +309,19 @@ def paramspmm_kernel(colidx, lrow, trow, init, fini, vals, B_padded, *,
     ``dblk`` from it (``SpMMConfig.dblk_for``); a narrower ``dblk`` is
     counted in ``spmm_dblk_narrowed_total{kernel}``.
 
+    ``heads`` = H > 1 packs H attention heads into the ``width`` data
+    lanes of one dim tile (head h in lanes ``h·d … h·d+d−1``, d =
+    ``width // H``); the call is counted in
+    ``spmm_heads_packed_total{kernel}``.  ``vals`` is then (C, V, K),
+    every head's, or (C, V, K, H), per head.
+
     Optional fusion operands:
       rowmax/rowsum (n_blocks·SUBLANES, LANES) — softmax prologue stats
                       (vals = logits) in the fused SDDMM's native layout:
                       one (8, 128) tile per block, row r of block b at
-                      ``[b·SUBLANES, r]``;
+                      ``[b·SUBLANES, r]``; with packed heads
+                      (n_blocks·R, dblk), row r's stat of head h in its
+                      lanes ``h·d … h·d+d−1``;
       scale (n_blocks·R, 1) — per-row epilogue scale (degree norm),
                       packed by ``ops._pack_scale``;
       bias (SUBLANES, J·dblk) — per-feature epilogue bias (row 0 real);
@@ -294,15 +335,23 @@ def paramspmm_kernel(colidx, lrow, trow, init, fini, vals, B_padded, *,
         raise ValueError(f"activation {activation!r} not in {ACTIVATIONS}")
     assert R <= LANES, f"R={R} must fit one stats-tile lane row"
     assert K % SUBLANES == 0, f"K={K} must be sublane-aligned"
-    stats_shape = (n_blocks * SUBLANES, LANES)
-    for name, arr in (("rowmax", rowmax), ("rowsum", rowsum)):
-        assert arr is None or arr.shape == stats_shape, (
-            f"{name} must be tile-aligned {stats_shape}, got {arr.shape}")
-    assert scale is None or scale.shape == (n_blocks * R, 1), (
-        f"scale must be ({n_blocks * R}, 1), got {scale.shape}")
     C = trow.shape[0]
     dim_pad = B_padded.shape[1]
     assert dim_pad % dblk == 0
+    head_dim = None
+    stats_shape = (n_blocks * SUBLANES, LANES)
+    if heads > 1:
+        assert width is not None and width % heads == 0 and dim_pad == dblk, (
+            f"{heads} heads must split the {width} data lanes of one "
+            f"{dblk}-lane tile")
+        head_dim, stats_shape = width // heads, (n_blocks * R, dblk)
+    assert vals.ndim == 3 or heads > 1 and vals.shape == (C, V, K, heads), (
+        f"per-head vals must be ({C}, {V}, {K}, {heads}), got {vals.shape}")
+    for name, arr in (("rowmax", rowmax), ("rowsum", rowsum)):
+        assert arr is None or arr.shape == stats_shape, (
+            f"{name} must be {stats_shape}, got {arr.shape}")
+    assert scale is None or scale.shape == (n_blocks * R, 1), (
+        f"scale must be ({n_blocks * R}, 1), got {scale.shape}")
     assert bias is None or bias.shape == (SUBLANES, dim_pad), (
         f"bias must be ({SUBLANES}, {dim_pad}), got {bias.shape}")
     J = dim_pad // dblk
@@ -317,15 +366,23 @@ def paramspmm_kernel(colidx, lrow, trow, init, fini, vals, B_padded, *,
     next_ids = pl.BlockSpec((1, 1, K),
                             lambda j, c, tr, it, fi: ((c + 1) % C, 0, 0),
                             memory_space=smem)
-    in_specs = [chunk_spec(1), next_ids, chunk_spec(1), chunk_spec(V),
+    # per-head values: the chunk's (V, K, H) block, in VMEM
+    vals_spec = (chunk_spec(V) if vals.ndim == 3 else pl.BlockSpec(
+        (1, V, K, heads), lambda j, c, tr, it, fi: (c, 0, 0, 0)))
+    in_specs = [chunk_spec(1), next_ids, chunk_spec(1), vals_spec,
                 pl.BlockSpec(memory_space=pl.ANY)]          # B stays in HBM
     colidx = colidx.reshape(C, 1, K)
     operands = [colidx, colidx, lrow.reshape(C, 1, K), vals,
                 lane_rows(B_padded)]
     if prologue:
-        stats_spec = pl.BlockSpec((SUBLANES, LANES),
-                                  lambda j, c, tr, it, fi: (tr[c], 0),
-                                  memory_space=smem)
+        # the block's stats tile in SMEM, or with packed heads its (R,
+        # Dblk) lane vectors in VMEM
+        stats_spec = (pl.BlockSpec((R, dblk),
+                                   lambda j, c, tr, it, fi: (tr[c], 0))
+                      if head_dim else
+                      pl.BlockSpec((SUBLANES, LANES),
+                                   lambda j, c, tr, it, fi: (tr[c], 0),
+                                   memory_space=smem))
         in_specs += [stats_spec, stats_spec]
         operands += [rowmax, rowsum]
     if scale is not None:
@@ -355,15 +412,18 @@ def paramspmm_kernel(colidx, lrow, trow, init, fini, vals, B_padded, *,
     name = (f"paramspmm_v{V}_k{K}_r{R}_d{dblk}"
             f"{'_pro' if prologue else ''}"
             f"{'_res' if residual is not None else ''}"
-            f"{'' if activation == 'none' else '_' + activation}")
+            f"{'' if activation == 'none' else '_' + activation}"
+            f"{f'_h{heads}' if heads > 1 else ''}")
     record_prefetched_share(name, C * J)
     record_gather_lanes(name, C=C, J=J, K=K, dblk=dblk,
                         width=dim_pad if width is None else width)
     if widest is not None and dblk < widest:
         _obs_metrics.counter("spmm_dblk_narrowed_total").inc(kernel=name)
+    if heads > 1:
+        _obs_metrics.counter("spmm_heads_packed_total").inc(kernel=name)
     fn = pl.pallas_call(
         functools.partial(_kernel, V=V, K=K, C=C, J=J, dblk=dblk,
-                          prologue=prologue,
+                          prologue=prologue, head_dim=head_dim,
                           has_scale=scale is not None,
                           has_bias=bias is not None,
                           has_resid=residual is not None,

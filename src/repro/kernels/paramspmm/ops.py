@@ -3,8 +3,9 @@ entry points ``paramspmm(pcsr, B)`` / ``paramspmm_with_vals``.
 
 ``spmm_steered`` is the only caller of the kernel: it picks the lane tile
 (``SpMMConfig.dblk_for``), pads the operand's columns, appends coverage
-chunks to call-time values, tiles heads, packs the epilogue operands and
-crops the output.  Every operator above the kernels (``core.engine``, and
+chunks to call-time values, packs heads into lanes (or tiles them where
+they do not fit one lane tile), packs the epilogue operands and crops
+the output.  Every operator above the kernels (``core.engine``, and
 through it ``dist``, ``serve`` and ``dynamic``) reaches the kernel
 through it, over constant or traced steering arrays.
 
@@ -113,6 +114,40 @@ def _call_heads(colidx, lrow, trow, init, fini, vals, B, rowmax=None,
     return out.reshape(H, n_blocks * cfg.R, dim)[:, :n_rows]
 
 
+def _spread_stats(stats, H: int, d: int, n_blocks: int, R: int, dblk: int):
+    """Per-head row stats in the stats SDDMM's tile-aligned layout
+    ``(H·n_blocks·SUBLANES, LANES)`` as the packed kernel's per-row lane
+    vectors ``(n_blocks·R, dblk)``: head h's stat of row r in lanes
+    ``h·d … h·d+d−1`` of row r, 0 past ``H·d``."""
+    dense = stats.reshape(H, n_blocks, SUBLANES, LANES)[:, :, 0, :R]
+    lanes = jnp.repeat(dense.reshape(H, n_blocks * R).T, d, axis=1)
+    return jnp.pad(lanes, ((0, 0), (0, dblk - H * d)))
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "cfg", "n_blocks", "K", "n_rows", "interpret"))
+def _call_packed(colidx, lrow, trow, init, fini, vals, B, rowmax=None,
+                 rowsum=None, *, cfg, n_blocks, K, n_rows, interpret):
+    """Every head of an (H, n, d) operand in one call over one head's
+    steering: head h rides lanes ``h·d … h·d+d−1`` of each gathered row
+    (``H·d`` ≤ ``cfg.dblk``).  ``vals`` are one head's stored (C, V, K)
+    values, every head's, or per-head (H, C, V, K) ones; the stats are
+    head-tiled, as the stats SDDMM gives them.  Returns (H, n_rows, d)."""
+    H, n, d = B.shape
+    R, dblk = cfg.R, cfg.dblk_for(H * d)
+    B_lanes, _ = _pad_cols(B.transpose(1, 0, 2).reshape(n, H * d), dblk)
+    if vals.ndim == 4:                    # (H, C, V, K) → (C, V, K, H)
+        vals = vals.transpose(1, 2, 3, 0)
+    if rowmax is not None:
+        rowmax, rowsum = (_spread_stats(x, H, d, n_blocks, R, dblk)
+                          for x in (rowmax, rowsum))
+    out = paramspmm_kernel(colidx, lrow, trow, init, fini, vals, B_lanes,
+                           n_blocks=n_blocks, R=R, V=cfg.V, K=K, dblk=dblk,
+                           rowmax=rowmax, rowsum=rowsum, width=H * d,
+                           widest=cfg.dblk, heads=H, interpret=interpret)
+    return out[:n_rows, :H * d].reshape(n_rows, H, d).transpose(1, 0, 2)
+
+
 def _pad_chunk_vals(vals, n_extra: int, fill: float):
     """Append ``n_extra`` coverage chunks to a (..., C, V, K) slot tensor."""
     if n_extra == 0:
@@ -133,7 +168,11 @@ def spmm_steered(steer, B, vals=None, *, cfg, n_blocks: int, K: int,
     ``cfg``, ``n_blocks``, ``K`` and ``n_rows``.
 
     ``B`` of shape (n, d) runs one head; (H, n, d) runs every head in one
-    kernel call over head-tiled steering and returns (H, n_rows, d).
+    kernel call and returns (H, n_rows, d).  Where several heads fit one
+    of the pick's lane tiles (H·d ≤ ``cfg.dblk``) they are packed side by
+    side into the lanes of each gathered row and the call runs head 0's
+    chunks (the first ``1/H`` of head-tiled steering); wider heads run
+    the head-tiled chunks, one row per head.
     ``vals=None`` uses the values in ``steer``; call-time ``vals``
     ((C, V, K), or (H, C, V, K)) cover the real chunks only, and the
     coverage chunks are appended here: −inf under ``stats``, else 0.
@@ -165,16 +204,24 @@ def spmm_steered(steer, B, vals=None, *, cfg, n_blocks: int, K: int,
         if (scale is not None or bias is not None or residual is not None
                 or activation != "none"):
             raise NotImplementedError("epilogue fusion is single-head")
-        H = B.shape[0]
+        H, d = B.shape[0], B.shape[2]
         C_cov = steer["trow"].shape[0] // H
-        if vals is None:                  # stored values, same for each head
-            vals = steer["vals"].reshape(H, C_cov, cfg.V, K)
-        else:
+        if vals is not None:
             vals = jnp.asarray(vals)
             if vals.ndim != 4 or vals.shape[0] != H:
                 raise ValueError(f"multi-head vals must be (H={H}, C, V, K), "
                                  f"got {vals.shape}")
             vals = _pad_chunk_vals(vals, C_cov - vals.shape[1], fill)
+        if 1 < H and H * d <= cfg.dblk:   # heads packed: head 0's chunks
+            h0 = {k: a[:C_cov * K] if k in ("colidx", "lrow") else a[:C_cov]
+                  for k, a in steer.items()}
+            return _call_packed(h0["colidx"], h0["lrow"], h0["trow"],
+                                h0["init"], h0["fini"],
+                                h0["vals"] if vals is None else vals, B,
+                                rowmax, rowsum, cfg=cfg, n_blocks=n_blocks,
+                                K=K, n_rows=n_rows, interpret=interpret)
+        if vals is None:                  # stored values, same for each head
+            vals = steer["vals"].reshape(H, C_cov, cfg.V, K)
         return _call_heads(steer["colidx"], steer["lrow"], steer["trow"],
                            steer["init"], steer["fini"], vals, B, rowmax,
                            rowsum, H=H, cfg=cfg, n_blocks=n_blocks, K=K,

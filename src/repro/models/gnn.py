@@ -151,7 +151,7 @@ def gat_forward(params, X, gat_msg, heads: int = 1):
     With ``heads > 1`` the projections are split into (H, n, d_head)
     stacks and handed to ``gat_msg`` as one batch — the message fn (see
     ``core.engine.make_gat_message_fn``) runs every head through a single
-    head-tiled kernel call, so the layer compiles once however many heads.
+    kernel call per kernel, so the layer compiles once however many heads.
     """
     h = X
     L = len(params)

@@ -1,6 +1,7 @@
 """Fused GAT attention pipeline: fused SDDMM→softmax kernel vs the
-engine oracle, multi-head batching, the dedicated transpose-PCSR backward,
-and the slot transfer map's round-trip properties."""
+engine oracle, multi-head batching (heads packed into the SpMM's lanes,
+or head-tiled), the dedicated transpose-PCSR backward, and the slot
+transfer map's round-trip properties."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -11,7 +12,9 @@ from repro.core.engine import (_slot_rows, edge_softmax, engine_sddmm,
 from repro.core.pcsr import (SpMMConfig, build_pcsr, slot_transfer_map,
                              transpose_pcsr)
 from repro.core.sparse import CSRMatrix
-from repro.kernels.sddmm import sddmm_softmax
+from repro.kernels.paramspmm import paramspmm_with_vals
+from repro.kernels.sddmm import sddmm_softmax, sddmm_softmax_stats
+from repro.obs import metrics_snapshot, reset_metrics
 
 from conftest import random_csr
 from _propcheck import booleans, floats, integers, propcases, sampled_from
@@ -102,6 +105,62 @@ def test_fused_multihead_matches_per_head_and_compiles_once(rng, monkeypatch):
                                                   interpret=True))
                          for h in range(H)])
     np.testing.assert_allclose(batched, per_head, atol=1e-6, rtol=1e-6)
+
+
+# (H, d): 8 × 8 = 64 and 8 × 3 = 24 lanes pack into one 128-lane row;
+# 4 × 40 = 160 does not, and keeps head tiling
+@pytest.mark.parametrize("form", ["vals", "stored", "prologue"])
+@pytest.mark.parametrize("H,d", [(8, 8), (8, 3), (4, 40)])
+def test_multihead_spmm_matches_per_head(rng, H, d, form):
+    """An (H, n, d) SpMM equals each head's own single-head call to the
+    bit, heads packed into lanes or not: every head keeps its slots,
+    their order and the f32 operations.  Call-time per-head values,
+    stored values (every head's) and the softmax prologue's per-head
+    logits and stats, over a graph with an empty-row band (coverage
+    chunks) and masked edges (−inf logits)."""
+    n = 48
+    A = ((rng.random((n, n)) < 0.2)
+         * rng.standard_normal((n, n))).astype(np.float32)
+    A[8:24] = 0.0
+    rows, cols = np.nonzero(A)
+    vals = A[rows, cols].copy()
+    vals[::5] = 0.0                       # every 5th stored edge masked out
+    csr = CSRMatrix.from_coo(rows, cols, vals, n, n, sum_duplicates=False)
+    p = build_pcsr(csr.indptr, csr.indices, csr.data, n, n,
+                   SpMMConfig(V=2, S=True, W=4))
+    assert p.n_empty_blocks > 0           # the call appends coverage chunks
+    B = rng.standard_normal((H, n, d)).astype(np.float32)
+    per_head = [{} for _ in range(H)]
+    if form == "vals":
+        v = rng.standard_normal((H,) + p.vals.shape).astype(np.float32)
+        v[:, np.asarray(p.vals) == 0] = 0.0
+        kw = {"vals": v}
+        per_head = [{"vals": v[h]} for h in range(H)]
+    elif form == "prologue":
+        Q = rng.standard_normal((H, n, d)).astype(np.float32)
+        K = rng.standard_normal((H, n, d)).astype(np.float32)
+        logits, rm, rs = sddmm_softmax_stats(p, Q, K, interpret=True)
+        assert np.isneginf(np.asarray(logits)).any()
+        kw = {"vals": logits, "stats": (rm, rs)}
+        rm, rs = (np.asarray(x).reshape(H, -1, x.shape[-1]) for x in (rm, rs))
+        per_head = [{"vals": logits[h], "stats": (rm[h], rs[h])}
+                    for h in range(H)]
+    else:
+        kw = {"vals": None}
+    jax.clear_caches()                    # trace every call anew
+    reset_metrics()
+    got = np.asarray(paramspmm_with_vals(p, kw.pop("vals"), B,
+                                         interpret=True, **kw))
+    packed = metrics_snapshot().get("spmm_heads_packed_total", {})
+    form_name = f"paramspmm_v2_k{p.K}_r8_d128{'_pro' * (form == 'prologue')}"
+    assert packed == ({f"kernel={form_name}_h{H}": 1} if H * d <= 128
+                      else {})
+    want = np.stack([np.asarray(paramspmm_with_vals(
+        p, per_head[h].pop("vals", None), B[h], interpret=True,
+        **per_head[h])) for h in range(H)])
+    assert got.shape == (H, n, d) and np.isfinite(got).all()
+    np.testing.assert_array_equal(got, want)
+    np.testing.assert_array_equal(got[:, 8:24], 0.0)   # empty rows
 
 
 def test_gat_pallas_backward_no_engine_fallback(rng, monkeypatch):

@@ -5,8 +5,9 @@ The smoke graph (``chip_smoke.py``: 65,536 nodes, 1.47M nonzeros after
 GCN normalization) packs under its picked ⟨W=16, F=1, V=2, S, B⟩ into
 2,066 chunks of K = 776 slots — 1,603,216 slots — over 2,048 output
 blocks of R = 32 rows.  Every kernel form of the GCN/GIN/GAT path is
-compiled at that size, single-head and 4-head tiled, and the SpMM forms
-also over a 256-wide operand (256-lane rows, or two 128-lane dim tiles);
+compiled at that size, single-head and 4-head tiled, the SpMM forms
+also over a 256-wide operand (256-lane rows, or two 128-lane dim tiles),
+and the attention SpMM forms with 4 and 8 heads of 8 packed into lanes;
 then whole jitted GCN value-and-grad steps, at the smoke widths and at
 OGB's ogbn-arxiv widths, whose device memory must fit a v5e.
 
@@ -44,6 +45,35 @@ def one_chip():
 # a wide form's suffix → (Dblk, operand width): 256-lane rows (F = 2),
 # or two 128-lane dim tiles (F = 1, J = 2), of a 256-wide operand
 WIDE = {"_wide_f2": (2 * LANES, 256), "_wide_j2": (LANES, 256)}
+HEAD_DIM = 8            # the packed forms' heads, as in the GAT cell's
+
+# (form, H): every form single-head and 4-head tiled; the packed forms
+# with 4 and 8 heads
+FORMS = [(name, H) for name in (
+    "spmm", "spmm_epilogue", "spmm_residual", "spmm_softmax_prologue",
+    "sddmm_softmax", "sddmm", "spmm_wide_f2", "spmm_epilogue_wide_f2",
+    "spmm_wide_j2", "spmm_epilogue_wide_j2") for H in (1, 4)] + [
+    (name, H) for name in ("spmm_packed", "spmm_softmax_prologue_packed")
+    for H in (4, 8)]
+
+
+def _packed_form(name: str, H: int, sd):
+    """(fn, argument shapes) of an attention SpMM form with H heads of
+    ``HEAD_DIM`` packed into the lanes of one row, over one copy of the
+    smoke graph's steering."""
+    from repro.kernels.paramspmm.kernel import paramspmm_kernel
+    f32, i32 = jnp.float32, jnp.int32
+    steer = [sd((C * K,), i32), sd((C * K,), i32), sd((C,), i32),
+             sd((C,), i32), sd((C,), i32)]
+    args = steer + [sd((C, V, K, H), f32), sd((N, DBLK), f32)]
+    kw = dict(n_blocks=NB, R=R, V=V, K=K, dblk=DBLK, width=H * HEAD_DIM,
+              heads=H, interpret=False)
+    if name == "spmm_packed":          # GAT backward dQ, dK, dVf
+        return (lambda *a: paramspmm_kernel(*a, **kw)), args
+    assert name == "spmm_softmax_prologue_packed"    # GAT aggregation
+    return (lambda *a: paramspmm_kernel(*a[:7], rowmax=a[7], rowsum=a[8],
+                                        **kw),
+            args + [sd((NB * R, DBLK), f32)] * 2)
 
 
 def _form(name: str, H: int, sd):
@@ -51,6 +81,8 @@ def _form(name: str, H: int, sd):
     copies of the smoke graph's steering."""
     from repro.kernels.paramspmm.kernel import paramspmm_kernel
     from repro.kernels.sddmm.kernel import sddmm_kernel, sddmm_softmax_kernel
+    if name.endswith("_packed"):
+        return _packed_form(name, H, sd)
     c, nb, n = H * C, H * NB, H * N
     f32, i32 = jnp.float32, jnp.int32
     steer = [sd((c * K,), i32), sd((c * K,), i32), sd((c,), i32),
@@ -86,29 +118,36 @@ def _form(name: str, H: int, sd):
             steer[:3] + [sd((nb * R, DBLK), f32), B])
 
 
-@pytest.mark.parametrize("H", [1, 4])
-@pytest.mark.parametrize("name", ["spmm", "spmm_epilogue", "spmm_residual",
-                                  "spmm_softmax_prologue", "sddmm_softmax",
-                                  "sddmm", "spmm_wide_f2",
-                                  "spmm_epilogue_wide_f2", "spmm_wide_j2",
-                                  "spmm_epilogue_wide_j2"])
+@pytest.mark.parametrize("name,H", FORMS)
 def test_kernel_compiles_for_v5e(one_chip, name, H):
     """Each form compiles with its two-slot gather, and tracing it sets the
     prefetched-share gauge: every grid step but the first (J = 1, but for
-    the ``_wide_j2`` forms' two dim tiles).  A wide form gathers a
-    256-wide operand and counts every gathered lane as carrying data."""
+    the ``_wide_j2`` forms' two dim tiles; a packed form's grid is one
+    head's chunks).  A wide form gathers a 256-wide operand and counts
+    every gathered lane as carrying data; a packed form counts its H·8
+    lanes as carried, and itself once as packed."""
     from repro.obs import metrics_snapshot, reset_metrics
     sd = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
     fn, args = _form(name, H, sd)
     J = 2 if name.endswith("_wide_j2") else 1
+    steps = (1 if name.endswith("_packed") else H) * C * J
     reset_metrics()
     hlo = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in hlo
-    share = metrics_snapshot()["kernel_gather_prefetched_share"]
-    assert list(share.values()) == [(H * C * J - 1) / (H * C * J)]
+    snap = metrics_snapshot()
+    assert list(snap["kernel_gather_prefetched_share"].values()) == [
+        (steps - 1) / steps]
     if "_wide_" in name:
-        lanes = metrics_snapshot()["kernel_gather_lanes_total"]
+        lanes = snap["kernel_gather_lanes_total"]
         assert sorted(lanes.values()) == [H * C * K * 256] * 2
+    if name.endswith("_packed"):
+        (kernel,) = snap["spmm_heads_packed_total"]
+        assert kernel.endswith(f"_h{H}")
+        assert snap["spmm_heads_packed_total"][kernel] == 1
+        assert sorted(snap["kernel_gather_lanes_total"].values()) == [
+            C * K * H * HEAD_DIM, C * K * DBLK]
+    else:
+        assert "spmm_heads_packed_total" not in snap
 
 
 def test_gcn_train_step_compiles_and_fits_v5e(one_chip):

@@ -3,8 +3,8 @@
 40, through ``ParamSpMM`` with ``F`` = 1 (two dim tiles at 256) and ``F``
 = 2 forced, against a plain gather + ``segment_sum`` reference in float32;
 a 40-wide operand under a 256-lane pick; each call's lane tile narrowed to
-its operand (``SpMMConfig.dblk_for``); and the ``kernel_gather_lanes_total``
-and ``spmm_dblk_narrowed_total`` counters."""
+its operand (``SpMMConfig.dblk_for``); and the ``kernel_gather_lanes_total``,
+``spmm_dblk_narrowed_total`` and ``spmm_heads_packed_total`` counters."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -159,6 +159,34 @@ def test_gather_lanes_counter(dim, F):
     assert metrics_snapshot()["kernel_gather_lanes_total"] == {
         f"kernel={name},lanes=moved": C * J * K * dblk,
         f"kernel={name},lanes=carried": C * K * dim}
+
+
+@pytest.mark.parametrize("shape", [(40, 64), (40, 200), (1, 40, 8),
+                                   (8, 40, 8), (8, 40, 3), (4, 40, 40)])
+def test_heads_packed_counter(rng, shape):
+    """Tracing a call whose (H, n, d) operand packs several heads into one
+    lane tile, (8, n, 8) and (8, n, 3), adds 1 to
+    ``spmm_heads_packed_total{kernel=<its _h8 name>}`` and counts its
+    H·d lanes carried over one head's chunks; a 2-D operand, one head,
+    or heads wider than the tile (4 × 40) add nothing."""
+    csr, _ = random_csr(rng, 40, 0.15)
+    p = build_pcsr(csr.indptr, csr.indices, csr.data, 40, 40,
+                   SpMMConfig(V=2, S=True, W=4))
+    jax.clear_caches()              # trace the call anew, so it counts
+    reset_metrics()
+    jax.eval_shape(lambda b: paramspmm(p, b, interpret=True),
+                   jax.ShapeDtypeStruct(shape, jnp.float32))
+    snap = metrics_snapshot()
+    if len(shape) == 2 or shape[0] == 1 or shape[0] * shape[2] > LANES:
+        assert "spmm_heads_packed_total" not in snap
+        return
+    H, _, d = shape
+    name = f"paramspmm_v2_k{p.K}_r8_d{LANES}_h{H}"
+    assert snap["spmm_heads_packed_total"] == {f"kernel={name}": 1}
+    C = p.steering(covered=True)["trow"].shape[0]
+    assert snap["kernel_gather_lanes_total"] == {
+        f"kernel={name},lanes=moved": C * p.K * LANES,
+        f"kernel={name},lanes=carried": C * p.K * H * d}
 
 
 @pytest.mark.parametrize("width,form,tile,J", [
